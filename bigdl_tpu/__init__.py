@@ -21,7 +21,9 @@ upstream path* (e.g. ``<dl>/nn/Linear.scala``) per SURVEY.md §2 and are marked 
 
 __version__ = "0.1.0"
 
-from bigdl_tpu.utils.engine import Engine
+from bigdl_tpu.utils.engine import Engine, place_compile_cache
 from bigdl_tpu.utils.table import Table, T
+
+place_compile_cache()
 
 __all__ = ["Engine", "Table", "T", "__version__"]

@@ -22,20 +22,27 @@ each (block_q, block_k) probability tile from (q, k, L) in VMEM and streams
 with D = rowsum(dO * O) precomputed in one fused elementwise pass — so
 TRAINING memory is O(T·d) too, not just inference (the O(T^2) score matrix is
 never materialised in either direction; asserted by test against the compiled
-HLO). Off-TPU (or if the kernel build fails) the recompute-form VJP of the
-reference jnp attention remains as fallback.
+HLO). Off TPU, and at sequence lengths no legal tile covers (``_pick_block``),
+the reference jnp attention and its recompute-form VJP run instead. On TPU a
+kernel that does not build raises: nothing here catches a build error.
+
+Per-row residuals (logsumexp ``L``, ``D``) cross HBM in the orientation each
+kernel broadcasts them in, so no kernel has to move a vector between
+sublanes and lanes: a column ``(bh, T, 1)`` for the forward and dq kernels (rows of the
+``(block_q, block_k)`` tile), a row ``(bh, 1, T)`` for the dk/dv kernel (its
+tile is transposed). Both shapes meet Mosaic's block rule — the last two block
+dims divide (8, 128) or span the array — which a ``(1, block_q)`` block over
+``(bh, T)`` does not.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 
 import jax
 import jax.numpy as jnp
 
-logger = logging.getLogger(__name__)
-_fallback_warned = False
+from bigdl_tpu.kernels.layernorm import _on_tpu, out_struct
 
 
 def _reference_attention(q, k, v, causal: bool):
@@ -105,12 +112,12 @@ def _pallas_flash_call(q3, k3, v3, causal, block_q, block_k, interpret):
             # per-row logsumexp residual for the flash backward: rows with no
             # live block (cannot happen causally — the diagonal is live) would
             # be -inf; clamp through the same denom guard
-            lse_ref[0] = (m_scr[:] + jnp.log(denom))[:, 0]
+            lse_ref[0] = m_scr[:] + jnp.log(denom)
 
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-                   jax.ShapeDtypeStruct((bh, t), jnp.float32)],
+        out_shape=[out_struct((bh, t, d), q3.dtype, q3, k3, v3),
+                   out_struct((bh, t, 1), jnp.float32, q3, k3, v3)],
         grid=(bh, t // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -118,18 +125,19 @@ def _pallas_flash_call(q3, k3, v3, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))],
+                   pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="bigdl_flash_fwd",
     )(q3, k3, v3)
     return out, lse
 
 
-def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse3, dd3, causal,
+def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
                          block_q, block_k, interpret):
     """dq = Σ_j (p_ij * (dO_i·v_j^T - D_i)) · k_j * scale, streaming over j
     with the probability tile recomputed from (q, k, lse) in VMEM."""
@@ -156,8 +164,8 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse3, dd3, causal,
             k = k_ref[0].astype(jnp.float32)
             v = v_ref[0].astype(jnp.float32)
             do = do_ref[0].astype(jnp.float32)
-            lse = lse_ref[0][:, None]                     # (bq, 1)
-            dd = dd_ref[0][:, None]                       # (bq, 1)
+            lse = lse_ref[0]                              # (bq, 1)
+            dd = dd_ref[0]                                # (bq, 1)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
             if causal:
@@ -180,23 +188,24 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse3, dd3, causal,
 
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
+        out_shape=out_struct((bh, t, d), q3.dtype, q3, k3, v3, do3),
         grid=(bh, t // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse3, dd3)
+        name="bigdl_flash_bwd_dq",
+    )(q3, k3, v3, do3, lse_col, dd_col)
 
 
-def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse3, dd3, causal,
+def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, causal,
                           block_q, block_k, interpret):
     """dv = Σ_i p_ij^T · dO_i ; dk = Σ_i ds_ij^T · q_i * scale — grid iterates
     k-blocks outer, q-blocks inner, with (dk, dv) accumulators in VMEM."""
@@ -226,8 +235,8 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse3, dd3, causal,
             k = k_ref[0].astype(jnp.float32)
             v = v_ref[0].astype(jnp.float32)
             do = do_ref[0].astype(jnp.float32)
-            lse = lse_ref[0][None, :]                     # (1, bq)
-            dd = dd_ref[0][None, :]                       # (1, bq)
+            lse = lse_ref[0]                              # (1, bq)
+            dd = dd_ref[0]                                # (1, bq)
             # transposed orientation: s_T (bk, bq)
             s_t = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                       preferred_element_type=jnp.float32) * scale
@@ -255,37 +264,41 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse3, dd3, causal,
 
     return pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), k3.dtype),
-                   jax.ShapeDtypeStruct((bh, t, d), v3.dtype)],
+        out_shape=[out_struct((bh, t, d), k3.dtype, q3, k3, v3, do3),
+                   out_struct((bh, t, d), v3.dtype, q3, k3, v3, do3)],
         grid=(bh, t // block_k, n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
         ],
         out_specs=[pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse3, dd3)
+        name="bigdl_flash_bwd_dkv",
+    )(q3, k3, v3, do3, lse_row, dd_row)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def _pick_block(t: int, target: int) -> int:
-    block = 1
-    while block < target and t % (block * 2) == 0:
-        block *= 2
-    return block
+def _pick_block(t: int, target: int) -> int | None:
+    """Tile length along a sequence axis of length ``t``: the whole axis when
+    it fits in ``target``, else the largest multiple of 128 up to ``target``
+    that divides ``t``. Both satisfy Mosaic's block rule in either residual
+    orientation (sublane or lane). ``None`` when no such tile exists, or when
+    ``t`` is not a multiple of the 8-row sublane tile — the caller then runs
+    the reference."""
+    if t < 8 or t % 8:
+        return None
+    if t <= target:
+        return t
+    for block in range(target - target % 128, 0, -128):
+        if t % block == 0:
+            return block
+    return None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -294,79 +307,57 @@ def flash_attention(q, k, v, causal: bool = False,
     """Streaming-softmax attention over (batch, heads, T, d) operands.
 
     ``force_pallas``: None = pallas on TPU, reference jnp elsewhere; True =
-    pallas (interpreted off-TPU — tests); False = reference.
+    pallas (interpreted off-TPU — tests); False = reference. Whatever the
+    setting, a ``T`` that ``_pick_block`` cannot tile runs the reference.
     """
     return _fa_fwd(q, k, v, causal, force_pallas)[0]
 
 
 def _fa_fwd(q, k, v, causal, force_pallas):
     use_pallas = _on_tpu() if force_pallas is None else force_pallas
-    out = lse = None
-    if use_pallas:
-        b, h, t, d = q.shape
-        # measured on v5e (T=2048, d=64): 256/512 tiles amortise grid-step
-        # overhead ~30% better than 128/128 and beat XLA's fused attention;
-        # VMEM stays comfortable (score tile 256x512 fp32 = 512 KB)
-        block_q, block_k = _pick_block(t, 256), _pick_block(t, 512)
-        # degenerate tiles can't use the MXU profitably; fall back
-        if block_q >= 8 and block_k >= 8:
-            try:
-                q3 = q.reshape(b * h, t, d)
-                k3 = k.reshape(b * h, t, d)
-                v3 = v.reshape(b * h, t, d)
-                out, lse = _pallas_flash_call(
-                    q3, k3, v3, causal, block_q, block_k,
-                    interpret=not _on_tpu())
-                out = out.reshape(b, h, t, d)
-            except Exception as e:  # pallas unavailable → reference
-                global _fallback_warned
-                if not _fallback_warned:
-                    _fallback_warned = True
-                    logger.warning(
-                        "flash_attention Pallas kernel failed (%s: %s); "
-                        "falling back to O(T^2) reference attention — "
-                        "long-context memory/speed benefits are lost",
-                        type(e).__name__, e)
-                out = lse = None
-    if out is None:
-        out = _reference_attention(q, k, v, causal)
-        return out, (q, k, v, None, None)
+    b, h, t, d = q.shape
+    # the backward's 128 tile is the tightest: a T it covers, the forward's
+    # 256/512 targets cover too
+    if not use_pallas or _pick_block(t, 128) is None:
+        return _reference_attention(q, k, v, causal), (q, k, v, None, None)
+    # measured on v5e (T=2048, d=64): 256/512 tiles amortise grid-step
+    # overhead ~30% better than 128/128 and beat XLA's fused attention;
+    # VMEM stays comfortable (score tile 256x512 fp32 = 512 KB)
+    block_q, block_k = _pick_block(t, 256), _pick_block(t, 512)
+    out, lse = _pallas_flash_call(
+        q.reshape(b * h, t, d), k.reshape(b * h, t, d),
+        v.reshape(b * h, t, d), causal, block_q, block_k,
+        interpret=not _on_tpu())
+    out = out.reshape(b, h, t, d)
     return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(causal, force_pallas, res, g):
     q, k, v, out, lse = res
-    if lse is not None:
-        try:
-            return _flash_bwd(q, k, v, out, lse, g, causal)
-        except Exception as e:  # pallas bwd unavailable → reference VJP
-            global _fallback_warned
-            if not _fallback_warned:
-                _fallback_warned = True
-                logger.warning(
-                    "flash_attention Pallas backward failed (%s: %s); "
-                    "falling back to the O(T^2) reference VJP",
-                    type(e).__name__, e)
-    _, vjp = jax.vjp(
-        lambda qq, kk, vv: _reference_attention(qq, kk, vv, causal), q, k, v)
-    return vjp(g)
+    if lse is None:
+        _, vjp = jax.vjp(
+            lambda qq, kk, vv: _reference_attention(qq, kk, vv, causal),
+            q, k, v)
+        return vjp(g)
+    return _flash_bwd(q, k, v, out, lse, g, causal)
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal):
     """Streaming flash-2 backward: O(T·d) memory, probability tiles recomputed
     from (q, k, lse) in VMEM."""
     b, h, t, d = q.shape
-    block_q, block_k = _pick_block(t, 128), _pick_block(t, 128)
+    block_q = block_k = _pick_block(t, 128)   # not None: _fa_fwd checked
     reshape = lambda a: a.reshape(b * h, t, d)
     q3, k3, v3, do3 = reshape(q), reshape(k), reshape(v), reshape(g)
     # D_i = rowsum(dO * O): one fused elementwise pass, O(T·d) reads
-    dd3 = jnp.sum(do3.astype(jnp.float32) * reshape(out).astype(jnp.float32),
-                  axis=-1)
+    dd = jnp.sum(do3.astype(jnp.float32) * reshape(out).astype(jnp.float32),
+                 axis=-1, keepdims=True)                    # (bh, t, 1)
     interp = not _on_tpu()
-    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd3, causal,
+    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd, causal,
                               block_q, block_k, interp)
-    dk, dv = _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse, dd3, causal,
-                                   block_q, block_k, interp)
+    as_row = lambda a: a.reshape(b * h, 1, t)
+    dk, dv = _pallas_flash_bwd_dkv(q3, k3, v3, do3, as_row(lse), as_row(dd),
+                                   causal, block_q, block_k, interp)
     unshape = lambda a, like: a.reshape(b, h, t, d).astype(like.dtype)
     return unshape(dq, q), unshape(dk, k), unshape(dv, v)
 

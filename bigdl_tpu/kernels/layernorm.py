@@ -28,6 +28,15 @@ def _reference_layer_norm(x, gamma, beta, eps):
     return (x - mean) * inv * gamma + beta
 
 
+def out_struct(shape, dtype, *inputs):
+    """A kernel output's ``ShapeDtypeStruct``. Under ``shard_map`` (pipeline
+    stages, ring attention) ``pallas_call`` must be told which manual mesh
+    axes its output varies over: the union of its inputs'. Outside
+    ``shard_map`` that set is empty."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 def _pallas_layer_norm(x2d, gamma, beta, eps, block_rows, interpret):
     from jax.experimental import pallas as pl
 
@@ -40,26 +49,41 @@ def _pallas_layer_norm(x2d, gamma, beta, eps, block_rows, interpret):
         inv = jax.lax.rsqrt(var + eps)
         o_ref[:] = ((x - mean) * inv * g_ref[:] + b_ref[:]).astype(o_ref.dtype)
 
-    grid = (n // block_rows,)
+    # the reference's promotion (bf16 x under fp32 gamma gives fp32): the
+    # backward is the reference's VJP, and its cotangent must have this dtype
+    out_dtype = jnp.result_type(x2d.dtype, gamma.dtype, beta.dtype)
+    # gamma/beta ride as (1, H) rows: they broadcast down the sublanes of the
+    # (block_rows, H) tile with no in-kernel 1-D -> 2-D reshape. A last
+    # block that overhangs n reads padding and has its overhang dropped on
+    # write; rows are independent, so what the padding holds never matters.
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((n, h), x2d.dtype),
-        grid=grid,
+        out_shape=out_struct((n, h), out_dtype, x2d, gamma, beta),
+        grid=(pl.cdiv(n, block_rows),),
         in_specs=[
             pl.BlockSpec((block_rows, h), lambda i: (i, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-            pl.BlockSpec((h,), lambda i: (0,)),
+            pl.BlockSpec((1, h), lambda i: (0, 0)),
+            pl.BlockSpec((1, h), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, h), lambda i: (i, 0)),
         interpret=interpret,
-    )(x2d, gamma, beta)
+        name="bigdl_layer_norm",
+    )(x2d, gamma.reshape(1, h), beta.reshape(1, h))
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _row_block(n: int, h: int, itemsize: int) -> int:
+    """Rows per tile. Mosaic wants a block's second-to-last dim to be a
+    multiple of the sublane tile (8 rows of 32-bit, 16 of 16-bit) or to span
+    the array, so: the whole array when it has few rows, else up to 256 rows
+    rounded down to that multiple, held to ~2 MB of fp32 per tile so wide H
+    still fits VMEM double-buffered."""
+    sublane = 8 * max(1, 4 // itemsize)
+    rows = max(sublane, min(256, (1 << 19) // h) // sublane * sublane)
+    return n if n <= rows else rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -67,31 +91,19 @@ def fused_layer_norm(x, gamma, beta, eps: float = 1e-5,
                      force_pallas: bool | None = None):
     """LayerNorm over the last axis. ``force_pallas``: None = pallas on TPU,
     reference jnp elsewhere; True = pallas (interpreted off-TPU — tests);
-    False = reference."""
+    False = reference. On TPU a kernel that does not build raises."""
     return _fln_fwd(x, gamma, beta, eps, force_pallas)[0]
 
 
 def _fln_fwd(x, gamma, beta, eps, force_pallas):
     use_pallas = _on_tpu() if force_pallas is None else force_pallas
-    h = x.shape[-1]
-    lead = x.shape[:-1]
-    out = None
     if use_pallas:
-        n = 1
-        for d in lead:
-            n *= d
-        x2d = x.reshape(n, h)
-        # block over rows: biggest power-of-two divisor up to 256 keeps the
-        # tile in VMEM for any realistic H while aligning to the 8-sublane tile
-        block = 1
-        while block < 256 and n % (block * 2) == 0:
-            block *= 2
-        try:
-            out = _pallas_layer_norm(x2d, gamma, beta, eps, block,
-                                     interpret=not _on_tpu()).reshape(x.shape)
-        except Exception:  # pallas unavailable (platform/version) → reference
-            out = None
-    if out is None:
+        h = x.shape[-1]
+        x2d = x.reshape(-1, h)
+        block = _row_block(x2d.shape[0], h, x.dtype.itemsize)
+        out = _pallas_layer_norm(x2d, gamma, beta, eps, block,
+                                 interpret=not _on_tpu()).reshape(x.shape)
+    else:
         out = _reference_layer_norm(x, gamma, beta, eps)
     return out, (x, gamma, beta)
 

@@ -708,7 +708,7 @@ class ImageNormalize(TensorModule):
 
     The TPU-native input path (SURVEY.md §2.2 redesign): the reference's
     pipeline normalizes on the CPU and ships float32 activations to the
-    compute tier; on TPU the wire (PCIe/tunnel) is the scarce resource, so the
+    compute tier; on TPU the host-to-device wire is the scarce resource, so the
     feed stays ``uint8`` (4x fewer bytes than fp32) and this layer casts +
     normalizes on device, where XLA fuses it into the first convolution's
     epilogue at zero marginal cost. Defaults are the ImageNet mean/std in

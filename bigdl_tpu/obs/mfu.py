@@ -54,6 +54,13 @@ _UNSET = object()
 _peak_cache = _UNSET       # cached table lookup for this process's backend
 
 
+def table_lookup(table, device_kind: Optional[str]) -> Optional[float]:
+    """First entry of a ``(substring, value)`` table whose substring occurs in
+    ``device_kind`` (case-insensitive), or None."""
+    kind = (device_kind or "").lower()
+    return next((v for sub, v in table if sub in kind), None) if kind else None
+
+
 def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
     """Peak FLOPs/s for a device kind string, or None when unknown.
 
@@ -68,13 +75,7 @@ def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
                 return v
         except ValueError:
             pass
-    if not device_kind:
-        return None
-    kind = device_kind.lower()
-    for sub, peak in PEAK_FLOPS:
-        if sub in kind:
-            return peak
-    return None
+    return table_lookup(PEAK_FLOPS, device_kind)
 
 
 def device_peak() -> Optional[float]:

@@ -90,11 +90,7 @@ def _eval_unroll(k: int) -> int:
     XLA while-loop bodies codegen ~2x slower, rolled scan on TPU."""
     raw = os.environ.get("BIGDL_FUSE_UNROLL", "auto").strip().lower()
     if raw in ("auto", ""):
-        try:
-            platform = Engine.devices()[0].platform
-        except Exception:
-            platform = "cpu"
-        return k if platform == "cpu" else 1
+        return k if Engine.devices()[0].platform == "cpu" else 1
     return max(1, min(int(raw), k))
 
 

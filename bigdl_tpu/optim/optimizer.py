@@ -948,11 +948,7 @@ class Optimizer:
         such penalty and compile time scales with unroll x body size."""
         raw = os.environ.get("BIGDL_FUSE_UNROLL", "auto").strip().lower()
         if raw in ("auto", ""):
-            try:
-                platform = Engine.devices()[0].platform
-            except Exception:
-                platform = "cpu"
-            return k if platform == "cpu" else 1
+            return k if Engine.devices()[0].platform == "cpu" else 1
         return max(1, min(int(raw), k))
 
     def _wrap_checkify_window(self, window):

@@ -26,6 +26,24 @@ from typing import Any, Optional, Sequence
 logger = logging.getLogger("bigdl_tpu")
 
 
+def place_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one place and return
+    it. ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself and nothing
+    is set here. Otherwise the cache goes to ``.jax_cache`` beside the
+    package — a fixed path, because the path is part of what a later process
+    must reproduce to hit. Called once, from ``import bigdl_tpu``, so every
+    entry point (trainer, serving engine, bench, CLI) passes it before its
+    first compile."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        pkg_parent = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(pkg_parent, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
 def _env(name: str, default: str | None = None) -> str | None:
     """Read a ``BIGDL_*`` property from the environment (the Python-native tier replacing
     the reference's ``bigdl.*`` JVM system properties, SURVEY.md §5.6). ``name`` must
@@ -107,20 +125,7 @@ class Engine:
         """
         import jax
 
-        # Some images preload jax._src at interpreter startup, which can swallow a
-        # JAX_PLATFORMS set for this process before jax reads it. Re-assert platform
-        # selection here (harmless no-op once a backend is already live).
         resolved_backend = backend or _env("BIGDL_BACKEND", "auto")
-        platforms = None
-        if resolved_backend in ("cpu", "tpu"):
-            platforms = resolved_backend
-        elif os.environ.get("JAX_PLATFORMS"):
-            platforms = os.environ["JAX_PLATFORMS"]
-        if platforms:
-            try:
-                jax.config.update("jax_platforms", platforms)
-            except Exception:
-                pass  # backend already initialized — selection is final
 
         with _STATE.lock:
             if _STATE.initialized:
@@ -155,18 +160,11 @@ class Engine:
                 # bootstrap (SURVEY.md §5.8) with jax.distributed. Only legal once per
                 # process, so re-inits skip it.
                 if resolved_backend in (None, "cpu"):
-                    # cross-process CPU collectives need the gloo transport;
-                    # JAX_CPU_COLLECTIVES_IMPLEMENTATION is latched when
-                    # jax._src first imports, which site hooks can trigger
-                    # before the caller's env is set — the config API still
-                    # works as long as the backend is not yet initialized
-                    try:
-                        jax.config.update(
-                            "jax_cpu_collectives_implementation",
-                            os.environ.get(
-                                "JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo"))
-                    except Exception:
-                        pass  # backend already up — keep its collectives
+                    # cross-process CPU collectives need the gloo transport
+                    jax.config.update(
+                        "jax_cpu_collectives_implementation",
+                        os.environ.get(
+                            "JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo"))
                 jax.distributed.initialize(
                     coordinator_address=coordinator_address,
                     num_processes=node_number,
@@ -176,6 +174,13 @@ class Engine:
                 _STATE.distributed_client_live = True
 
             devices = cls._discover_devices_bounded(cfg.backend)
+            if devices[0].platform == "cpu" and cfg.backend != "cpu" \
+                    and os.environ.get("JAX_PLATFORMS") != "cpu":
+                logger.warning(
+                    "Engine.init(backend=%r) found no accelerator and is "
+                    "running on %d CPU device(s); set JAX_PLATFORMS=cpu or "
+                    "backend='cpu' if that is intended", cfg.backend,
+                    len(devices))
             cfg.node_number = node_number or jax.process_count()
             cfg.core_number = core_number or jax.local_device_count()
             if core_number is not None:

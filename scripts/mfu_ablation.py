@@ -1,6 +1,6 @@
 """MFU ablation harness: where does the ResNet-50 step time go?
 
-VERDICT r3 item 1 / r4 follow-up: the headline step is at MFU ~0.30 with
+Round-3/4 review follow-up: the headline step is at MFU ~0.30 with
 ~1.5x headroom vs tuned TPU ResNet implementations. This script decomposes the
 compiled step into its phases and sweeps the knobs that plausibly matter, each
 measured as a SEPARATE jitted program on the live chip:

@@ -1,7 +1,7 @@
 """bench --model transformerlm-long (round-4 verdict #3): the long-context
 TRAINING leg emits one JSON line carrying tokens/sec, the sequence length,
 and the attention implementation under test. Tiny T on CPU keeps it a
-contract test; the real T=4096/8192 numbers come from the relay sweep."""
+contract test; T=4096/8192 on the chip is not measured (ROADMAP S5)."""
 
 import json
 import os

@@ -1,0 +1,221 @@
+"""Every Pallas kernel must lower for the TPU — checked from the CPU.
+
+``jax.export`` with ``platforms=["tpu"]`` runs the Pallas→Mosaic lowering
+(block-shape rules included) without a chip. It does not run the Mosaic
+compiler itself — only ``chip_smoke.py`` on the chip does — but it is the check
+that catches an illegal BlockSpec, which is how the flash-2 residual and the
+6/12-row LayerNorm once went unnoticed behind interpret-mode tests.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bigdl_tpu.kernels import flash_attention as fa
+from bigdl_tpu.kernels import layernorm as ln
+
+FLASH_KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd_dq", "bigdl_flash_bwd_dkv")
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Take the kernels' TPU branch (compiled, not interpreted)."""
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ln, "_on_tpu", lambda: True)
+
+
+def _tpu_module(fn, *avals) -> str:
+    return jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals) \
+        .mlir_module()
+
+
+def _kernel_calls(text: str, name: str) -> int:
+    return text.count(f'kernel_name = "{name}"')
+
+
+# --------------------------------------------------------------- flash
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_lowers_at_bench_shape(on_tpu, dtype, causal):
+    """Forward and both backward kernels at the bench LM's shape."""
+    q = jax.ShapeDtypeStruct((16, 8, 512, 64), dtype)
+    fwd = _tpu_module(lambda a, b, c: fa.flash_attention(a, b, c, causal),
+                      q, q, q)
+    assert _kernel_calls(fwd, "bigdl_flash_fwd") == 1
+    grad = _tpu_module(
+        jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, causal)
+                 .astype(jnp.float32).sum(), argnums=(0, 1, 2)), q, q, q)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(grad, name) == 1, name
+
+
+@pytest.mark.parametrize("t", [8, 24, 64, 384, 640, 8192])
+def test_flash_lowers_across_tilings(on_tpu, t):
+    """Whole-axis tiles (T <= target) and multiples of 128 beyond it."""
+    q = jax.ShapeDtypeStruct((1, 2, t, 64), jnp.bfloat16)
+    grad = _tpu_module(
+        jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, True)
+                 .astype(jnp.float32).sum(), argnums=(0, 1, 2)), q, q, q)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(grad, name) == 1, name
+
+
+@pytest.mark.parametrize("t,target,want", [
+    (512, 256, 256), (512, 512, 512), (512, 128, 128),   # the bench LM
+    (64, 256, 64), (24, 128, 24), (8, 128, 8),           # whole axis
+    (384, 256, 128), (640, 512, 128), (8192, 512, 512),
+    (15, 128, None), (4, 128, None),                     # not a sublane multiple
+    (200, 128, None), (264, 256, None), (1000, 256, None),  # no 128-multiple
+])
+def test_pick_block_rule(t, target, want):
+    block = fa._pick_block(t, target)
+    assert block == want
+    if block is not None:
+        assert t % block == 0 and (block == t or block % 128 == 0)
+
+
+@pytest.mark.parametrize("t", [15, 200])
+def test_untileable_length_is_reference_by_rule(on_tpu, t):
+    """A T no legal tile covers runs the jnp reference by an explicit rule —
+    on the TPU branch too, and with no kernel in the program."""
+    q = jax.ShapeDtypeStruct((1, 2, t, 8), jnp.float32)
+    text = _tpu_module(lambda a, b, c: fa.flash_attention(a, b, c, True),
+                       q, q, q)
+    assert "tpu_custom_call" not in text
+
+
+# ----------------------------------------------------------- layer norm
+@pytest.mark.parametrize("h", [64, 512])
+@pytest.mark.parametrize("rows", [1, 5, 6, 12, 13, 24, 300, 8192])
+def test_layer_norm_lowers(on_tpu, rows, h):
+    g = jax.ShapeDtypeStruct((h,), jnp.float32)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jax.ShapeDtypeStruct((rows, h), dtype)
+        text = _tpu_module(lambda a, b, c: ln.fused_layer_norm(a, b, c),
+                           x, g, g)
+        assert _kernel_calls(text, "bigdl_layer_norm") == 1
+
+
+@pytest.mark.parametrize("rows,h,itemsize,want", [
+    (6, 512, 4, 6), (12, 512, 4, 12), (13, 512, 2, 13),   # whole array
+    (8192, 512, 4, 256), (300, 64, 2, 256),
+    (8192, 8192, 4, 64), (8192, 8192, 2, 64),             # wide rows: VMEM
+    (8192, 1 << 20, 2, 16), (8192, 1 << 20, 4, 8),        # never below a tile
+])
+def test_row_block_rule(rows, h, itemsize, want):
+    block = ln._row_block(rows, h, itemsize)
+    assert block == want
+    sublane = 8 * (4 // itemsize)
+    assert block == rows or block % sublane == 0
+
+
+@pytest.mark.parametrize("rows", [6, 13, 300])
+def test_layer_norm_ragged_rows_match_reference(rows):
+    """Whole-array blocks and an overhanging last block (300 = 256 + 44),
+    through the interpreter."""
+    rng = np.random.default_rng(rows)
+    x = jnp.asarray(rng.normal(size=(rows, 64)).astype(np.float32))
+    g = jnp.asarray(rng.normal(size=(64,)).astype(np.float32))
+    b = jnp.asarray(rng.normal(size=(64,)).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(ln.fused_layer_norm(x, g, b, 1e-5, True)),
+        np.asarray(ln._reference_layer_norm(x, g, b, 1e-5)),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_layer_norm_kernel_keeps_the_reference_dtype():
+    """bf16 activations under fp32 gamma/beta: the kernel must return what
+    the reference returns (fp32), or the reference-VJP backward rejects the
+    cotangent — found on the chip, where the kernel is the default path."""
+    x = jnp.ones((16, 32), jnp.bfloat16)
+    g, b = jnp.ones((32,), jnp.float32), jnp.zeros((32,), jnp.float32)
+    ref = ln._reference_layer_norm(x, g, b, 1e-5)
+    assert ln.fused_layer_norm(x, g, b, 1e-5, True).dtype == ref.dtype
+    dx = jax.grad(lambda a: ln.fused_layer_norm(a, g, b, 1e-5, True)
+                  .astype(jnp.float32).sum())(x)
+    assert dx.dtype == x.dtype and dx.shape == x.shape
+
+
+# ------------------------------------------------------ under shard_map
+def _mesh_2x2():
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "pipe"))
+
+
+def test_layer_norm_lowers_inside_shard_map(on_tpu):
+    """Pipeline stages run LayerNorm inside shard_map, where pallas_call must
+    declare which mesh axes its output varies over (``out_struct``). Found on
+    four real chips: until then the silent fallback had hidden it."""
+    from jax.sharding import PartitionSpec as P
+    f = jax.shard_map(lambda x, g, b: ln.fused_layer_norm(x, g, b),
+                      mesh=_mesh_2x2(), in_specs=(P("data"), P(), P()),
+                      out_specs=P("data"))
+    x = jax.ShapeDtypeStruct((8, 16, 32), jnp.float32)
+    g = jax.ShapeDtypeStruct((32,), jnp.float32)
+    assert _kernel_calls(_tpu_module(f, x, g, g), "bigdl_layer_norm") == 1
+    # the backward is the reference VJP: it only has to trace and lower
+    _tpu_module(jax.grad(lambda x, g, b: jnp.square(f(x, g, b)).sum(),
+                         argnums=(0, 1)), x, g, g)
+
+
+def test_flash_lowers_inside_shard_map(on_tpu):
+    from jax.sharding import PartitionSpec as P
+    f = jax.shard_map(lambda q, k, v: fa.flash_attention(q, k, v, True),
+                      mesh=_mesh_2x2(), in_specs=(P("data"),) * 3,
+                      out_specs=P("data"))
+    q = jax.ShapeDtypeStruct((4, 2, 16, 8), jnp.float32)
+    grad = _tpu_module(jax.grad(lambda a, b, c: f(a, b, c).sum(),
+                                argnums=(0, 1, 2)), q, q, q)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(grad, name) == 1, name
+
+
+# ------------------------------------------------- no fallback on TPU
+class _Boom(RuntimeError):
+    pass
+
+
+def _boom(*a, **k):
+    raise _Boom("kernel build failed")
+
+
+def _qkv(t=16):
+    return (jnp.ones((1, 2, t, 8), jnp.float32),) * 3
+
+
+def test_flash_forward_build_error_raises_on_tpu(on_tpu, monkeypatch):
+    monkeypatch.setattr(fa, "_pallas_flash_call", _boom)
+    with pytest.raises(_Boom):
+        fa.flash_attention(*_qkv(), True)
+
+
+@pytest.mark.parametrize("kernel", ["_pallas_flash_bwd_dq",
+                                    "_pallas_flash_bwd_dkv"])
+def test_flash_backward_build_error_raises(monkeypatch, kernel):
+    # forward through the interpreter (force_pallas=True off-TPU), then the
+    # backward kernel fails to build: the reference VJP must not take over
+    monkeypatch.setattr(fa, kernel, _boom)
+    with pytest.raises(_Boom):
+        jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, True, True)
+                 .sum(), argnums=(0, 1, 2))(*_qkv())
+
+
+def test_layer_norm_build_error_raises_on_tpu(on_tpu, monkeypatch):
+    monkeypatch.setattr(ln, "_pallas_layer_norm", _boom)
+    with pytest.raises(_Boom):
+        ln.fused_layer_norm(jnp.ones((6, 32)), jnp.ones((32,)),
+                            jnp.zeros((32,)))
+
+
+def test_off_tpu_default_is_the_reference():
+    """Off TPU nothing changes: force_pallas=None computes the reference,
+    bit for bit."""
+    q, k, v = _qkv()
+    np.testing.assert_array_equal(
+        np.asarray(fa.flash_attention(q, k, v, True)),
+        np.asarray(fa._reference_attention(q, k, v, True)))
+    x, g, b = jnp.ones((6, 32)) * 2, jnp.ones((32,)), jnp.zeros((32,))
+    np.testing.assert_array_equal(
+        np.asarray(ln.fused_layer_norm(x, g, b)),
+        np.asarray(ln._reference_layer_norm(x, g, b, 1e-5)))

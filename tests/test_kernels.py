@@ -395,9 +395,9 @@ def test_probe_backend_success_no_retries():
     assert err is None and sleeps == []
 
 
-def test_degraded_record_carries_probe_error(capsys):
-    """The orchestrator's special-leg failure record says degraded + why —
-    the r04/r05 silent-CPU-LeNet failure mode must be impossible."""
+def test_failed_probe_is_reported_and_nothing_stands_in(capsys):
+    """A backend that does not answer: the orchestrator says so, exits
+    non-zero and measures nothing — no CPU LeNet line in its place."""
     import argparse
     import json as _json
 
@@ -414,18 +414,19 @@ def test_degraded_record_carries_probe_error(capsys):
     old = {k: os.environ.get(k) for k in env}
     os.environ.update(env)
     try:
-        benchmark.run_orchestrator(args)
+        rc = benchmark.run_orchestrator(args)
     finally:
         for k, v in old.items():
             if v is None:
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    rec = _json.loads(line)
-    assert rec["degraded"] is True
-    assert rec.get("probe_error")
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert rc == 1 and len(lines) == 1      # the failure record, nothing else
+    rec = _json.loads(lines[0])
+    assert rec["value"] is None and rec.get("probe_error")
     assert "kernel_bench" in rec["metric"]
+    assert "degraded" not in rec and "platform" not in rec
 
 
 # ------------------------------------------------------------- bench leg

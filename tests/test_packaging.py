@@ -127,8 +127,12 @@ class TestCliBench:
         import bigdl_tpu.benchmark as bm
         from bigdl_tpu.cli import main
         called = {}
-        monkeypatch.setattr(bm, "run_orchestrator",
-                            lambda args: called.setdefault("model", args.model))
+
+        def orchestrator(args):
+            called["model"] = args.model
+            return 0
+
+        monkeypatch.setattr(bm, "run_orchestrator", orchestrator)
         monkeypatch.setattr("sys.argv", ["bigdl-tpu", "bench"])
         assert main(["bench"]) == 0
         assert called["model"] == "resnet50"
@@ -165,8 +169,11 @@ class TestCliBenchArgs:
         import bigdl_tpu.benchmark as bm
         from bigdl_tpu.cli import main
         seen = {}
-        monkeypatch.setattr(bm, "run_orchestrator",
-                            lambda args: seen.update(model=args.model,
-                                                     iters=args.iters))
-        assert main(["bench", "--model", "lenet", "--iters", "5"]) == 0
+
+        def orchestrator(args):
+            seen.update(model=args.model, iters=args.iters)
+            return 3       # the CLI hands the bench's exit code through
+
+        monkeypatch.setattr(bm, "run_orchestrator", orchestrator)
+        assert main(["bench", "--model", "lenet", "--iters", "5"]) == 3
         assert seen == {"model": "lenet", "iters": 5}

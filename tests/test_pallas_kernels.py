@@ -187,7 +187,7 @@ class TestFlashAttention:
 
 
 class TestFlashBackwardMemory:
-    """VERDICT r3 item 4 done-criterion: training at long T must not scale
+    """Training at long T must not scale
     O(T^2). Pinned by shape math — the traced grad program may not contain
     ANY (T, T)-shaped intermediate on the flash path (the reference-VJP path
     materialises scores/probs at exactly that shape, so the assertion

@@ -1,13 +1,20 @@
 """Thread-aware span tracer — Chrome-trace export + JSONL event log.
 
-``span("train/step")`` context managers record (name, thread, start, duration)
-tuples; nesting is implicit per thread (Chrome/Perfetto reconstruct the tree
-from time containment of same-``tid`` events, and :func:`open_spans` exposes
-the live per-thread stacks for the hang watchdog). Two outputs:
+``span("train/step")`` context managers record a :class:`SpanRecord` each:
+name, thread, start in Unix nanoseconds, duration, the span open beneath it
+on its thread (``parent``) and ``args``. The Unix start puts a span on the
+clock of a ``jax.profiler`` trace with no host tracer at all: the xplane's
+``Task Environment`` plane carries ``profile_start_time`` in Unix nanoseconds
+and every event's offset is relative to it. :func:`open_spans` exposes the
+live per-thread stacks for the hang watchdog. The newest ``_MAX_SPANS``
+records are kept (a ring; what falls out is counted), and
+:func:`spans_between` is their one reader. Two outputs:
 
 - **Chrome trace JSON** (:func:`export_chrome`): ``X`` complete events with
   microsecond ``ts``/``dur`` per thread, plus thread-name metadata — loads
-  directly in ``chrome://tracing`` / Perfetto.
+  directly in ``chrome://tracing`` / Perfetto. ``ts`` counts from
+  ``otherData.ts_zero_unix_ns``, so ``profile_start_time - ts_zero_unix_ns``
+  shifts a profiler trace onto it.
 - **JSONL event log** (:func:`event`): one JSON object per line for
   *structured* occurrences — watchdog dumps, robustness events, the end-of-run
   report — written immediately (a hung process must already have its dump on
@@ -23,14 +30,35 @@ nothing — pinned by a counting test on ``_SPANS_CREATED``.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
-#: finished-span buffer bound; beyond it spans are counted, not stored
+#: finished-span ring: the newest this many are kept, older ones are counted
 _MAX_SPANS = 262_144
+
+#: ``jax.named_scope`` names of the train step's phases (optim/optimizer.py
+#: ``_make_step_fn``): a device operation carries its scope in the profile's
+#: ``tf_op`` stat, where the trace readers match these
+SCOPE_CAST = "bigdl_cast"              # compute-dtype casts around the model
+SCOPE_LOSS = "bigdl_loss"              # criterion and added penalties
+SCOPE_GRAD_SCALE = "bigdl_grad_scale"  # per-layer scales, accumulation mean, clipping
+SCOPE_UPDATE = "bigdl_update"          # the optimizer method's update
+
+
+class SpanRecord(NamedTuple):
+    """One finished span, on the Unix clock."""
+    name: str
+    tid: int
+    thread: str
+    start_unix_ns: int
+    dur_ns: int
+    parent: Optional[str]   # the span open beneath it on its thread
+    args: Optional[dict]
+
 
 _lock = threading.Lock()
 _ENABLED = False
@@ -39,11 +67,19 @@ _TRACE_DIR: Optional[str] = None
 _JSONL_PATH: Optional[str] = None
 _JSONL_FILE = None
 
-_finished: list = []       # (name, tid, t0_s, dur_s, args)
+_finished: collections.deque = collections.deque(maxlen=_MAX_SPANS)  # SpanRecord
 _dropped = 0
 _totals: dict = {}         # name -> [count, total_seconds]
 _threads: dict = {}        # tid -> thread name (as of first span)
-_open_stacks: dict = {}    # tid -> [(name, t0_s), ...] — owner-thread writes
+_open_stacks: dict = {}    # tid -> [(name, t0_ns), ...] — owner-thread writes
+#: Unix time of ``perf_counter_ns() == 0``, taken when tracing is configured:
+#: a span reads ``perf_counter_ns`` alone
+_UNIX_OFFSET_NS = 0
+
+
+def _sync_clock() -> None:
+    global _UNIX_OFFSET_NS
+    _UNIX_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
 
 #: _Span instances ever constructed — the zero-alloc-when-disabled pin
 _SPANS_CREATED = 0
@@ -60,6 +96,7 @@ def configure(enabled: Optional[bool] = None, trace_dir: Optional[str] = None,
     global _ENABLED, _EXPLICIT, _TRACE_DIR, _JSONL_PATH
     with _lock:
         _EXPLICIT = True
+        _sync_clock()
         if trace_dir is not None:
             _TRACE_DIR = trace_dir
         if enabled is not None:
@@ -80,6 +117,7 @@ def configure_from_env() -> None:
     with _lock:
         if _EXPLICIT:
             return
+        _sync_clock()
         _ENABLED = _truthy(os.environ.get("BIGDL_TRACE"))
         env_dir = os.environ.get("BIGDL_TRACE_DIR")
         if env_dir:
@@ -141,12 +179,18 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def begin(self, t0_ns):
+        pass
+
+    def end(self, t1_ns):
+        pass
+
 
 _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0", "_tid")
+    __slots__ = ("name", "args", "_t0", "_tid", "_parent")
 
     def __init__(self, name: str, args):
         global _SPANS_CREATED
@@ -155,6 +199,17 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self.begin(time.perf_counter_ns())
+        return self
+
+    def __exit__(self, *exc):
+        self.end(time.perf_counter_ns())
+        return False
+
+    def begin(self, t0_ns: int) -> None:
+        """Open the span at a ``perf_counter_ns`` reading the caller already
+        took (``optim/metrics.py`` times a phase and its span off one clock
+        read an edge)."""
         tid = threading.get_ident()
         self._tid = tid
         stack = _open_stacks.get(tid)
@@ -162,30 +217,29 @@ class _Span:
             # first span on this thread: register its name for the trace
             _open_stacks[tid] = stack = []
             _threads[tid] = threading.current_thread().name
-        self._t0 = time.perf_counter()
-        stack.append((self.name, self._t0))
-        return self
+        self._parent = stack[-1][0] if stack else None
+        self._t0 = t0_ns
+        stack.append((self.name, t0_ns))
 
-    def __exit__(self, *exc):
+    def end(self, t1_ns: int) -> None:
         global _dropped
-        t1 = time.perf_counter()
         stack = _open_stacks.get(self._tid)
         if stack:
             stack.pop()
-        dur = t1 - self._t0
+        dur = t1_ns - self._t0
+        rec = SpanRecord(self.name, self._tid, _threads.get(self._tid, "?"),
+                         self._t0 + _UNIX_OFFSET_NS, dur, self._parent,
+                         self.args)
         with _lock:
             tot = _totals.get(self.name)
             if tot is None:
-                _totals[self.name] = [1, dur]
+                _totals[self.name] = [1, dur / 1e9]
             else:
                 tot[0] += 1
-                tot[1] += dur
-            if len(_finished) < _MAX_SPANS:
-                _finished.append((self.name, self._tid, self._t0, dur,
-                                  self.args))
-            else:
-                _dropped += 1
-        return False
+                tot[1] += dur / 1e9
+            if len(_finished) == _finished.maxlen:
+                _dropped += 1       # the ring pushes its oldest out
+            _finished.append(rec)
 
 
 def span(name: str, args: Optional[dict] = None):
@@ -206,13 +260,28 @@ def span_totals() -> dict:
                 for name, (c, t) in _totals.items()}
 
 
+def spans_between(t0_unix_ns: int = 0, t1_unix_ns: Optional[int] = None) -> list:
+    """The kept :class:`SpanRecord` s that overlap ``[t0, t1]`` (Unix
+    nanoseconds; no ``t1``: up to now), oldest start first. The one reader of
+    the ring: a trace reader hands it a profile's extent
+    (``profile_start_time`` plus the events' offsets) and gets the program's
+    spans on that clock."""
+    with _lock:
+        spans = list(_finished)
+    if t1_unix_ns is None:
+        t1_unix_ns = time.time_ns()
+    return sorted((r for r in spans if r.start_unix_ns <= t1_unix_ns
+                   and r.start_unix_ns + r.dur_ns >= t0_unix_ns),
+                  key=lambda r: r.start_unix_ns)
+
+
 def open_spans() -> dict:
     """Live per-thread open-span stacks (outermost first) with ages — the
     watchdog's view of what every thread is in the middle of."""
-    now = time.perf_counter()
+    now = time.perf_counter_ns()
     out = {}
     for tid, stack in list(_open_stacks.items()):
-        entries = [{"name": n, "age_ms": round((now - t0) * 1e3, 1)}
+        entries = [{"name": n, "age_ms": round((now - t0) / 1e6, 1)}
                    for n, t0 in list(stack)]
         if entries:
             out[f"{_threads.get(tid, '?')} ({tid})"] = entries
@@ -260,7 +329,7 @@ def read_events(path: str) -> list:
 def export_chrome(path: Optional[str] = None) -> Optional[str]:
     """Write every finished span as a Chrome-trace JSON file (``X`` complete
     events, per-thread ``tid``, thread-name metadata). Returns the path, or
-    None when tracing is disabled. Idempotent — the span buffer is kept."""
+    None when tracing is disabled. Idempotent — the span ring is kept."""
     if not _ENABLED:
         return None
     path = path or chrome_path()
@@ -268,25 +337,24 @@ def export_chrome(path: Optional[str] = None) -> Optional[str]:
     if d:
         os.makedirs(d, exist_ok=True)
     pid = os.getpid()
-    with _lock:
-        spans = list(_finished)
-        threads = dict(_threads)
-        dropped = _dropped
+    spans = spans_between()
+    zero = _UNIX_OFFSET_NS      # ts counts from perf_counter's zero
     events = [{"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
                "args": {"name": "bigdl-tpu"}}]
-    for tid, name in threads.items():
+    for tid, name in dict(_threads).items():
         events.append({"ph": "M", "name": "thread_name", "pid": pid,
                        "tid": tid, "args": {"name": name}})
-    for name, tid, t0, dur, args in spans:
-        ev = {"name": name, "ph": "X", "cat": "bigdl",
-              "ts": round(t0 * 1e6, 3), "dur": round(dur * 1e6, 3),
-              "pid": pid, "tid": tid}
-        if args:
-            ev["args"] = args
+    for r in spans:
+        ev = {"name": r.name, "ph": "X", "cat": "bigdl",
+              "ts": (r.start_unix_ns - zero) / 1e3, "dur": r.dur_ns / 1e3,
+              "pid": pid, "tid": r.tid}
+        if r.args:
+            ev["args"] = r.args
         events.append(ev)
     with open(path, "w") as f:
-        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-    event("trace_exported", path=path, spans=len(spans), dropped=dropped)
+        json.dump({"traceEvents": events, "displayTimeUnit": "ms",
+                   "otherData": {"ts_zero_unix_ns": zero}}, f)
+    event("trace_exported", path=path, spans=len(spans), dropped=_dropped)
     return path
 
 

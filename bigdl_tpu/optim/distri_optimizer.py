@@ -212,16 +212,16 @@ class DistriOptimizer(Optimizer):
         target = self._put_sharded(batch.target, self._batch_sh)
         return inp, target
 
-    def _place_window(self, batches):
+    def _stack_and_cast(self, batches):
         n_dev = int(dict(self._mesh.shape)[Engine.DATA_AXIS])
         for b in batches:
             if b.size() % n_dev != 0:
                 raise ValueError(
                     f"batch size {b.size()} not divisible by data-parallel "
                     f"size {n_dev}")
-        inp = jax.tree_util.tree_map(
-            self._feed_cast, self._stack_window([b.input for b in batches]))
-        target = self._stack_window([b.target for b in batches])
+        return super()._stack_and_cast(batches)
+
+    def _place_window(self, inp, target):
         return (self._put_sharded(inp, self._window_sh),
                 self._put_sharded(target, self._window_sh))
 

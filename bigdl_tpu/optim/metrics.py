@@ -36,8 +36,12 @@ class Metrics:
             self._counts[name] += 1
         _obs_registry.histogram("phase/" + name).observe(seconds)
 
-    def timer(self, name: str):
-        return _Timer(self, name)
+    def timer(self, name: str, span=None):
+        """Time a phase; ``span`` (a ``trace.span(...)``) opens and closes on
+        the same two clock reads, and ``.seconds`` holds the interval
+        afterwards: one measurement for the phase, the span and whoever else
+        asks."""
+        return _Timer(self, name, span)
 
     def summary(self) -> dict[str, float]:
         """Mean seconds per phase occurrence."""
@@ -66,13 +70,20 @@ class Metrics:
 
 
 class _Timer:
-    def __init__(self, metrics: Metrics, name: str):
-        self.metrics, self.name = metrics, name
+    def __init__(self, metrics: Metrics, name: str, span):
+        self.metrics, self.name, self.span = metrics, name, span
+        self.seconds = 0.0
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        self.t0 = time.perf_counter_ns()
+        if self.span is not None:
+            self.span.begin(self.t0)
         return self
 
     def __exit__(self, *exc):
-        self.metrics.add(self.name, time.perf_counter() - self.t0)
+        t1 = time.perf_counter_ns()
+        if self.span is not None:
+            self.span.end(t1)
+        self.seconds = (t1 - self.t0) / 1e9
+        self.metrics.add(self.name, self.seconds)
         return False

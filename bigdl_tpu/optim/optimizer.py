@@ -18,9 +18,11 @@ accumulated on device and trigger boundaries kept exact (``Trigger.next_fire_in`
 
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import os
+import queue
 import re
 import sys
 import threading
@@ -212,6 +214,12 @@ class Optimizer:
         self.log_every: int = 1
         from bigdl_tpu.optim.metrics import Metrics
         self.metrics = Metrics()
+        # sequence number of the window (or batch) the feed is placing: it
+        # rides the producer's spans and the dispatch span that consumes it
+        self._feed_seq: int = 0
+        # queue of the thread that waits for copies in flight while spans
+        # are on (`_watch_copy`); None while no such thread runs
+        self._copy_watch = None
         # feed pipeline depth (placed batches in flight); 0 = synchronous
         self.prefetch_depth: int = int(os.environ.get("BIGDL_PREFETCH", "2"))
         # jax.profiler trace window (set_profile / BIGDL_PROFILE_DIR)
@@ -745,26 +753,44 @@ class Optimizer:
         remat_policy = (jax.checkpoint_policies.checkpoint_dots
                         if remat == "dots" else None)
 
+        # the step's phases carry jax.named_scope names (obs/trace.py SCOPE_*):
+        # a profile bills each device operation to its phase. Metadata only:
+        # the compiled program is the same
+        def scale_and_clip(grads, row_grads=None):
+            with jax.named_scope(trace.SCOPE_GRAD_SCALE):
+                if scale_tree is not None:
+                    # sparse plan entries require scale 1.0 on the table
+                    # weight, so only the dense leaves are scaled (0-size
+                    # embed leaves pass through the map unchanged)
+                    grads = jax.tree_util.tree_map(
+                        lambda g, s: g * s, grads, scale_tree)
+                if row_grads is None:
+                    return self._clip_grads(grads)
+                return self._clip_grads((grads, row_grads))
+
         def step(params, mstate, ostate, step_idx, inp, target, base_rng):
             rng0 = jax.random.fold_in(base_rng, step_idx) if needs_rng else None
 
             def loss_fn(p, ms, x, t, rng):
                 p = stop_frozen(p)
                 if mixed:
-                    p = cast_floating(p, compute_dtype)
-                    x = cast_floating(x, compute_dtype)
+                    with jax.named_scope(trace.SCOPE_CAST):
+                        p = cast_floating(p, compute_dtype)
+                        x = cast_floating(x, compute_dtype)
                 out, new_ms = model.apply(p, ms, x, training=True, rng=rng)
                 if mixed:
-                    out = cast_floating(out, jnp.float32)
-                    new_ms = cast_floating(new_ms, jnp.float32)
-                loss = criterion.apply(out, t)
-                aux, pen = collect_state_losses(new_ms)
-                if aux is not None and aux_w:
-                    loss = loss + aux_w * aux
-                if pen is not None:
-                    loss = loss + pen
-                if has_reg:  # per-layer L1/L2 weight penalties (regularizer.py)
-                    loss = loss + model.regularizer_penalty(p)
+                    with jax.named_scope(trace.SCOPE_CAST):
+                        out = cast_floating(out, jnp.float32)
+                        new_ms = cast_floating(new_ms, jnp.float32)
+                with jax.named_scope(trace.SCOPE_LOSS):
+                    loss = criterion.apply(out, t)
+                    aux, pen = collect_state_losses(new_ms)
+                    if aux is not None and aux_w:
+                        loss = loss + aux_w * aux
+                    if pen is not None:
+                        loss = loss + pen
+                    if has_reg:  # per-layer L1/L2 weight penalties (regularizer.py)
+                        loss = loss + model.regularizer_penalty(p)
                 return loss, new_ms
 
             if remat != "none":
@@ -789,16 +815,11 @@ class Optimizer:
                         (params, deltas0), mstate, inp, target, rng0)
                 uids_map, new_ms = sparse_plan.pop_uids(new_ms)
                 grads = sparse_plan.mask_embed(grads)
-                if scale_tree is not None:
-                    # plan entries require scale 1.0 on the table weight, so
-                    # only the dense leaves are scaled (0-size embed leaves
-                    # pass through the map unchanged)
-                    grads = jax.tree_util.tree_map(
-                        lambda g, s: g * s, grads, scale_tree)
-                grads, row_grads = self._clip_grads((grads, row_grads))
-                new_p, new_os = method.sparse_apply(
-                    params, grads, row_grads, uids_map, ostate, step_idx,
-                    trainable_mask)
+                grads, row_grads = scale_and_clip(grads, row_grads)
+                with jax.named_scope(trace.SCOPE_UPDATE):
+                    new_p, new_os = method.sparse_apply(
+                        params, grads, row_grads, uids_map, ostate, step_idx,
+                        trainable_mask)
                 return new_p, new_ms, new_os, loss
             if pipe_fn is not None:
                 # stages are stateless (GPipe contract) → mstate passes
@@ -864,16 +885,16 @@ class Optimizer:
                         "size_average=False.", type(criterion).__name__)
                 crit_averages = bool(getattr(criterion, "size_average", True))
                 if crit_averages:
-                    grads = jax.tree_util.tree_map(lambda g: g / accum, gsum)
+                    with jax.named_scope(trace.SCOPE_GRAD_SCALE):
+                        grads = jax.tree_util.tree_map(
+                            lambda g: g / accum, gsum)
                     loss = lsum / accum
                 else:
                     grads, loss = gsum, lsum
-            if scale_tree is not None:
-                grads = jax.tree_util.tree_map(
-                    lambda g, s: g * s, grads, scale_tree)
-            grads = self._clip_grads(grads)
-            new_p, new_os = method.update_trimmed(params, grads, ostate,
-                                                  step_idx, trainable_mask)
+            grads = scale_and_clip(grads)
+            with jax.named_scope(trace.SCOPE_UPDATE):
+                new_p, new_os = method.update_trimmed(
+                    params, grads, ostate, step_idx, trainable_mask)
             return new_p, new_ms, new_os, loss
 
         return step
@@ -1051,20 +1072,74 @@ class Optimizer:
             hit = cache.get(id(batch))
             if hit is not None and hit[0] is batch:
                 return hit[1]
-        with self.metrics.timer("put_batch"), trace.span("feed/h2d"):
-            placed = self._place_batch(batch)
+        # ring-assembled batch (SampleToMiniBatch): hand its buffers back for
+        # reuse once the device owns the bytes. PJRT may keep reading the
+        # host buffer until the transfer completes, so wait for the placed
+        # arrays HERE in the producer thread (the step loop's overlap is
+        # untouched) before the ring may overwrite them.
+        recycle = cache is None \
+            and getattr(batch, "_ring_slot", None) is not None \
+            and not _device_put_may_alias()
+        placed = self._timed_h2d(self._place_batch, (batch,), wait=recycle)
         if cache is not None:
             cache[id(batch)] = (batch, placed)
-        elif getattr(batch, "_ring_slot", None) is not None \
-                and not _device_put_may_alias():
-            # ring-assembled batch (SampleToMiniBatch): hand its buffers back
-            # for reuse once the device owns the bytes. PJRT may keep reading
-            # the host buffer until the transfer completes, so wait for the
-            # placed arrays HERE in the producer thread (the step loop's
-            # overlap is untouched) before the ring may overwrite them.
-            jax.block_until_ready(placed)
+        elif recycle:
             batch.recycle()
         return placed
+
+    def _timed_h2d(self, place, host, wait=False):
+        """``place(*host)`` under the ``put_batch`` phase, and
+        ``feed/h2d_bytes`` counts what was handed to ``device_put``.
+        ``device_put`` only enqueues the copy. The ``feed/h2d`` span runs
+        from the same clock read until the placed arrays are ready: with
+        ``wait`` the producer thread waits for that itself; otherwise, with
+        spans on, a watcher thread does and closes the span, so that the
+        producer goes on to stack the next window while the copy runs, as
+        it does with spans off (on the chip a producer that waited for its
+        own copy became the feed's bottleneck and the traced run no longer
+        looked like the job: ``PERF.md`` section 6, PR 26)."""
+        span = trace.span("feed/h2d", self._seq_args())
+        watched = trace.enabled() and not wait
+        with self.metrics.timer("put_batch", span if not watched else None) as t:
+            placed = place(*host)
+            if wait:
+                jax.block_until_ready(placed)
+        if watched:
+            self._watch_copy(span, t.t0, placed)
+        obs_registry.registry.counter("feed/h2d_bytes").inc(sum(
+            getattr(a, "nbytes", 0) for a in jax.tree_util.tree_leaves(placed)))
+        return placed
+
+    def _watch_copy(self, span, t0_ns: int, placed) -> None:
+        """Hand a copy in flight to the watcher thread (started on first
+        use, stopped by :meth:`_stop_copy_watcher` when ``optimize()`` ends):
+        it opens ``span`` at ``t0_ns`` and closes it when ``placed`` is
+        ready. Copies complete in the order they were enqueued, so one
+        thread that waits for them in turn reads each end as it happens."""
+        if self._copy_watch is None:
+            q = self._copy_watch = queue.SimpleQueue()
+
+            def watch():
+                for span, t0_ns, placed in iter(q.get, None):
+                    span.begin(t0_ns)
+                    jax.block_until_ready(placed)
+                    span.end(time.perf_counter_ns())
+
+            self._copy_watcher = threading.Thread(
+                target=watch, name="bigdl-h2d-watch", daemon=True)
+            self._copy_watcher.start()
+        self._copy_watch.put((span, t0_ns, placed))
+
+    def _stop_copy_watcher(self) -> None:
+        if self._copy_watch is not None:
+            self._copy_watch.put(None)
+            self._copy_watcher.join(timeout=30.0)  # a lost device never lands a copy
+            self._copy_watch = None
+
+    def _seq_args(self):
+        """``args`` of the spans that follow one window through the feed:
+        the sequence number `_optimize_impl` gave the window being placed."""
+        return {"seq": self._feed_seq} if trace.enabled() else None
 
     def _place_batch(self, batch: MiniBatch):
         return (jax.device_put(self._feed_cast(batch.input)),
@@ -1094,8 +1169,9 @@ class Optimizer:
             hit = cache.get(key)
             if hit is not None and all(a is b for a, b in zip(hit[0], batches)):
                 return hit[1]
-        with self.metrics.timer("put_batch"), trace.span("feed/h2d"):
-            placed = self._place_window(batches)
+        with trace.span("feed/stack_window", self._seq_args()):
+            host = self._stack_and_cast(batches)
+        placed = self._timed_h2d(self._place_window, host)
         if cache is not None:
             nbytes = sum(getattr(b.input, "nbytes", 0)
                          + getattr(b.target, "nbytes", 0) for b in batches)
@@ -1110,12 +1186,14 @@ class Optimizer:
                 b.recycle()
         return placed
 
-    def _place_window(self, batches: list):
+    def _stack_and_cast(self, batches: list):
+        """The window on the host, ready for the copy: (input, target)."""
         inp = self._stack_window([b.input for b in batches])
         target = self._stack_window([b.target for b in batches])
-        return (jax.device_put(
-                    jax.tree_util.tree_map(self._feed_cast, inp)),
-                jax.device_put(target))
+        return jax.tree_util.tree_map(self._feed_cast, inp), target
+
+    def _place_window(self, inp, target):
+        return jax.device_put(inp), jax.device_put(target)
 
     @staticmethod
     def _feed_cast(x):
@@ -1598,10 +1676,19 @@ class Optimizer:
             make_iter = ((lambda s=skip: itertools.islice(
                 self.dataset.data(train=True), s, None)) if skip
                 else (lambda: self.dataset.data(train=True)))
-            feed = PrefetchingFeed(
-                make_iter,
-                self._put_window if fuse > 1 else self._put_batch,
-                self.prefetch_depth, window=fuse)
+            place = self._put_window if fuse > 1 else self._put_batch
+            # sequence numbers of the windows in flight: the producer notes
+            # one before it places a window, the loop takes one with each
+            # window it is handed (the feed's queue is first in, first out)
+            seqs: collections.deque = collections.deque()
+
+            def put(group, place=place, seqs=seqs):
+                self._feed_seq += 1
+                seqs.append(self._feed_seq)
+                return place(group)
+
+            feed = PrefetchingFeed(make_iter, put, self.prefetch_depth,
+                                   window=fuse)
             with feed, trace.span("train/epoch",
                                   {"epoch": state["epoch"]}):
                 feed_it = iter(feed)
@@ -1613,16 +1700,15 @@ class Optimizer:
                         break
                     # "feed" = time the step loop actually *waits* on data; in
                     # steady state the producer thread hides assembly + transfer
-                    t_feed0 = time.perf_counter()
-                    with self.metrics.timer("feed"), \
-                            trace.span("train/feed_wait"):
+                    with self.metrics.timer(
+                            "feed", trace.span("train/feed_wait")) as waited:
                         try:
                             item, placed = next(feed_it)
                         except StopIteration:
                             break
-                    self._obs_feed_wait(time.perf_counter() - t_feed0,
-                                        step_hist)
+                    self._obs_feed_wait(waited.seconds, step_hist)
                     epoch_had_data = True
+                    seq = seqs.popleft()
 
                     batches = item if fuse > 1 else [item]
                     # full windows arrive device-stacked (leading scan axis);
@@ -1643,8 +1729,12 @@ class Optimizer:
                         start_it = state["neval"]
                         step_idx0 = jnp.asarray(start_it - 1, jnp.int32)
                         inp, target = stacked
-                        with self.metrics.timer("step_dispatch"), \
-                                trace.span("train/window", {"k": k}):
+                        # times the dispatch call, not the K steps: the
+                        # device runs them after the call returns
+                        with self.metrics.timer("step_dispatch", trace.span(
+                                "train/window",
+                                {"k": k, "it": start_it, "seq": seq}
+                                if trace.enabled() else None)):
                             out = window_fn(params, mstate, ostate, step_idx0,
                                             inp, target, base_rng)
                         if self.check_numerics:
@@ -1752,8 +1842,11 @@ class Optimizer:
                             profile_stop_at = state["neval"] + self.profile_n_iters
 
                         step_idx = jnp.asarray(state["neval"] - 1, jnp.int32)
-                        with self.metrics.timer("step_dispatch"), \
-                                trace.span("train/step"):
+                        # times the dispatch call, not the step
+                        with self.metrics.timer("step_dispatch", trace.span(
+                                "train/step",
+                                {"it": state["neval"], "seq": seq}
+                                if trace.enabled() else None)):
                             out = step_fn(
                                 params, mstate, ostate, step_idx, inp, target,
                                 base_rng)
@@ -1847,6 +1940,7 @@ class Optimizer:
                 break
 
         self._stop_profiler_if_active()  # endWhen fired inside the trace window
+        self._stop_copy_watcher()  # every feed/h2d span is recorded by now
         self._flush_pending(pending, state, keep_last=False)
         self._join_checkpoint_writer()  # optimize() returning implies ckpt durable
         self.model.set_params(jax.device_get(params))
@@ -2010,7 +2104,7 @@ class Optimizer:
             to_fetch = list(pending)
         if not to_fetch:
             return 0
-        with self.metrics.timer("loss_fetch"), trace.span("train/loss_fetch"):
+        with self.metrics.timer("loss_fetch", trace.span("train/loss_fetch")):
             vals, errs, mvals = jax.device_get(
                 ([l for _, l, _, _, _, _ in to_fetch],
                  [e for _, _, _, e, _, _ in to_fetch],
@@ -2145,7 +2239,7 @@ class Optimizer:
         # feed's pipelining — and device-capable methods fold on device, so
         # the pass fetches O(1) metric scalars instead of per-batch logits.
         from bigdl_tpu.optim.evaluator import run_device_eval
-        with self.metrics.timer("validation"), trace.span("train/validation"):
+        with self.metrics.timer("validation", trace.span("train/validation")):
             results, stats = run_device_eval(
                 self.model, params, mstate, self.val_dataset,
                 list(self.val_methods), depth=self.prefetch_depth,

@@ -28,14 +28,18 @@ logger = logging.getLogger("bigdl_tpu")
 
 def place_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its one place and return
-    it. ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself and nothing
-    is set here. Otherwise the cache goes to ``.jax_cache`` beside the
+    it. ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself and no
+    directory is set here. Otherwise the cache goes to ``.jax_cache`` beside the
     package — a fixed path, because the path is part of what a later process
     must reproduce to hit. Called once, from ``import bigdl_tpu``, so every
     entry point (trainer, serving engine, bench, CLI) passes it before its
     first compile."""
     import jax
 
+    # the key covers the operations' metadata (named scopes, source lines):
+    # otherwise a program cached before a scope was renamed is served with
+    # its old names, and a profile bills device time to scopes that are gone
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         pkg_parent = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))

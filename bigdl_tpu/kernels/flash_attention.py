@@ -4,20 +4,45 @@ Complements the multi-chip ring attention (parallel/ring_attention.py): ring
 shards the SEQUENCE over mesh devices and rotates K/V over ICI; this kernel is
 the intra-chip analog of the same streaming-softmax idea. Plain XLA attention
 materialises the (T, T) score matrix in HBM twice (softmax in, probs out);
-flash keeps one (block_q, block_k) score tile at a time in VMEM with running
-max/sum statistics, so HBM traffic drops from O(T^2) to O(T·d) and the two
-matmuls per tile stay on the MXU.
+flash keeps one score tile at a time in VMEM with running max/sum statistics,
+so HBM traffic drops from O(T^2) to O(T·d) and the two matmuls per tile stay
+on the MXU.
 
-Grid layout (TPU grids execute sequentially, innermost-last): (batch*heads,
-q_blocks, k_blocks) with the k-dim innermost; the running (m, l, acc) state
-lives in VMEM scratch carried across k iterations, initialised at k==0 and
-flushed to the output block at the last k step — the standard Pallas
-accumulation pattern.
+Tile program (``_Tiles``, chosen by ``_tiles`` from T, head_dim and the
+input's itemsize): the grid is (batch*heads, blocks, spans). A grid step owns
+one ``block`` of queries (forward, dq) or of keys (dk/dv) and has a ``span`` of
+the other side resident in VMEM: the whole axis wherever two operands of it,
+double-buffered, fit ``_RESIDENT_BYTES`` (T up to 16k at d=64 in bf16), so
+their block index moves only with the head and they are fetched once a head.
+Inside, a rolled ``fori_loop`` walks the span in ``chunk``s. Under a causal
+mask its trip count ends at the diagonal (a dead chunk is no step at all, a
+dead span is clamped onto the resident one and copies nothing) and every
+live chunk builds the mask, one compare of ``_lead`` against a scalar. A
+second body without the mask for the chunks wholly below the diagonal was
+built and dropped: the compare and select hide under the MXU's time (1,231
+against 1,261 bundles a 512x512 forward chunk, 1,761 against 1,740 for dq,
+2,253 both ways for dk/dv) and it doubles the kernel's code. Block and chunk
+need not be equal. The running (m, l, acc) state lives in VMEM scratch across
+a block's chunks and spans. Each kernel is a jitted function, so a model's
+layers share one trace and one lowering of it.
+
+Operands go to the MXU in the input's dtype with fp32 accumulation
+(``preferred_element_type``): bf16 q/k/v/dO tiles as they are, ``p`` and ``ds``
+cast to that dtype for the second product, fp32 inputs in fp32 (which Mosaic
+runs as one bf16 pass at default precision: the fp32 kernel reads 3e-3 off a
+``"highest"`` reference, and casting bf16 tiles to fp32 first, as this file
+did until PR 27, cost nothing and bought nothing). The softmax scale rides
+on one (block, d) operand a grid step, not on every score (exact for bf16
+where head_dim is a power of 4, e.g. 64; otherwise one more rounding of q or
+k at the operand's own precision). The forward keeps m and l with a row's
+value in every lane of a register, and l as per-lane partial sums reduced
+once at the flush: one lane reduction (the max) a chunk instead of two
+reductions and two lane broadcasts. Statistics and accumulators are fp32.
 
 Semantics: forward AND backward are Pallas kernels on TPU (interpreter
 elsewhere — tests). The backward is the standard flash-2 scheme: the forward
 additionally saves the per-row logsumexp L = m + log(l); backward recomputes
-each (block_q, block_k) probability tile from (q, k, L) in VMEM and streams
+each probability tile from (q, k, L) in VMEM and streams
   dq += (p * (dO·v^T - D)) · k,   dv += p^T · dO,   dk += ds^T · q
 with D = rowsum(dO * O) precomputed in one fused elementwise pass — so
 TRAINING memory is O(T·d) too, not just inference (the O(T^2) score matrix is
@@ -28,16 +53,25 @@ kernel that does not build raises: nothing here catches a build error.
 
 Per-row residuals (logsumexp ``L``, ``D``) cross HBM in the orientation each
 kernel broadcasts them in, so no kernel has to move a vector between
-sublanes and lanes: a column ``(bh, T, 1)`` for the forward and dq kernels (rows of the
-``(block_q, block_k)`` tile), a row ``(bh, 1, T)`` for the dk/dv kernel (its
-tile is transposed). Both shapes meet Mosaic's block rule — the last two block
-dims divide (8, 128) or span the array — which a ``(1, block_q)`` block over
-``(bh, T)`` does not.
+sublanes and lanes: a column ``(bh, T, 1)`` for the forward and dq kernels
+(rows of the ``(block, chunk)`` tile), a row ``(bh, 1, T)`` for the dk/dv
+kernel (its tile is transposed; XLA gets from one to the other by a bitcast).
+Both shapes meet Mosaic's block rule — the last two block dims divide (8, 128)
+or span the array — which a ``(1, block)`` block over ``(bh, T)`` does not.
+
+Measured on one v5e chip (PERF.md, PR 27) at (8, 16, 1024, 64) bf16 causal,
+the shape of the benchmark's GPT-2 medium cell, 512-row blocks and chunks:
+forward 0.49, dq 0.61, dk/dv 0.73 ms a call (1.05, 2.94 and 2.91 ms with the
+256x512 forward and 128x128 backward tiles of before; 0.85, 0.76 and 0.81 ms
+with 512x512 tiles on that older three-axis grid, so the tile size alone was
+most of it). The loops' bodies are then within a fifth of what the MXU needs
+for products that fill half of it (head_dim 64 against 128 rows).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,227 +92,342 @@ def _reference_attention(q, k, v, causal: bool):
     return jnp.einsum("...qk,...kd->...qd", p, v.astype(p.dtype)).astype(q.dtype)
 
 
-def _pallas_flash_call(q3, k3, v3, causal, block_q, block_k, interpret):
+class _Tiles(NamedTuple):
+    """One kernel's tiling of a sequence axis of length ``t``. A grid step
+    owns ``block`` rows of the operand that streams through the MXU (queries
+    for the forward and dq kernels, keys for dk/dv) and holds ``span`` rows
+    of the other side resident in VMEM (the whole axis wherever it fits);
+    a rolled loop walks the span in chunks of ``chunk`` rows."""
+    block: int
+    chunk: int
+    span: int
+
+
+# VMEM the kernels may take (v5e has 128 MiB; Mosaic's default scope is 16).
+# The largest plan holds _RESIDENT_BYTES of resident operands (two of them,
+# double-buffered) and about five fp32 temporaries of a block x chunk tile.
+_VMEM_LIMIT_BYTES = 32 * 2 ** 20
+_RESIDENT_BYTES = 8 * 2 ** 20
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _part(ref, axis, j, size):
+    """Part ``j`` of a ``(1, ·, ·)`` block cut along ``axis`` into parts of
+    ``size``: a chunk's rows of a resident ``(1, span, d)`` operand, or its
+    lanes of a ``(1, 1, span)`` row of residuals."""
+    from jax.experimental import pallas as pl
+
+    at = [0, slice(None), slice(None)]
+    if ref.shape[axis] != size:
+        at[axis] = pl.ds(pl.multiple_of(j * size, size), size)
+    return ref[tuple(at)]
+
+
+def _each(lo, hi, body):
+    """Rolled loop over ``[lo, hi)``, run for what ``body`` writes to refs."""
+    def step(j, carry):
+        body(j)
+        return carry
+    jax.lax.fori_loop(lo, hi, step, 0)
+
+
+def _within(chunk, s_idx, per_span):
+    """Chunk ``chunk`` of the whole axis as a loop bound inside span
+    ``s_idx``: numbered from the span's first chunk and cut to the span, so
+    a span wholly on the dead side of it gets a loop of no step."""
+    return jnp.clip(chunk - s_idx * per_span, 0, per_span)
+
+
+def _lead(shape, a, b):
+    """``iota`` along dim ``a`` minus ``iota`` along dim ``b``: by how much one
+    index of a tile leads the other. A chunk's causal mask is one compare of
+    this against a scalar."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, a)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, b))
+
+
+def _lanes(x, n):
+    """A ``(rows, w)`` statistic whose lanes all hold the row's value, at
+    width ``n``: a slice or a repeat of whole registers where the widths
+    allow, no lane broadcast."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, w = x.shape
+    if n <= w:
+        return x[:, :n]
+    if w == 128 and n % w == 0:
+        return pltpu.repeat(x, n // w, axis=1)
+    return jnp.broadcast_to(x[:, :1], (rows, n))
+
+
+# A kernel is a jitted function of its tiles: the layers of a model call it
+# with equal shapes, and it is then traced and lowered to Mosaic once a
+# program, not once a layer (6 s of an LM step's 8 s of lowering, 24 layers).
+_kernel = functools.partial(
+    jax.jit, static_argnames=("causal", "tiles", "interpret"))
+
+
+def _kv_map(block_q, span, causal):
+    """Index map of the keys' and values' resident span under a grid of
+    (heads, query blocks, spans). Under the mask a span above the diagonal
+    names the last live one, which is already resident: no copy."""
+    def index(b, i, s):
+        last = (i * block_q + block_q - 1) // span
+        return (b, jnp.minimum(s, last) if causal else s, 0)
+    return index
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+@_kernel
+def _pallas_flash_call(q3, k3, v3, causal, tiles, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    block_q, block_k, span = tiles
     scale = 1.0 / (d ** 0.5)
-    n_k = t // block_k
+    n_span, per_span = t // span, span // block_k
+    # the running max and sum keep a row's value in every lane of a register
+    # (as the (block_q, block_k) tile meets them), not in one lane of 128
+    width = min(block_k, 128)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
-        i = pl.program_id(1)
-        j = pl.program_id(2)
+        i, s_idx = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(j == 0)
+        @pl.when(s_idx == 0)
         def _init():
             m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-        # causal block skip: a k-block strictly above the diagonal contributes
-        # nothing — skip its two matmuls entirely (halves causal FLOPs)
-        live = (j * block_k <= i * block_q + block_q - 1) if causal else True
+        # the softmax scale rides on the (block_q, d) operand, once a step
+        q = q_ref[0] * scale
+        row0 = i * block_q
 
-        @pl.when(live)
-        def _step():
-            q = q_ref[0].astype(jnp.float32)
-            k = k_ref[0].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
+        def step(j):
+            k = _part(k_ref, 1, j, block_k)
+            v = _part(v_ref, 1, j, block_k)
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32)
             if causal:
-                qi = i * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                kj = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(kj <= qi, s, -jnp.inf)
-
+                col0 = (s_idx * per_span + j) * block_k
+                s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
+            # every row has a live key in the axis' first chunk, so from the
+            # first step on m is finite and no exponent reads inf - inf
             m_prev = m_scr[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            alpha = jnp.where(jnp.isfinite(m_prev),
-                              jnp.exp(m_prev - safe_m), 0.0)
-            p = jnp.exp(jnp.where(jnp.isfinite(s), s - safe_m, -jnp.inf))
-            l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-                p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            # l stays a sum by lane until the flush: adds, no lane reduction
+            l_scr[:] = l_scr[:] * alpha + sum(
+                p[:, c:c + width] for c in range(0, block_k, width))
+            acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jax.lax.dot_general(
+                p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
             m_scr[:] = m_new
 
-        @pl.when(j == n_k - 1)
-        def _flush():
-            denom = jnp.maximum(l_scr[:], 1e-37)
-            o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-            # per-row logsumexp residual for the flash backward: rows with no
-            # live block (cannot happen causally — the diagonal is live) would
-            # be -inf; clamp through the same denom guard
-            lse_ref[0] = m_scr[:] + jnp.log(denom)
+        # under the mask the loop ends with the chunk the block's last row
+        # reaches: chunks above the diagonal are no steps at all
+        live = pl.cdiv(row0 + block_q, block_k)
+        _each(0, _within(live, s_idx, per_span) if causal else per_span, step)
 
+        @pl.when(s_idx == n_span - 1)
+        def _flush():
+            l = jnp.sum(l_scr[:], axis=-1, keepdims=True)
+            o_ref[0] = (acc_scr[:] * (1.0 / l)).astype(o_ref.dtype)
+            # per-row logsumexp, the flash backward's residual
+            lse_ref[0] = m_scr[:, :1] + jnp.log(l)
+
+    kv_map = _kv_map(block_q, span, causal)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[out_struct((bh, t, d), q3.dtype, q3, k3, v3),
                    out_struct((bh, t, 1), jnp.float32, q3, k3, v3)],
-        grid=(bh, t // block_q, n_k),
+        grid=(bh, t // block_q, n_span),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+            pl.BlockSpec((1, span, d), kv_map),
+            pl.BlockSpec((1, span, d), kv_map),
         ],
-        out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))],
+        out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+                   pl.BlockSpec((1, block_q, 1), lambda b, i, s: (b, i, 0))],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, width), jnp.float32),
+            pltpu.VMEM((block_q, width), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="bigdl_flash_fwd",
     )(q3, k3, v3)
     return out, lse
 
 
+@_kernel
 def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
-                         block_q, block_k, interpret):
+                         tiles, interpret):
     """dq = Σ_j (p_ij * (dO_i·v_j^T - D_i)) · k_j * scale, streaming over j
     with the probability tile recomputed from (q, k, lse) in VMEM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    block_q, block_k, span = tiles
     scale = 1.0 / (d ** 0.5)
-    n_k = t // block_k
+    n_span, per_span = t // span, span // block_k
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc_scr):
-        i = pl.program_id(1)
-        j = pl.program_id(2)
+        i, s_idx = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(j == 0)
+        @pl.when(s_idx == 0)
         def _init():
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-        live = (j * block_k <= i * block_q + block_q - 1) if causal else True
+        q = q_ref[0] * scale
+        do = do_ref[0]
+        lse = lse_ref[0]                                  # (bq, 1)
+        dd = dd_ref[0]                                    # (bq, 1)
+        row0 = i * block_q
 
-        @pl.when(live)
-        def _step():
-            q = q_ref[0].astype(jnp.float32)
-            k = k_ref[0].astype(jnp.float32)
-            v = v_ref[0].astype(jnp.float32)
-            do = do_ref[0].astype(jnp.float32)
-            lse = lse_ref[0]                              # (bq, 1)
-            dd = dd_ref[0]                                # (bq, 1)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
+        def step(j):
+            k = _part(k_ref, 1, j, block_k)
+            v = _part(v_ref, 1, j, block_k)
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32)
             if causal:
-                qi = i * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                kj = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(kj <= qi, s, -jnp.inf)
+                col0 = (s_idx * per_span + j) * block_k
+                s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
             p = jnp.exp(s - lse)                          # (bq, bk)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+            dp = jax.lax.dot_general(do, v, _NT,
                                      preferred_element_type=jnp.float32)
-            ds = p * (dp - dd) * scale
+            ds = p * (dp - dd)
             acc_scr[:] = acc_scr[:] + jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-        @pl.when(j == n_k - 1)
+        live = pl.cdiv(row0 + block_q, block_k)
+        _each(0, _within(live, s_idx, per_span) if causal else per_span, step)
+
+        @pl.when(s_idx == n_span - 1)
         def _flush():
-            dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
+            # ds' own factor of the scale, once on the (bq, d) sum
+            dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
 
+    kv_map = _kv_map(block_q, span, causal)
+    row_map = lambda b, i, s: (b, i, 0)
     return pl.pallas_call(
         kernel,
         out_shape=out_struct((bh, t, d), q3.dtype, q3, k3, v3, do3),
-        grid=(bh, t // block_q, n_k),
+        grid=(bh, t // block_q, n_span),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), row_map),
+            pl.BlockSpec((1, span, d), kv_map),
+            pl.BlockSpec((1, span, d), kv_map),
+            pl.BlockSpec((1, block_q, d), row_map),
+            pl.BlockSpec((1, block_q, 1), row_map),
+            pl.BlockSpec((1, block_q, 1), row_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d), row_map),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="bigdl_flash_bwd_dq",
     )(q3, k3, v3, do3, lse_col, dd_col)
 
 
+@_kernel
 def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, causal,
-                          block_q, block_k, interpret):
-    """dv = Σ_i p_ij^T · dO_i ; dk = Σ_i ds_ij^T · q_i * scale — grid iterates
-    k-blocks outer, q-blocks inner, with (dk, dv) accumulators in VMEM."""
+                          tiles, interpret):
+    """dv = Σ_i p_ij^T · dO_i ; dk = Σ_i ds_ij^T · q_i * scale — a grid step
+    owns a block of keys and walks the queries, in the transposed orientation
+    (keys down the sublanes), with (dk, dv) accumulators in VMEM.
+    ``lse_row`` and ``dd_row`` are ``(bh, 1, t)``: queries along the lanes,
+    as the transposed tile meets them."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    block_k, block_q, span = tiles
     scale = 1.0 / (d ** 0.5)
-    n_q = t // block_q
+    n_span, per_span = t // span, span // block_q
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                dk_ref, dv_ref, dk_scr, dv_scr):
-        j = pl.program_id(1)   # k block
-        i = pl.program_id(2)   # q block (innermost)
+        j, s_idx = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(i == 0)
+        @pl.when(s_idx == 0)
         def _init():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
 
-        # causal: a q block entirely above this k block contributes nothing
-        live = (i * block_q + block_q - 1 >= j * block_k) if causal else True
+        k = k_ref[0] * scale
+        v = v_ref[0]
+        col0 = j * block_k
 
-        @pl.when(live)
-        def _step():
-            q = q_ref[0].astype(jnp.float32)
-            k = k_ref[0].astype(jnp.float32)
-            v = v_ref[0].astype(jnp.float32)
-            do = do_ref[0].astype(jnp.float32)
-            lse = lse_ref[0]                              # (1, bq)
-            dd = dd_ref[0]                                # (1, bq)
-            # transposed orientation: s_T (bk, bq)
-            s_t = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32) * scale
+        def step(i):
+            q = _part(q_ref, 1, i, block_q)
+            do = _part(do_ref, 1, i, block_q)
+            lse = _part(lse_ref, 2, i, block_q)        # (1, bq)
+            dd = _part(dd_ref, 2, i, block_q)          # (1, bq)
+            s_t = jax.lax.dot_general(k, q, _NT,          # (bk, bq)
+                                      preferred_element_type=jnp.float32)
             if causal:
-                kj = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, block_q), 0)
-                qi = i * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, block_q), 1)
-                s_t = jnp.where(kj <= qi, s_t, -jnp.inf)
-            p_t = jnp.exp(s_t - lse)                      # (bk, bq)
+                row0 = (s_idx * per_span + i) * block_q
+                s_t = jnp.where(_lead(s_t.shape, 0, 1) <= row0 - col0,
+                                s_t, -jnp.inf)
+            p_t = jnp.exp(s_t - lse)
             dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                p_t, do, (((1,), (0,)), ((), ())),
+                p_t.astype(do.dtype), do, _NN,
                 preferred_element_type=jnp.float32)
-            dp_t = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+            dp_t = jax.lax.dot_general(v, do, _NT,
                                        preferred_element_type=jnp.float32)
-            ds_t = p_t * (dp_t - dd) * scale
+            ds_t = p_t * (dp_t - dd)
             dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-                ds_t, q, (((1,), (0,)), ((), ())),
+                ds_t.astype(q.dtype), q, _NN,
                 preferred_element_type=jnp.float32)
 
-        @pl.when(i == n_q - 1)
+        # under the mask the loop starts with the query chunk that the
+        # block's first key reaches
+        live = col0 // block_q
+        _each(_within(live, s_idx, per_span) if causal else 0, per_span, step)
+
+        @pl.when(s_idx == n_span - 1)
         def _flush():
-            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+            dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
+    def q_map(b, j, s):
+        # a span of queries above the diagonal names the first live one
+        first = (j * block_k) // span
+        return (b, jnp.maximum(s, first) if causal else s, 0)
+
+    row_map = lambda b, j, s: (b, 0, q_map(b, j, s)[1])
+    col_map = lambda b, j, s: (b, j, 0)
     return pl.pallas_call(
         kernel,
         out_shape=[out_struct((bh, t, d), k3.dtype, q3, k3, v3, do3),
                    out_struct((bh, t, d), v3.dtype, q3, k3, v3, do3)],
-        grid=(bh, t // block_k, n_q),
+        grid=(bh, t // block_k, n_span),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, span, d), q_map),
+            pl.BlockSpec((1, block_k, d), col_map),
+            pl.BlockSpec((1, block_k, d), col_map),
+            pl.BlockSpec((1, span, d), q_map),
+            pl.BlockSpec((1, 1, span), row_map),
+            pl.BlockSpec((1, 1, span), row_map),
         ],
-        out_specs=[pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))],
+        out_specs=[pl.BlockSpec((1, block_k, d), col_map),
+                   pl.BlockSpec((1, block_k, d), col_map)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="bigdl_flash_bwd_dkv",
     )(q3, k3, v3, do3, lse_row, dd_row)
@@ -301,6 +450,27 @@ def _pick_block(t: int, target: int) -> int | None:
     return None
 
 
+# Rows of a block and of a chunk that ``_tiles`` aims for, in all three
+# kernels: the winner of a sweep of 128 to 1024 on a v5e at (8, 16, 1024, 64)
+# bf16 causal (PERF.md, PR 27). Smaller tiles pay a loop step's fixed cost
+# (about 200 bundles beside the MXU's 1,000 to 2,000) more often; larger ones
+# run more dead columns on the diagonal.
+_TILE_ROWS = 512
+
+
+def _tiles(t: int, d: int, itemsize: int) -> _Tiles | None:
+    """The kernels' tiles from what the call can see: ``block`` and ``chunk``
+    by ``_pick_block`` towards ``_TILE_ROWS``, ``span`` the longest legal
+    tile whose two resident operands, double-buffered, stay within
+    ``_RESIDENT_BYTES``. ``None`` where ``_pick_block(t, 128)`` is: a chunk
+    is then the whole axis or a multiple of 128 that divides the span."""
+    if _pick_block(t, 128) is None:
+        return None
+    span = _pick_block(t, max(128, _RESIDENT_BYTES // (4 * d * itemsize)))
+    return _Tiles(_pick_block(t, _TILE_ROWS), _pick_block(span, _TILE_ROWS),
+                  span)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = False,
                     force_pallas: bool | None = None):
@@ -316,18 +486,12 @@ def flash_attention(q, k, v, causal: bool = False,
 def _fa_fwd(q, k, v, causal, force_pallas):
     use_pallas = _on_tpu() if force_pallas is None else force_pallas
     b, h, t, d = q.shape
-    # the backward's 128 tile is the tightest: a T it covers, the forward's
-    # 256/512 targets cover too
-    if not use_pallas or _pick_block(t, 128) is None:
+    tiles = _tiles(t, d, q.dtype.itemsize)
+    if not use_pallas or tiles is None:
         return _reference_attention(q, k, v, causal), (q, k, v, None, None)
-    # measured on v5e (T=2048, d=64): 256/512 tiles amortise grid-step
-    # overhead ~30% better than 128/128 and beat XLA's fused attention;
-    # VMEM stays comfortable (score tile 256x512 fp32 = 512 KB)
-    block_q, block_k = _pick_block(t, 256), _pick_block(t, 512)
     out, lse = _pallas_flash_call(
         q.reshape(b * h, t, d), k.reshape(b * h, t, d),
-        v.reshape(b * h, t, d), causal, block_q, block_k,
-        interpret=not _on_tpu())
+        v.reshape(b * h, t, d), causal, tiles, interpret=not _on_tpu())
     out = out.reshape(b, h, t, d)
     return out, (q, k, v, out, lse)
 
@@ -346,18 +510,17 @@ def _flash_bwd(q, k, v, out, lse, g, causal):
     """Streaming flash-2 backward: O(T·d) memory, probability tiles recomputed
     from (q, k, lse) in VMEM."""
     b, h, t, d = q.shape
-    block_q = block_k = _pick_block(t, 128)   # not None: _fa_fwd checked
+    tiles = _tiles(t, d, q.dtype.itemsize)      # not None: _fa_fwd checked
     reshape = lambda a: a.reshape(b * h, t, d)
     q3, k3, v3, do3 = reshape(q), reshape(k), reshape(v), reshape(g)
     # D_i = rowsum(dO * O): one fused elementwise pass, O(T·d) reads
     dd = jnp.sum(do3.astype(jnp.float32) * reshape(out).astype(jnp.float32),
                  axis=-1, keepdims=True)                    # (bh, t, 1)
     interp = not _on_tpu()
-    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd, causal,
-                              block_q, block_k, interp)
+    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd, causal, tiles, interp)
     as_row = lambda a: a.reshape(b * h, 1, t)
     dk, dv = _pallas_flash_bwd_dkv(q3, k3, v3, do3, as_row(lse), as_row(dd),
-                                   causal, block_q, block_k, interp)
+                                   causal, tiles, interp)
     unshape = lambda a, like: a.reshape(b, h, t, d).astype(like.dtype)
     return unshape(dq, q), unshape(dk, k), unshape(dv, v)
 

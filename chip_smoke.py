@@ -169,7 +169,9 @@ KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd_dq", "bigdl_flash_bwd_dkv",
 
 def _kernel_numerics(shape=(16, 8, 512, 64)) -> dict:
     """One forward and gradient of each Pallas kernel on the chip against its
-    jnp reference on the same inputs."""
+    jnp reference on the same inputs: flash attention at ``shape`` and at
+    (8, 16, 1024, 64), which the benchmark's GPT-2 medium cell trains at;
+    LayerNorm at ``shape``'s rows."""
     import jax
     import jax.numpy as jnp
 
@@ -184,22 +186,26 @@ def _kernel_numerics(shape=(16, 8, 512, 64)) -> dict:
     def sq(fn):
         return lambda *a: jnp.sum(jnp.square(fn(*a).astype(jnp.float32)))
 
-    for name, dt in (("bf16", jnp.bfloat16), ("f32", jnp.float32)):
-        q, k, v = (jnp.asarray(rng.normal(size=shape), dt) for _ in range(3))
-        got = jax.jit(lambda a, b, c: flash_attention(a, b, c, True))(q, k, v)
-        ggot = jax.jit(jax.grad(sq(lambda a, b, c: flash_attention(
-            a, b, c, True)), argnums=(0, 1, 2)))(q, k, v)
-        with jax.default_matmul_precision("highest"):
-            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
-            ref = _reference_attention(*f32, True)
-            gref = jax.grad(sq(lambda a, b, c: _reference_attention(
-                a, b, c, True)), argnums=(0, 1, 2))(*f32)
-        errs = [rel_err(got, ref)] + [rel_err(a, b)
-                                      for a, b in zip(ggot, gref)]
-        out[f"flash_{name}"] = [float(f"{e:.3g}") for e in errs]
-        check(max(errs) <= TOL_FLASH,
-              f"flash attention ({name}) off its reference: fwd/dq/dk/dv "
-              f"errors {errs} > {TOL_FLASH}")
+    for qkv_shape in (shape, (8, 16, 1024, 64)):
+        for name, dt in (("bf16", jnp.bfloat16), ("f32", jnp.float32)):
+            q, k, v = (jnp.asarray(rng.normal(size=qkv_shape), dt)
+                       for _ in range(3))
+            got = jax.jit(lambda a, b, c: flash_attention(a, b, c, True))(
+                q, k, v)
+            ggot = jax.jit(jax.grad(sq(lambda a, b, c: flash_attention(
+                a, b, c, True)), argnums=(0, 1, 2)))(q, k, v)
+            with jax.default_matmul_precision("highest"):
+                f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+                ref = _reference_attention(*f32, True)
+                gref = jax.grad(sq(lambda a, b, c: _reference_attention(
+                    a, b, c, True)), argnums=(0, 1, 2))(*f32)
+            errs = [rel_err(got, ref)] + [rel_err(a, b)
+                                          for a, b in zip(ggot, gref)]
+            out[f"flash_{name}_t{qkv_shape[2]}"] = [float(f"{e:.3g}")
+                                                    for e in errs]
+            check(max(errs) <= TOL_FLASH,
+                  f"flash attention ({name}, {qkv_shape}) off its reference: "
+                  f"fwd/dq/dk/dv errors {errs} > {TOL_FLASH}")
 
     n, h = shape[0] * shape[2], shape[1] * shape[3]
     # bf16 throughout (the model under mixed precision), fp32 throughout, and

@@ -37,9 +37,11 @@ def _kernel_calls(text: str, name: str) -> int:
 # --------------------------------------------------------------- flash
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_flash_lowers_at_bench_shape(on_tpu, dtype, causal):
-    """Forward and both backward kernels at the bench LM's shape."""
-    q = jax.ShapeDtypeStruct((16, 8, 512, 64), dtype)
+@pytest.mark.parametrize("shape", [(16, 8, 512, 64), (8, 16, 1024, 64)])
+def test_flash_lowers_at_bench_shape(on_tpu, shape, dtype, causal):
+    """Forward and both backward kernels at the bench LM's shape and at the
+    benchmark's GPT-2 medium cell's."""
+    q = jax.ShapeDtypeStruct(shape, dtype)
     fwd = _tpu_module(lambda a, b, c: fa.flash_attention(a, b, c, causal),
                       q, q, q)
     assert _kernel_calls(fwd, "bigdl_flash_fwd") == 1
@@ -50,7 +52,7 @@ def test_flash_lowers_at_bench_shape(on_tpu, dtype, causal):
         assert _kernel_calls(grad, name) == 1, name
 
 
-@pytest.mark.parametrize("t", [8, 24, 64, 384, 640, 8192])
+@pytest.mark.parametrize("t", [8, 24, 64, 384, 640, 8192, 1024, 1280, 2048])
 def test_flash_lowers_across_tilings(on_tpu, t):
     """Whole-axis tiles (T <= target) and multiples of 128 beyond it."""
     q = jax.ShapeDtypeStruct((1, 2, t, 64), jnp.bfloat16)
@@ -73,6 +75,40 @@ def test_pick_block_rule(t, target, want):
     assert block == want
     if block is not None:
         assert t % block == 0 and (block == t or block % 128 == 0)
+
+
+@pytest.mark.parametrize("t,d,itemsize,want", [
+    (1024, 64, 2, (512, 512, 1024)),      # the GPT-2 medium cell
+    (512, 64, 2, (512, 512, 512)), (512, 64, 4, (512, 512, 512)),
+    (64, 64, 2, (64, 64, 64)), (24, 16, 4, (24, 24, 24)),  # whole axis
+    (384, 64, 2, (384, 384, 384)), (640, 64, 2, (128, 128, 640)),
+    (1280, 64, 2, (256, 256, 1280)), (8192, 64, 2, (512, 512, 8192)),
+    (8192, 128, 4, (512, 512, 4096)),     # the span gives way to VMEM
+    (32768, 128, 4, (512, 512, 4096)), (32768, 64, 2, (512, 512, 16384)),
+    (2560, 256, 4, (512, 256, 1280)),     # two spans; chunk and block differ
+    (15, 64, 2, None), (200, 64, 2, None), (1000, 64, 4, None),
+])
+def test_tiles_rule(t, d, itemsize, want):
+    tiles = fa._tiles(t, d, itemsize)
+    assert tiles == (want and fa._Tiles(*want))
+    if tiles is not None:
+        block, chunk, span = tiles
+        assert t % block == 0 and t % span == 0 and span % chunk == 0
+        assert span == 128 or 4 * span * d * itemsize <= fa._RESIDENT_BYTES
+        for tile in tiles:
+            assert tile == t or tile % 128 == 0
+
+
+def test_flash_lowers_with_several_spans(on_tpu):
+    """A sequence whose keys and values pass the resident budget: the grid
+    gets a third axis over spans, and the index maps clamp at the diagonal."""
+    q = jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.float32)
+    assert fa._tiles(32768, 128, 4).span < 32768
+    grad = _tpu_module(
+        jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, True)
+                 .sum(), argnums=(0, 1, 2)), q, q, q)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(grad, name) == 1, name
 
 
 @pytest.mark.parametrize("t", [15, 200])

@@ -186,6 +186,70 @@ class TestFlashAttention:
             rtol=1e-4, atol=1e-5)
 
 
+def _max_rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-30))
+
+
+class TestFlashTilings:
+    """Interpreted parity of the forward and of all three gradients against
+    ``_reference_attention`` at ``"highest"``, at tilings the one-tile tests
+    above never reach: rectangular tiles the diagonal crosses, several spans
+    of the resident operand, and the training cell's own T=1024, d=64."""
+
+    TOL = {"float32": 1e-5, "bfloat16": 1e-2}
+
+    def _check(self, shape, dtype, causal, seed=0):
+        from bigdl_tpu.kernels.flash_attention import (
+            _reference_attention, flash_attention,
+        )
+        rng = np.random.default_rng(seed)
+        q, k, v = (jnp.asarray(rng.normal(size=shape), dtype)
+                   for _ in range(3))
+
+        def sq(fn):
+            return lambda *a: jnp.sum(jnp.square(fn(*a).astype(jnp.float32)))
+
+        got = flash_attention(q, k, v, causal, True)
+        ggot = jax.grad(sq(lambda a, b, c: flash_attention(
+            a, b, c, causal, True)), argnums=(0, 1, 2))(q, k, v)
+        assert got.dtype == q.dtype
+        assert [g.dtype for g in ggot] == [q.dtype] * 3
+        with jax.default_matmul_precision("highest"):
+            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+            ref = _reference_attention(*f32, causal)
+            gref = jax.grad(sq(lambda a, b, c: _reference_attention(
+                a, b, c, causal)), argnums=(0, 1, 2))(*f32)
+        errs = [_max_rel_err(got, ref)] + [
+            _max_rel_err(a, b) for a, b in zip(ggot, gref)]
+        assert max(errs) <= self.TOL[jnp.dtype(dtype).name], errs
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("tiles", [
+        (256, 384, 768),    # 3 blocks x 2 chunks: the diagonal cuts chunks
+        (384, 128, 768),    # 2 blocks x 6 chunks
+        (128, 256, 256),    # 3 spans of one chunk each
+        (256, 128, 384),    # 2 spans of 3 chunks, blocks that straddle them
+    ])
+    def test_rectangular_tiles_match_reference(self, monkeypatch, tiles,
+                                               dtype, causal):
+        from bigdl_tpu.kernels import flash_attention as fa
+        monkeypatch.setattr(fa, "_tiles",
+                            lambda t, d, itemsize: fa._Tiles(*tiles))
+        self._check((1, 2, 768, 16), dtype, causal)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_cell_shape_matches_reference(self, dtype, causal):
+        """T=1024, d=64 as ``gpt2-medium.train-t1024`` runs it (two heads):
+        the tiles are the rule's own, two blocks of 512 over one span."""
+        from bigdl_tpu.kernels import flash_attention as fa
+        assert fa._tiles(1024, 64, jnp.dtype(dtype).itemsize) == \
+            fa._Tiles(512, 512, 1024)
+        self._check((1, 2, 1024, 64), dtype, causal, seed=1)
+
+
 class TestFlashBackwardMemory:
     """Training at long T must not scale
     O(T^2). Pinned by shape math — the traced grad program may not contain

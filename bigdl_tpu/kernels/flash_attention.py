@@ -22,7 +22,14 @@ second body without the mask for the chunks wholly below the diagonal was
 built and dropped: the compare and select hide under the MXU's time (1,231
 against 1,261 bundles a 512x512 forward chunk, 1,761 against 1,740 for dq,
 2,253 both ways for dk/dv) and it doubles the kernel's code. Block and chunk
-need not be equal. The running (m, l, acc) state lives in VMEM scratch across
+need not be equal. The mask is a static description (``None``, ``"causal"``
+or ``BlockDiffusion(L, b)``): from it each kernel derives, a tile, the one or
+two ranges of chunks that hold a live pair (``_live_keys``, ``_live_queries``)
+and walks them in the same one loop (``_walk``); a live chunk under block
+diffusion builds its mask from two compares of block indices. Keys and values
+may hold fewer heads than the queries: the index maps read a group's shared
+head, and the dk/dv kernel's last grid axis walks the group's query heads.
+The running (m, l, acc) state lives in VMEM scratch across
 a block's chunks and spans. Each kernel is a jitted function, so a model's
 layers share one trace and one lowering of it.
 
@@ -79,15 +86,57 @@ import jax.numpy as jnp
 from bigdl_tpu.kernels.layernorm import _on_tpu, out_struct
 
 
-def _reference_attention(q, k, v, causal: bool):
-    """Plain jnp attention over (..., T, d) — the numerical oracle and VJP."""
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion training mask (BD3-LMs, arXiv:2503.09573) over an
+    axis of ``2 * length`` positions: a noised copy of a sequence in
+    ``[0, length)`` and the clean sequence in ``[length, 2 * length)``, both in
+    blocks of ``block`` tokens. With ``blk(i) = (i mod length) // block`` a
+    query sees a key iff: both noised and ``blk(k) == blk(q)``; query noised,
+    key clean and ``blk(k) < blk(q)``; both clean and ``blk(k) <= blk(q)``. A
+    clean query never sees a noised key. Static and hashable: the kernels
+    derive their live chunks and a chunk's mask from the two numbers."""
+    length: int
+    block: int
+
+
+def _as_mask(causal, mask=None):
+    """The one static description the kernels take: ``None`` (every key),
+    ``"causal"`` or a :class:`BlockDiffusion`. ``causal`` is the older
+    boolean spelling (a description is passed through)."""
+    if mask is not None:
+        return mask
+    if isinstance(causal, (BlockDiffusion, str)):
+        return causal
+    return "causal" if causal else None
+
+
+def dense_mask(mask, t: int):
+    """``(t, t)`` booleans, queries down the rows: the mask written out, for
+    the reference path and the tests."""
+    if mask is None:
+        return jnp.ones((t, t), bool)
+    if mask == "causal":
+        return jnp.tril(jnp.ones((t, t), bool))
+    pos = jnp.arange(t)
+    noised = pos < mask.length
+    blk = (pos % mask.length) // mask.block
+    qn, kn, qb, kb = noised[:, None], noised[None, :], blk[:, None], blk[None, :]
+    return jnp.where(kn, qn & (kb == qb), jnp.where(qn, kb < qb, kb <= qb))
+
+
+def _reference_attention(q, k, v, causal=False):
+    """Plain jnp attention over (..., T, d) — the numerical oracle and VJP.
+    ``causal`` is a boolean or a mask description; ``k`` and ``v`` may hold
+    fewer heads than ``q`` (query head ``i`` reads head ``i // group``)."""
+    mask = _as_mask(causal)
     d = q.shape[-1]
+    if q.ndim == 4 and k.shape[1] != q.shape[1]:
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32)
     s = s / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    if causal:
-        t_q, t_k = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((t_q, t_k), bool))
-        s = jnp.where(mask, s, -jnp.inf)
+    if mask is not None:
+        s = jnp.where(dense_mask(mask, s.shape[-1]), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("...qk,...kd->...qd", p, v.astype(p.dtype)).astype(q.dtype)
 
@@ -133,11 +182,28 @@ def _each(lo, hi, body):
     jax.lax.fori_loop(lo, hi, step, 0)
 
 
-def _within(chunk, s_idx, per_span):
+def _within(chunk, s_idx, per_span, n_span):
     """Chunk ``chunk`` of the whole axis as a loop bound inside span
     ``s_idx``: numbered from the span's first chunk and cut to the span, so
-    a span wholly on the dead side of it gets a loop of no step."""
+    a span wholly on the dead side of it gets a loop of no step. The axis'
+    two ends, given as Python numbers, stay Python numbers."""
+    if isinstance(chunk, int) and chunk in (0, n_span * per_span):
+        return per_span if chunk else 0
     return jnp.clip(chunk - s_idx * per_span, 0, per_span)
+
+
+def _walk(ranges, s_idx, per_span, n_span, body):
+    """``body(j)`` for every chunk ``j`` of span ``s_idx`` that lies in one of
+    ``ranges``, one or two ``(lo, hi)`` of chunks of the whole axis in rising
+    order: one rolled loop and one body either way (two ranges share the
+    loop's counter, a scalar select picks the chunk)."""
+    cut = [tuple(_within(c, s_idx, per_span, n_span) for c in r) for r in ranges]
+    if len(cut) == 1:
+        return _each(*cut[0], body)
+    (lo1, hi1), (lo2, hi2) = cut
+    n1 = hi1 - lo1
+    _each(0, n1 + hi2 - lo2,
+          lambda i: body(jnp.where(i < n1, lo1 + i, lo2 + i - n1)))
 
 
 def _lead(shape, a, b):
@@ -162,20 +228,120 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (rows, n))
 
 
+# ------------------------------------------------ the masks, tile by tile
+def _live_keys(mask, row0, rows, chunk, n_chunks):
+    """The chunks of keys that some query of ``[row0, row0 + rows)`` sees:
+    one or two ``(lo, hi)`` for ``_walk``. Causal: the chunks up to the one
+    the last row reaches. Block diffusion: the noised keys of the rows' own
+    blocks, then the clean keys from the axis' middle up to the last block a
+    row sees (two ranges, the second cut where a tile straddles both)."""
+    from jax.experimental import pallas as pl
+
+    if mask is None:
+        return [(0, n_chunks)]
+    if mask == "causal":
+        return [(0, pl.cdiv(row0 + rows, chunk))]
+    length, b = mask
+    # the last noised row's block: its clean keys stop before it, a clean
+    # row's after it
+    last_noised = (jnp.minimum(row0 + rows, length) - 1) // b
+    last_clean = (row0 + rows - 1 - length) // b + 1
+    has_noised, has_clean = row0 < length, row0 + rows > length
+    clean_hi = length + b * jnp.where(
+        has_clean, jnp.where(has_noised, jnp.maximum(last_clean, last_noised),
+                             last_clean), last_noised)
+    lo1 = jnp.where(has_noised, (row0 // b) * b // chunk, 0)
+    hi1 = jnp.where(has_noised, pl.cdiv((last_noised + 1) * b, chunk), 0)
+    lo2 = jnp.maximum(length // chunk, hi1)
+    return [(lo1, hi1), (lo2, jnp.maximum(pl.cdiv(clean_hi, chunk), lo2))]
+
+
+def _live_queries(mask, col0, cols, chunk, n_chunks):
+    """The chunks of queries that see some key of ``[col0, col0 + cols)``.
+    Causal: from the chunk the first key reaches to the end. Block
+    diffusion: a noised key is seen by its own block's noised rows; a clean
+    key by the noised rows of later blocks and the clean rows from its own
+    block on."""
+    from jax.experimental import pallas as pl
+
+    if mask is None:
+        return [(0, n_chunks)]
+    if mask == "causal":
+        return [(col0 // chunk, n_chunks)]
+    length, b = mask
+    has_noised, has_clean = col0 < length, col0 + cols > length
+    first_clean = (jnp.maximum(col0, length) - length) // b
+    last_noised = (jnp.minimum(col0 + cols, length) - 1) // b
+    # noised rows: the hull of the noised keys' own blocks and of the
+    # blocks after the first clean key's (one of the two unless a tile
+    # straddles the middle)
+    own = ((col0 // b) * b, (last_noised + 1) * b)
+    later = ((first_clean + 1) * b, length)
+    lo1 = jnp.where(has_noised, jnp.where(has_clean, jnp.minimum(
+        own[0], later[0]), own[0]), later[0]) // chunk
+    hi1 = pl.cdiv(jnp.where(has_clean, later[1], own[1]), chunk)
+    hi1 = jnp.maximum(hi1, lo1)
+    lo2 = jnp.where(has_clean, (length + first_clean * b) // chunk, n_chunks)
+    lo2 = jnp.maximum(lo2, hi1)
+    return [(lo1, hi1), (lo2, jnp.maximum(n_chunks, lo2))]
+
+
+_NOISED = 1 << 30       # added to a noised position's block index
+
+
+def _positions(n, axis, start):
+    """Positions ``start ..`` of a tile's rows (a column, ``axis`` 0) or of
+    its columns (a row, ``axis`` 1)."""
+    shape = (n, 1) if axis == 0 else (1, n)
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis) + start
+
+
+def _block_index(mask, pos):
+    """(is noised, block index) of positions under a block-diffusion mask."""
+    length, b = mask
+    noised = pos < length
+    at = jnp.where(noised, pos, pos - length)
+    return noised, (at >> (b.bit_length() - 1) if b & (b - 1) == 0 else at // b)
+
+
+def _query_keys(mask, pos):
+    """What a query's row of the mask is compared with: the last clean block
+    it sees, and its own noised block (no block for a clean query)."""
+    noised, blk = _block_index(mask, pos)
+    return (jnp.where(noised, blk - 1, blk),
+            jnp.where(noised, blk + _NOISED, -1))
+
+
+def _key_value(mask, pos):
+    noised, blk = _block_index(mask, pos)
+    return jnp.where(noised, blk + _NOISED, blk)
+
+
+def _block_diffusion(s, query_keys, key_value):
+    """A chunk's scores under the block-diffusion mask: two compares of block
+    indices, a query's pair down one side of the tile against a key's value
+    along the other."""
+    clean_upto, own = query_keys
+    return jnp.where((key_value <= clean_upto) | (key_value == own),
+                     s, -jnp.inf)
+
+
 # A kernel is a jitted function of its tiles: the layers of a model call it
 # with equal shapes, and it is then traced and lowered to Mosaic once a
 # program, not once a layer (6 s of an LM step's 8 s of lowering, 24 layers).
 _kernel = functools.partial(
-    jax.jit, static_argnames=("causal", "tiles", "interpret"))
+    jax.jit, static_argnames=("mask", "tiles", "interpret"))
 
 
-def _kv_map(block_q, span, causal):
+def _kv_map(block_q, span, mask, group):
     """Index map of the keys' and values' resident span under a grid of
-    (heads, query blocks, spans). Under the mask a span above the diagonal
-    names the last live one, which is already resident: no copy."""
+    (query heads, query blocks, spans): query head ``b`` reads key/value head
+    ``b // group``. Under the causal mask a span above the diagonal names the
+    last live one, which is already resident: no copy."""
     def index(b, i, s):
-        last = (i * block_q + block_q - 1) // span
-        return (b, jnp.minimum(s, last) if causal else s, 0)
+        if mask == "causal":
+            s = jnp.minimum(s, (i * block_q + block_q - 1) // span)
+        return (b if group == 1 else b // group, s, 0)
     return index
 
 
@@ -188,12 +354,14 @@ def _compiler_params():
 
 
 @_kernel
-def _pallas_flash_call(q3, k3, v3, causal, tiles, interpret):
+def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    group = bh // k3.shape[0]
     block_q, block_k, span = tiles
+    diffusion = isinstance(mask, BlockDiffusion)
     scale = 1.0 / (d ** 0.5)
     n_span, per_span = t // span, span // block_k
     # the running max and sum keep a row's value in every lane of a register
@@ -212,17 +380,24 @@ def _pallas_flash_call(q3, k3, v3, causal, tiles, interpret):
         # the softmax scale rides on the (block_q, d) operand, once a step
         q = q_ref[0] * scale
         row0 = i * block_q
+        if diffusion:
+            query_keys = _query_keys(mask, _positions(block_q, 0, row0))
 
         def step(j):
             k = _part(k_ref, 1, j, block_k)
             v = _part(v_ref, 1, j, block_k)
             s = jax.lax.dot_general(q, k, _NT,
                                     preferred_element_type=jnp.float32)
-            if causal:
-                col0 = (s_idx * per_span + j) * block_k
+            col0 = (s_idx * per_span + j) * block_k
+            if mask == "causal":
                 s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
-            # every row has a live key in the axis' first chunk, so from the
-            # first step on m is finite and no exponent reads inf - inf
+            elif diffusion:
+                s = _block_diffusion(s, query_keys, _key_value(
+                    mask, _positions(block_k, 1, col0)))
+            # every row has a live key in the first chunk it meets (the axis'
+            # first under the causal mask, its own block's under block
+            # diffusion), so from the first step on m is finite and no
+            # exponent reads inf - inf
             m_prev = m_scr[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -234,10 +409,10 @@ def _pallas_flash_call(q3, k3, v3, causal, tiles, interpret):
                 p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
             m_scr[:] = m_new
 
-        # under the mask the loop ends with the chunk the block's last row
-        # reaches: chunks above the diagonal are no steps at all
-        live = pl.cdiv(row0 + block_q, block_k)
-        _each(0, _within(live, s_idx, per_span) if causal else per_span, step)
+        # under a mask the loop walks the live chunks alone: those above the
+        # diagonal, or of blocks no row of the tile sees, are no steps at all
+        _walk(_live_keys(mask, row0, block_q, block_k, n_span * per_span),
+              s_idx, per_span, n_span, step)
 
         @pl.when(s_idx == n_span - 1)
         def _flush():
@@ -246,7 +421,7 @@ def _pallas_flash_call(q3, k3, v3, causal, tiles, interpret):
             # per-row logsumexp, the flash backward's residual
             lse_ref[0] = m_scr[:, :1] + jnp.log(l)
 
-    kv_map = _kv_map(block_q, span, causal)
+    kv_map = _kv_map(block_q, span, mask, group)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[out_struct((bh, t, d), q3.dtype, q3, k3, v3),
@@ -272,7 +447,7 @@ def _pallas_flash_call(q3, k3, v3, causal, tiles, interpret):
 
 
 @_kernel
-def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
+def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, mask,
                          tiles, interpret):
     """dq = Σ_j (p_ij * (dO_i·v_j^T - D_i)) · k_j * scale, streaming over j
     with the probability tile recomputed from (q, k, lse) in VMEM."""
@@ -280,7 +455,9 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    group = bh // k3.shape[0]
     block_q, block_k, span = tiles
+    diffusion = isinstance(mask, BlockDiffusion)
     scale = 1.0 / (d ** 0.5)
     n_span, per_span = t // span, span // block_k
 
@@ -296,15 +473,20 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
         lse = lse_ref[0]                                  # (bq, 1)
         dd = dd_ref[0]                                    # (bq, 1)
         row0 = i * block_q
+        if diffusion:
+            query_keys = _query_keys(mask, _positions(block_q, 0, row0))
 
         def step(j):
             k = _part(k_ref, 1, j, block_k)
             v = _part(v_ref, 1, j, block_k)
             s = jax.lax.dot_general(q, k, _NT,
                                     preferred_element_type=jnp.float32)
-            if causal:
-                col0 = (s_idx * per_span + j) * block_k
+            col0 = (s_idx * per_span + j) * block_k
+            if mask == "causal":
                 s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
+            elif diffusion:
+                s = _block_diffusion(s, query_keys, _key_value(
+                    mask, _positions(block_k, 1, col0)))
             p = jnp.exp(s - lse)                          # (bq, bk)
             dp = jax.lax.dot_general(do, v, _NT,
                                      preferred_element_type=jnp.float32)
@@ -312,15 +494,15 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
             acc_scr[:] = acc_scr[:] + jax.lax.dot_general(
                 ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-        live = pl.cdiv(row0 + block_q, block_k)
-        _each(0, _within(live, s_idx, per_span) if causal else per_span, step)
+        _walk(_live_keys(mask, row0, block_q, block_k, n_span * per_span),
+              s_idx, per_span, n_span, step)
 
         @pl.when(s_idx == n_span - 1)
         def _flush():
             # ds' own factor of the scale, once on the (bq, d) sum
             dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
 
-    kv_map = _kv_map(block_q, span, causal)
+    kv_map = _kv_map(block_q, span, mask, group)
     row_map = lambda b, i, s: (b, i, 0)
     return pl.pallas_call(
         kernel,
@@ -343,26 +525,35 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, causal,
 
 
 @_kernel
-def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, causal,
+def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
                           tiles, interpret):
     """dv = Σ_i p_ij^T · dO_i ; dk = Σ_i ds_ij^T · q_i * scale — a grid step
     owns a block of keys and walks the queries, in the transposed orientation
     (keys down the sublanes), with (dk, dv) accumulators in VMEM.
     ``lse_row`` and ``dd_row`` are ``(bh, 1, t)``: queries along the lanes,
-    as the transposed tile meets them."""
+    as the transposed tile meets them. Where ``group`` query heads share a
+    key/value head, the grid's last axis walks the group's heads (each with
+    its spans) and the accumulators take all of them."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    bkv = k3.shape[0]
+    group = bh // bkv
     block_k, block_q, span = tiles
+    diffusion = isinstance(mask, BlockDiffusion)
     scale = 1.0 / (d ** 0.5)
     n_span, per_span = t // span, span // block_q
 
+    def split(s):
+        """(query head within the group, span) of a step of the last axis."""
+        return (0, s) if group == 1 else (s // n_span, s % n_span)
+
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                dk_ref, dv_ref, dk_scr, dv_scr):
-        j, s_idx = pl.program_id(1), pl.program_id(2)
+        j, s_idx = pl.program_id(1), split(pl.program_id(2))[1]
 
-        @pl.when(s_idx == 0)
+        @pl.when(pl.program_id(2) == 0)
         def _init():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -370,6 +561,8 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, causal,
         k = k_ref[0] * scale
         v = v_ref[0]
         col0 = j * block_k
+        if diffusion:
+            key_value = _key_value(mask, _positions(block_k, 0, col0))
 
         def step(i):
             q = _part(q_ref, 1, i, block_q)
@@ -378,10 +571,13 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, causal,
             dd = _part(dd_ref, 2, i, block_q)          # (1, bq)
             s_t = jax.lax.dot_general(k, q, _NT,          # (bk, bq)
                                       preferred_element_type=jnp.float32)
-            if causal:
-                row0 = (s_idx * per_span + i) * block_q
+            row0 = (s_idx * per_span + i) * block_q
+            if mask == "causal":
                 s_t = jnp.where(_lead(s_t.shape, 0, 1) <= row0 - col0,
                                 s_t, -jnp.inf)
+            elif diffusion:
+                s_t = _block_diffusion(s_t, _query_keys(
+                    mask, _positions(block_q, 1, row0)), key_value)
             p_t = jnp.exp(s_t - lse)
             dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
                 p_t.astype(do.dtype), do, _NN,
@@ -393,28 +589,33 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, causal,
                 ds_t.astype(q.dtype), q, _NN,
                 preferred_element_type=jnp.float32)
 
-        # under the mask the loop starts with the query chunk that the
-        # block's first key reaches
-        live = col0 // block_q
-        _each(_within(live, s_idx, per_span) if causal else 0, per_span, step)
+        # under a mask the loop walks the query chunks that see the block's
+        # keys: from the diagonal on, or the blocks' own and later rows
+        _walk(_live_queries(mask, col0, block_k, block_q, n_span * per_span),
+              s_idx, per_span, n_span, step)
 
-        @pl.when(s_idx == n_span - 1)
+        @pl.when(pl.program_id(2) == group * n_span - 1)
         def _flush():
             dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
     def q_map(b, j, s):
-        # a span of queries above the diagonal names the first live one
-        first = (j * block_k) // span
-        return (b, jnp.maximum(s, first) if causal else s, 0)
+        head, s = split(s)
+        if mask == "causal":
+            # a span of queries above the diagonal names the first live one
+            s = jnp.maximum(s, (j * block_k) // span)
+        return (b if group == 1 else b * group + head, s, 0)
 
-    row_map = lambda b, j, s: (b, 0, q_map(b, j, s)[1])
+    def row_map(b, j, s):
+        head, span_idx, _ = q_map(b, j, s)
+        return (head, 0, span_idx)
+
     col_map = lambda b, j, s: (b, j, 0)
     return pl.pallas_call(
         kernel,
-        out_shape=[out_struct((bh, t, d), k3.dtype, q3, k3, v3, do3),
-                   out_struct((bh, t, d), v3.dtype, q3, k3, v3, do3)],
-        grid=(bh, t // block_k, n_span),
+        out_shape=[out_struct((bkv, t, d), k3.dtype, q3, k3, v3, do3),
+                   out_struct((bkv, t, d), v3.dtype, q3, k3, v3, do3)],
+        grid=(bkv, t // block_k, group * n_span),
         in_specs=[
             pl.BlockSpec((1, span, d), q_map),
             pl.BlockSpec((1, block_k, d), col_map),
@@ -471,57 +672,82 @@ def _tiles(t: int, d: int, itemsize: int) -> _Tiles | None:
                   span)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _tiles_under(mask, t: int, d: int, itemsize: int) -> _Tiles | None:
+    """``_tiles``, and under a block-diffusion mask over more than one tile a
+    block and a chunk that divide the clean length: no tile then straddles
+    the axis' middle, and the first chunk a row meets holds a key it sees."""
+    tiles = _tiles(t, d, itemsize)
+    if tiles is None or not isinstance(mask, BlockDiffusion) or tiles.block == t:
+        return tiles
+    tile = _pick_block(mask.length, _TILE_ROWS)
+    if tile is None or tiles.span % tile:
+        return None
+    return _Tiles(tile, tile, tiles.span)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = False,
-                    force_pallas: bool | None = None):
-    """Streaming-softmax attention over (batch, heads, T, d) operands.
+                    force_pallas: bool | None = None, mask=None):
+    """Streaming-softmax attention: ``q`` (batch, heads, T, d), ``k`` and ``v``
+    (batch, kv_heads, T, d) with ``kv_heads`` dividing ``heads`` (query head
+    ``i`` reads key/value head ``i // (heads // kv_heads)``; the kernels' index
+    maps do that, nothing is repeated in memory).
 
-    ``force_pallas``: None = pallas on TPU, reference jnp elsewhere; True =
-    pallas (interpreted off-TPU — tests); False = reference. Whatever the
-    setting, a ``T`` that ``_pick_block`` cannot tile runs the reference.
+    ``mask``: ``None`` for what ``causal`` says, or a static description:
+    ``"causal"`` or :class:`BlockDiffusion`. ``force_pallas``: None = pallas on
+    TPU, reference jnp elsewhere; True = pallas (interpreted off-TPU — tests);
+    False = reference. Whatever the setting, a ``T`` that ``_pick_block``
+    cannot tile runs the reference.
     """
-    return _fa_fwd(q, k, v, causal, force_pallas)[0]
+    return _fa_fwd(q, k, v, causal, force_pallas, mask)[0]
 
 
-def _fa_fwd(q, k, v, causal, force_pallas):
+def _fa_fwd(q, k, v, causal, force_pallas, mask):
+    mask = _as_mask(causal, mask)
+    if isinstance(mask, BlockDiffusion) and q.shape[2] != 2 * mask.length:
+        raise ValueError(f"{mask} is over {2 * mask.length} positions, the "
+                         f"operands have {q.shape[2]}")
     use_pallas = _on_tpu() if force_pallas is None else force_pallas
     b, h, t, d = q.shape
-    tiles = _tiles(t, d, q.dtype.itemsize)
+    hkv = k.shape[1]
+    tiles = _tiles_under(mask, t, d, q.dtype.itemsize)
     if not use_pallas or tiles is None:
-        return _reference_attention(q, k, v, causal), (q, k, v, None, None)
+        return _reference_attention(q, k, v, mask), (q, k, v, None, None)
     out, lse = _pallas_flash_call(
-        q.reshape(b * h, t, d), k.reshape(b * h, t, d),
-        v.reshape(b * h, t, d), causal, tiles, interpret=not _on_tpu())
+        q.reshape(b * h, t, d), k.reshape(b * hkv, t, d),
+        v.reshape(b * hkv, t, d), mask, tiles, interpret=not _on_tpu())
     out = out.reshape(b, h, t, d)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, force_pallas, res, g):
+def _fa_bwd(causal, force_pallas, mask, res, g):
+    mask = _as_mask(causal, mask)
     q, k, v, out, lse = res
     if lse is None:
         _, vjp = jax.vjp(
-            lambda qq, kk, vv: _reference_attention(qq, kk, vv, causal),
+            lambda qq, kk, vv: _reference_attention(qq, kk, vv, mask),
             q, k, v)
         return vjp(g)
-    return _flash_bwd(q, k, v, out, lse, g, causal)
+    return _flash_bwd(q, k, v, out, lse, g, mask)
 
 
-def _flash_bwd(q, k, v, out, lse, g, causal):
+def _flash_bwd(q, k, v, out, lse, g, mask):
     """Streaming flash-2 backward: O(T·d) memory, probability tiles recomputed
     from (q, k, lse) in VMEM."""
     b, h, t, d = q.shape
-    tiles = _tiles(t, d, q.dtype.itemsize)      # not None: _fa_fwd checked
-    reshape = lambda a: a.reshape(b * h, t, d)
+    hkv = k.shape[1]
+    tiles = _tiles_under(mask, t, d, q.dtype.itemsize)    # not None: _fa_fwd checked
+    reshape = lambda a: a.reshape(-1, t, d)
     q3, k3, v3, do3 = reshape(q), reshape(k), reshape(v), reshape(g)
     # D_i = rowsum(dO * O): one fused elementwise pass, O(T·d) reads
     dd = jnp.sum(do3.astype(jnp.float32) * reshape(out).astype(jnp.float32),
                  axis=-1, keepdims=True)                    # (bh, t, 1)
     interp = not _on_tpu()
-    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd, causal, tiles, interp)
+    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd, mask, tiles, interp)
     as_row = lambda a: a.reshape(b * h, 1, t)
     dk, dv = _pallas_flash_bwd_dkv(q3, k3, v3, do3, as_row(lse), as_row(dd),
-                                   causal, tiles, interp)
-    unshape = lambda a, like: a.reshape(b, h, t, d).astype(like.dtype)
+                                   mask, tiles, interp)
+    unshape = lambda a, like: a.reshape(like.shape).astype(like.dtype)
     return unshape(dq, q), unshape(dk, k), unshape(dv, v)
 
 

@@ -1,0 +1,222 @@
+"""A decoder built from a published configuration's keys.
+
+``TransformerLM`` writes its layers out one by one from the Torch table
+algebra, one kind of layer. ``ConfigDecoder`` is built from the keys a model's
+``config.json`` has (``hidden_size``, ``num_hidden_layers``,
+``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
+``moe_intermediate_size``, ``num_experts``, ``num_experts_per_tok``,
+``vocab_size``, ...) and runs its layers as ONE ``lax.scan`` body over
+stacked weights: the step program traces and compiles one layer whatever the
+depth. A layer is
+
+    h = h + Attention(RMSNorm(h), positions)      nn.MultiHeadAttention
+    h = h + Experts(RMSNorm(h))                   parallel.MoE, router="topk"
+
+with grouped-query heads of their own ``head_dim``, per-head RMSNorm on
+queries and keys, RoPE by position ids, and the routed SiLU-gated expert
+layer, which is told which experts it holds (``held``). The two modules are
+the layer's templates: the scan body calls their ``apply`` on a layer's
+slice of the stacked parameters. ``remat`` recomputes each layer in the
+backward pass (the scan then keeps one hidden state a layer).
+
+``block_diffusion=(L, b)`` trains by diffusion over blocks (BD3-LMs,
+arXiv:2503.09573): the input is ``[x_t ; x_0]``, a noised copy of a sequence of
+``L`` tokens and the clean sequence, ``2L`` positions with position ids
+``[0..L-1 ; 0..L-1]`` under ``kernels.flash_attention.BlockDiffusion(L, b)``;
+only the noised half reaches the head. Without it the decoder is causal.
+
+In training the output is ``Table(hidden, head weight)`` for a criterion that
+streams the vocabulary (``nn/fused_loss.py``): ``WeightedTokenCriterion``
+here, which weights each position and ignores those with a negative target.
+In evaluation it is the logits.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from bigdl_tpu import nn
+from bigdl_tpu.nn.abstractnn import TensorModule
+from bigdl_tpu.nn.criterion import AbstractCriterion
+from bigdl_tpu.nn.fused_loss import chunked_softmax_xent
+from bigdl_tpu.parallel.moe import MoE
+from bigdl_tpu.utils.random_generator import RandomGenerator
+from bigdl_tpu.utils.table import Table
+
+# the layers' health leaves as the decoder's own state: the worst layer for
+# what warns, the sum for what counts
+_HEALTH = {"aux_loss": jnp.sum, "router_z_loss": jnp.mean,
+           "dropped_fraction": jnp.max, "expert_load_max": jnp.max,
+           "pairs_held": jnp.sum}
+
+
+class ConfigDecoder(TensorModule):
+    """Token ids ``(N, T)`` int32 → ``Table(hidden, head)`` in training, logits
+    in evaluation; see the module docstring. ``num_experts`` is the router's
+    width (all experts), ``held=(first, count)`` what this chip holds."""
+
+    CONFIG_KEYS = ("vocab_size", "hidden_size", "num_hidden_layers",
+                   "num_attention_heads", "num_key_value_heads", "head_dim",
+                   "moe_intermediate_size", "num_experts",
+                   "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps",
+                   "rope_theta", "initializer_range")
+
+    def __init__(self, vocab_size: int, hidden_size: int,
+                 num_hidden_layers: int, num_attention_heads: int,
+                 num_key_value_heads: int, head_dim: int,
+                 moe_intermediate_size: int, num_experts: int,
+                 num_experts_per_tok: int, norm_topk_prob: bool = True,
+                 rms_norm_eps: float = 1e-6, rope_theta: float = 10000.0,
+                 initializer_range: float = 0.02,
+                 held: Optional[tuple] = None, qk_norm: bool = True,
+                 block_diffusion: Optional[tuple] = None, remat: bool = True):
+        super().__init__()
+        self.vocab_size, self.hidden_size = int(vocab_size), int(hidden_size)
+        self.num_hidden_layers = int(num_hidden_layers)
+        self.initializer_range = float(initializer_range)
+        self.remat = bool(remat)
+        self.block_diffusion = block_diffusion and tuple(block_diffusion)
+        mask = None
+        if self.block_diffusion:
+            from bigdl_tpu.kernels.flash_attention import BlockDiffusion
+            mask = BlockDiffusion(*self.block_diffusion)
+        self.norm = nn.RMSNorm(hidden_size, eps=rms_norm_eps)
+        self.attention = nn.MultiHeadAttention(
+            hidden_size, num_attention_heads, causal=mask is None,
+            with_bias=False, num_kv_heads=num_key_value_heads, rope=True,
+            rope_base=rope_theta, head_dim=head_dim, qk_norm=qk_norm,
+            qk_norm_eps=rms_norm_eps, mask=mask)
+        self.experts = MoE(hidden_size, moe_intermediate_size, num_experts,
+                           router="topk", top_k=num_experts_per_tok,
+                           norm_topk_prob=norm_topk_prob, held=held)
+        # the templates lend their shapes; their own copies are never read
+        self._layer_shapes = jax.tree_util.tree_map(lambda a: a.shape, {
+            "attn": self.attention.get_params(),
+            "attn_norm": self.norm.get_params()["weight"],
+            "moe": self.experts.get_params(),
+            "moe_norm": self.norm.get_params()["weight"]})
+        for template in (self.attention, self.experts, self.norm):
+            template._params, template._grads = {}, {}
+        self.reset()
+
+    @classmethod
+    def from_config(cls, config: dict, **more) -> "ConfigDecoder":
+        """From a ``config.json``'s keys (those of ``CONFIG_KEYS`` it has);
+        ``more`` overrides them and gives what a config has no key for."""
+        return cls(**{**{k: config[k] for k in cls.CONFIG_KEYS if k in config},
+                      **more})
+
+    def reset(self) -> None:
+        """Matrices N(0, ``initializer_range``), gains 1, drawn on the device
+        from the global generator's next salt."""
+        n, std = self.num_hidden_layers, self.initializer_range
+        is_shape = lambda v: isinstance(v, tuple)
+        # (shape, is a gain): a gain holds one number a channel
+        spec = {"embed": ((self.vocab_size, self.hidden_size), False),
+                "final_norm": ((self.hidden_size,), True),
+                "head": ((self.vocab_size, self.hidden_size), False),
+                "layers": jax.tree_util.tree_map(
+                    lambda shape: ((n,) + shape, len(shape) == 1),
+                    self._layer_shapes, is_leaf=is_shape)}
+        leaves, treedef = jax.tree_util.tree_flatten(spec, is_leaf=is_shape)
+
+        def draw(key):
+            return [jnp.ones(shape, jnp.float32) if gain else
+                    std * jax.random.normal(jax.random.fold_in(key, i), shape,
+                                            jnp.float32)
+                    for i, (shape, gain) in enumerate(leaves)]
+
+        key = jax.random.PRNGKey(RandomGenerator.next_salt() & 0x7FFFFFFF)
+        self._params = jax.tree_util.tree_unflatten(treedef, jax.jit(draw)(key))
+        self._state = {k: jnp.zeros((), jnp.float32) for k in _HEALTH}
+        self.zero_grad_parameters()
+
+    def zero_grad_parameters(self) -> None:
+        # no buffer of zeros the size of the model: the optimizers never read
+        # a module's own gradient, and `get_grads` makes one when asked
+        self._grads = {}
+
+    def get_grads(self) -> dict:
+        return self._grads or jax.tree_util.tree_map(jnp.zeros_like,
+                                                     self._params)
+
+    def grad_scales(self) -> dict:
+        scale = 0.0 if self.is_frozen() else self.scale_w
+        return jax.tree_util.tree_map(lambda _: scale, self._params)
+
+    def _positions(self, t: int):
+        if not self.block_diffusion:
+            return jnp.arange(t)
+        length = self.block_diffusion[0]
+        if t != 2 * length:
+            raise ValueError(f"block diffusion over {length} tokens takes "
+                             f"{2 * length} positions, got {t}")
+        return jnp.tile(jnp.arange(length), 2)
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        h = params["embed"][input]                              # (N, T, D)
+        positions = self._positions(input.shape[1])
+        norm, experts_state = self.norm, self.experts.get_state()
+
+        def layer(h, p):
+            a, _ = norm.apply({"weight": p["attn_norm"]}, {}, h)
+            a, _ = self.attention.apply(p["attn"], {}, (a, positions),
+                                        training=training)
+            h = h + a
+            m, _ = norm.apply({"weight": p["moe_norm"]}, {}, h)
+            m, health = self.experts.apply(p["moe"], experts_state, m,
+                                           training=training)
+            return h + m, {k: health[k] for k in _HEALTH}
+
+        if self.remat:
+            layer = jax.checkpoint(layer)
+        h, health = jax.lax.scan(layer, h, params["layers"])
+        if self.block_diffusion:
+            h = h[:, :self.block_diffusion[0]]      # the noised half predicts
+        h, _ = norm.apply({"weight": params["final_norm"]}, {}, h)
+        new_state = {k: jax.lax.stop_gradient(fold(health[k]))
+                     for k, fold in _HEALTH.items()}
+        if training:
+            return Table(h, params["head"]), new_state
+        return h @ params["head"].T, new_state
+
+    def __repr__(self):
+        return (f"ConfigDecoder({self.num_hidden_layers} layers, "
+                f"hidden={self.hidden_size}, {self.attention!r}, "
+                f"{self.experts!r})")
+
+
+class WeightedTokenCriterion(AbstractCriterion):
+    """``sum_i w_i * -log softmax(head(h_i))[y_i] / n`` over the ``n`` positions
+    of ``Table(hidden, head weight)``. ``target`` packs both as one float
+    array ``(N, 2, T)``: ``target[:, 0]`` the token ids (exact in float32 up
+    to 2**24; a negative id is ignored, loss and gradient 0),
+    ``target[:, 1]`` the weights. Block diffusion's objective has the masked
+    positions' clean tokens as targets with weight ``1/t`` and every other
+    position ignored. The vocabulary streams in chunks of ``chunk_size``."""
+
+    size_average = True
+
+    def __init__(self, chunk_size: int = 8192):
+        super().__init__()
+        self.chunk_size = int(chunk_size)
+
+    def apply(self, input, target):
+        hidden, weight = (input.values() if isinstance(input, Table)
+                          else list(input))[:2]
+        h2 = hidden.reshape(-1, hidden.shape[-1])
+        labels = target[:, 0].reshape(-1).astype(jnp.int32)
+        weights = target[:, 1].reshape(-1).astype(jnp.float32)
+        losses = chunked_softmax_xent(h2, weight, None, labels, self.chunk_size)
+        return jnp.sum(losses * weights) / h2.shape[0]
+
+    def __repr__(self):
+        return f"WeightedTokenCriterion(chunk={self.chunk_size})"
+
+
+from bigdl_tpu.utils.serializer import register as _register_serializable  # noqa: E402
+
+_register_serializable(ConfigDecoder)

@@ -49,6 +49,17 @@ class MultiHeadAttention(TensorModule):
     ``attention_impl``: "auto" (ring iff the mesh has a ``seq`` axis, else the
     single-chip flash kernel with off-TPU fallback), "ring", "flash", or
     "full" (plain fused attention, the numerical oracle).
+
+    ``head_dim`` (default ``embed_dim // num_heads``) may be set apart from
+    the width: the projections are then ``embed -> heads * head_dim`` and back
+    (32 heads of 128 over a width of 2048). ``qk_norm`` puts an RMSNorm with
+    a gain of its own over each head's query and key before RoPE. ``mask`` is
+    a static description the flash kernels take in ``causal``'s place
+    (``kernels.flash_attention.BlockDiffusion``). The input may be
+    ``(x, positions)``: RoPE then turns by those position ids, ``(t,)`` or
+    ``(b, t)``, which may repeat (a sequence and its noised copy), in place
+    of ``0 .. t-1``. On the flash path keys and values reach the kernel at
+    their own head count.
     """
 
     @property
@@ -64,12 +75,16 @@ class MultiHeadAttention(TensorModule):
                  rope: bool = False, rope_base: float = 10000.0,
                  window: Optional[int] = None,
                  lora_rank: Optional[int] = None,
-                 lora_alpha: Optional[float] = None):
+                 lora_alpha: Optional[float] = None,
+                 head_dim: Optional[int] = None, qk_norm: bool = False,
+                 qk_norm_eps: float = 1e-6, mask=None):
         super().__init__()
-        if embed_dim % num_heads != 0:
+        if head_dim is None and embed_dim % num_heads != 0:
             raise ValueError(f"embed_dim {embed_dim} % num_heads {num_heads} != 0")
-        if rope and (embed_dim // num_heads) % 2 != 0:
+        if rope and (head_dim or embed_dim // num_heads) % 2 != 0:
             raise ValueError("rope needs an even head_dim")
+        if mask is not None and (causal or window is not None):
+            raise ValueError("mask stands in causal's place: give one of them")
         if window is not None:
             if not causal:
                 raise ValueError("window (sliding-window attention) requires "
@@ -86,7 +101,9 @@ class MultiHeadAttention(TensorModule):
                              f"got {attention_impl!r}")
         self.embed_dim = embed_dim
         self.num_heads = num_heads
-        self.head_dim = embed_dim // num_heads
+        self.head_dim = int(head_dim or embed_dim // num_heads)
+        self.qk_norm, self.qk_norm_eps = bool(qk_norm), float(qk_norm_eps)
+        self.mask = mask
         # grouped-query attention (beyond reference): kv_heads < num_heads
         # shares each K/V head across a GROUP of query heads — the decode
         # KV cache (and its HBM bandwidth) shrinks by num_heads/kv_heads;
@@ -118,9 +135,15 @@ class MultiHeadAttention(TensorModule):
         self.w_init = w_init or Xavier()
         self.reset()
 
+    @property
+    def inner_dim(self) -> int:
+        """Width of the heads side by side: ``embed_dim`` unless ``head_dim``
+        was set apart from it (older pickles have no such attribute)."""
+        return self.num_heads * self.head_dim
+
     def reset(self) -> None:
-        e = self.embed_dim
-        if self.kv_heads == self.num_heads:
+        e, inner = self.embed_dim, self.inner_dim
+        if self.kv_heads == self.num_heads and inner == e:
             # plain MHA keeps the fused-QKV parameter layout (existing
             # checkpoints/archives stay loadable)
             self._params = {
@@ -136,16 +159,19 @@ class MultiHeadAttention(TensorModule):
             kv = 2 * self.kv_heads * self.head_dim
             self._params = {
                 "q_weight": jnp.asarray(
-                    self.w_init.init((e, e), fan_in=e, fan_out=e)),
+                    self.w_init.init((inner, e), fan_in=e, fan_out=inner)),
                 "kv_weight": jnp.asarray(
                     self.w_init.init((kv, e), fan_in=e, fan_out=kv)),
                 "out_weight": jnp.asarray(
-                    self.w_init.init((e, e), fan_in=e, fan_out=e)),
+                    self.w_init.init((e, inner), fan_in=inner, fan_out=e)),
             }
             if self.with_bias:
-                self._params["q_bias"] = jnp.zeros((e,), jnp.float32)
+                self._params["q_bias"] = jnp.zeros((inner,), jnp.float32)
                 self._params["kv_bias"] = jnp.zeros((kv,), jnp.float32)
                 self._params["out_bias"] = jnp.zeros((e,), jnp.float32)
+        if getattr(self, "qk_norm", False):
+            self._params["q_norm"] = jnp.ones((self.head_dim,), jnp.float32)
+            self._params["k_norm"] = jnp.ones((self.head_dim,), jnp.float32)
         if getattr(self, "lora_rank", None):
             self._extend_lora_params()   # adapters survive re-randomise
         self.zero_grad_parameters()
@@ -232,12 +258,25 @@ class MultiHeadAttention(TensorModule):
         return w
 
     def _attend(self, q, k, v):
+        """``k`` and ``v`` at their own head count: the flash kernels read a
+        group's shared head through their index maps; the other paths get
+        them widened."""
         from bigdl_tpu.parallel.ring_attention import full_attention, ring_attention
-        if self.attention_impl == "full":
-            return full_attention(q, k, v, causal=self.causal)
-        if self.attention_impl == "flash":
+        mask = getattr(self, "mask", None)
+
+        def flash():
             from bigdl_tpu.kernels.flash_attention import flash_attention
-            return flash_attention(q, k, v, self.causal)
+            return flash_attention(q, k, v, self.causal, None, mask)
+
+        if self.attention_impl == "flash":
+            return flash()
+        if self.attention_impl == "full":
+            if mask is None:
+                return full_attention(q, self._expand_kv(k), self._expand_kv(v),
+                                      causal=self.causal)
+            from bigdl_tpu.kernels.flash_attention import dense_mask
+            return full_attention(q, self._expand_kv(k), self._expand_kv(v),
+                                  kv_mask=dense_mask(mask, q.shape[2])[None, None])
         from bigdl_tpu.utils.engine import Engine
         mesh = Engine.mesh() if Engine.is_initialized() else None
         if mesh is None or Engine.SEQ_AXIS not in mesh.axis_names:
@@ -247,13 +286,28 @@ class MultiHeadAttention(TensorModule):
                     f"'{Engine.SEQ_AXIS}' axis")
             # single chip: the flash kernel engages on TPU and degrades to the
             # plain fused attention elsewhere (kernels/flash_attention.py)
-            from bigdl_tpu.kernels.flash_attention import flash_attention
-            return flash_attention(q, k, v, self.causal)
-        return ring_attention(q, k, v, mesh=mesh, seq_axis=Engine.SEQ_AXIS,
+            return flash()
+        if mask is not None:
+            raise ValueError("ring attention takes no mask but causal")
+        return ring_attention(q, self._expand_kv(k), self._expand_kv(v),
+                              mesh=mesh, seq_axis=Engine.SEQ_AXIS,
                               causal=self.causal)
 
+    def _head_norm(self, x, gain):
+        """RMSNorm over each head's ``head_dim``, statistics in fp32."""
+        ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(ms + self.qk_norm_eps).astype(x.dtype) \
+            * gain.astype(x.dtype)
+
     def _project_qkv(self, params, input, b, t):
-        if self.kv_heads == self.num_heads:
+        q, k, v = self._project(params, input, b, t)
+        if getattr(self, "qk_norm", False):
+            q = self._head_norm(q, params["q_norm"])
+            k = self._head_norm(k, params["k_norm"])
+        return q, k, v
+
+    def _project(self, params, input, b, t):
+        if "qkv_weight" in params:
             qkv = input @ self._w(params, "qkv_weight").T
             if self.with_bias:
                 qkv = qkv + params["qkv_bias"]
@@ -271,14 +325,18 @@ class MultiHeadAttention(TensorModule):
         return q, k, v                       # q (b,h,t,d); k,v (b,kv_h,t,d)
 
     def apply(self, params, state, input, *, training=False, rng=None):
-        b, t, e = input.shape
+        positions = None
+        if isinstance(input, (tuple, list)):
+            input, positions = input
+        b, t, _ = input.shape
+        e = self.inner_dim
         q, k, v = self._project_qkv(params, input, b, t)
         if isinstance(state, dict) and "page_k" in state:
             return self._paged_decode_step(params, state, q, k, v, b, t, e)
         if isinstance(state, dict) and "cache_k" in state:
             return self._decode_step(params, state, q, k, v, b, t, e)
         if getattr(self, "rope", False):
-            pos = jnp.arange(t)
+            pos = jnp.arange(t) if positions is None else positions
             q = rope_rotate(q, pos, self.rope_base)
             k = rope_rotate(k, pos, self.rope_base)
         if getattr(self, "window", None) is not None:
@@ -290,7 +348,7 @@ class MultiHeadAttention(TensorModule):
             o = full_attention(q, self._expand_kv(k), self._expand_kv(v),
                                causal=False, kv_mask=band[None, None])
         else:
-            o = self._attend(q, self._expand_kv(k), self._expand_kv(v))
+            o = self._attend(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, e)
         out = o @ self._w(params, "out_weight").T
         if self.with_bias:

@@ -47,6 +47,13 @@ SCOPE_CAST = "bigdl_cast"              # compute-dtype casts around the model
 SCOPE_LOSS = "bigdl_loss"              # criterion and added penalties
 SCOPE_GRAD_SCALE = "bigdl_grad_scale"  # per-layer scales, accumulation mean, clipping
 SCOPE_UPDATE = "bigdl_update"          # the optimizer method's update
+#: and of the routed expert layer (parallel/moe.py, router="topk"): the whole
+#: layer, and inside it the router with the sort and the gather, the grouped
+#: products with the gate between them, and the weighted sum back
+SCOPE_MOE = "bigdl_moe"
+SCOPE_MOE_ROUTE = "bigdl_moe_route"
+SCOPE_MOE_EXPERTS = "bigdl_moe_experts"
+SCOPE_MOE_COMBINE = "bigdl_moe_combine"
 
 
 class SpanRecord(NamedTuple):

@@ -141,7 +141,8 @@ class Optimizer:
     # aux loss trained blind — capacity drops were invisible in logs), and
     # any future layer exposing a same-named scalar rides for free.
     OBSERVABLE_STATE_LEAVES = ("aux_loss", "router_z_loss",
-                               "dropped_fraction", "expert_load_max")
+                               "dropped_fraction", "expert_load_max",
+                               "pairs_held")
 
     def __new__(cls, model: AbstractModule = None, dataset: AbstractDataSet = None,
                 criterion: AbstractCriterion = None, **kw):
@@ -1943,9 +1944,7 @@ class Optimizer:
         self._stop_copy_watcher()  # every feed/h2d span is recorded by now
         self._flush_pending(pending, state, keep_last=False)
         self._join_checkpoint_writer()  # optimize() returning implies ckpt durable
-        self.model.set_params(jax.device_get(params))
-        self.model.set_state(jax.device_get(mstate))
-        self._final_ostate = jax.device_get(ostate)
+        self._publish(params, mstate, ostate)
         if self.metrics.summary():
             logger.info("phase timings (mean): %r", self.metrics)
         stages = self._feed_stage_report(reg_snap0)
@@ -2063,6 +2062,21 @@ class Optimizer:
             raise NonFiniteLossError(
                 f"non-finite loss at iteration {it}: {v}", iteration=it)
         return v
+
+    def _publish(self, params, mstate, ostate) -> None:
+        """The trained state back onto the model, and the optimizer's slots
+        kept for the next ``optimize()`` call, as the arrays the last step left
+        on the device: a model holds device arrays from ``reset()`` on, and a
+        fetch to the host here with the copy back at the next call's start is
+        12 bytes a parameter each way under Adam (6.6 GB and 4.5 to 7 s a call
+        for 551M parameters, of a window of 10 s; PERF.md, PR 29). Across
+        processes an array is not wholly addressable, and the state is
+        gathered to the host as before."""
+        if jax.process_count() > 1:
+            params, mstate, ostate = jax.device_get((params, mstate, ostate))
+        self.model.set_params(params)
+        self.model.set_state(mstate)
+        self._final_ostate = ostate
 
     def _collect_state_metrics(self, mstate) -> list:
         """(tag, device_scalar) pairs for observable module-state leaves

@@ -1,16 +1,39 @@
 """Mixture-of-Experts with expert parallelism.
 
 No reference counterpart (SURVEY.md §2.3 checklist: EP/MoE absent upstream —
-design headroom for the TPU build, like ring attention). Switch-style top-1
-routing in the GShard dense-dispatch formulation: every tensor keeps a static
-shape (tokens × experts × capacity one-hot dispatch), so the whole layer is
-three einsums + a softmax — exactly what the SPMD partitioner can shard.
+design headroom for the TPU build, like ring attention). Two forms of one
+layer, by the router:
 
-Expert parallelism is NOT a separate communication path: the expert-indexed
-parameters (E, D, H) are sharded over a mesh axis via the same TPRules
-machinery as tensor parallelism (``expert_parallel_rules``), and XLA inserts
-the token all-to-all implied by the dispatch einsums over ICI. One mechanism,
-dp x ep meshes for free.
+- ``top1 | top2 | expert_choice``: Switch/GShard routing in the dense-dispatch
+  formulation: every tensor keeps a static shape (tokens × experts × capacity
+  one-hot dispatch), so the whole layer is three einsums + a softmax — exactly
+  what the SPMD partitioner can shard. What is over an expert's capacity is
+  dropped. Expert parallelism is NOT a separate communication path here: the
+  expert-indexed parameters (E, D, H) are sharded over a mesh axis via the
+  same TPRules machinery as tensor parallelism (``expert_parallel_rules``),
+  and XLA inserts the token all-to-all implied by the dispatch einsums.
+- ``topk``: the routed layer of today's open models (softmax over all
+  experts, the ``top_k`` largest, their weights renormalised, SiLU-gated
+  experts without biases), dropless, and told which experts it holds:
+  ``held=(first, count)``. The (token, expert) pairs are sorted by expert, the
+  rows gathered, one grouped product for gate and up and one for down run over
+  the held groups (``kernels/grouped_matmul.py``), and the weighted rows are
+  summed back per token. The layer returns the held experts' part of the sum.
+  The static bound on rows is every pair there is (tokens × top_k), so no pair
+  of a held expert can be dropped; the pairs of experts held elsewhere sort
+  last and cost a row and no product, except that the products always run
+  over at least twice the balanced expectation of rows (the rows after the
+  held pairs, weight 0), so that a step's time does not follow the router's
+  imbalance: at seeded weights the held pairs swing from 0.6 to 1.7 times the
+  expectation from batch to batch (PERF.md, PR 29).
+  ``held`` is what one rank of an
+  expert-parallel mesh axis holds: rank ``r`` of ``n`` holds
+  ``(r * E // n, E // n)``, routes over all ``E`` and computes its own part;
+  the parts add up to the whole layer (tests/test_moe_topk.py). On one chip
+  the layer runs so, without its exchange. Over a mesh axis the same layer
+  under ``shard_map`` with the pairs' exchange is not built yet
+  (``expert_parallel_rules`` shards ``w_in``/``w_out`` on the expert
+  dimension all the same, for the partitioner's dense fallback).
 """
 
 from __future__ import annotations
@@ -22,8 +45,65 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.initialization import InitializationMethod, RandomNormal
+from bigdl_tpu.obs import trace
 from bigdl_tpu.parallel.tensor_parallel import TPRules
 from jax.sharding import PartitionSpec as P
+
+
+def _sum_by_token(rows, slot, weight=None):
+    """(tokens, D) in fp32: ``sum_k weight[t, k] * rows[slot[t, k]]`` (weights
+    of 1 if none are given), one of a token's k rows at a time, so that
+    nothing of (tokens, k, D) is held."""
+    total = 0.0
+    for j in range(slot.shape[1]):
+        part = rows[slot[:, j]].astype(jnp.float32)
+        total = total + (part if weight is None else part * weight[:, j, None])
+    return total
+
+
+@jax.custom_vjp
+def _dispatch(x, token_of, slot):
+    """Row ``r`` of the result is token ``token_of[r]`` of ``x`` (tokens, D).
+    ``slot`` (tokens, k) is the inverse: where each of a token's pairs went.
+    The gradient is a gather too, a token's k rows summed: no scatter."""
+    return x[token_of]
+
+
+def _dispatch_fwd(x, token_of, slot):
+    return x[token_of], slot
+
+
+def _dispatch_bwd(slot, g):
+    return _sum_by_token(g, slot).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, weight, token_of, slot, order):
+    """(tokens, D) in fp32: ``sum_k weight[t, k] * out[slot[t, k]]``. ``order``
+    is the pair a row came from, for the weights as the rows' gradient
+    meets them."""
+    return _sum_by_token(out, slot, weight)
+
+
+def _combine_fwd(out, weight, token_of, slot, order):
+    return _sum_by_token(out, slot, weight), (out, weight, token_of, slot, order)
+
+
+def _combine_bwd(res, g):
+    out, weight, token_of, slot, order = res
+    by_row = weight.reshape(-1)[order]
+    # in the rows' own type before the gather: nothing of (rows, D) in fp32
+    d_out = g.astype(out.dtype)[token_of] * by_row[:, None].astype(out.dtype)
+    d_weight = jnp.stack(
+        [jnp.sum(out[slot[:, j]].astype(jnp.float32) * g, axis=-1)
+         for j in range(slot.shape[1])], axis=1)
+    return d_out, d_weight, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 class MoE(TensorModule):
@@ -56,24 +136,50 @@ class MoE(TensorModule):
 
     Scalars among these are auto-logged to TrainSummary/TB by the training
     loop (``Optimizer.OBSERVABLE_STATE_LEAVES``).
+
+    ``router="topk"`` (module docstring) takes ``top_k``, ``norm_topk_prob``
+    and ``held=(first, count)`` (default: all experts) and ignores
+    ``capacity_factor``: nothing is dropped. Its parameters are the router
+    ``w_gate`` (D, E) over all experts, ``w_in`` (count, D, 2H) holding each
+    held expert's gate and up matrices side by side and ``w_out`` (count, H, D).
+    Its state adds ``pairs_held`` (the (token, expert) pairs that reached a held
+    expert); ``expert_load`` is each expert's share of all pairs,
+    ``dropped_fraction`` the held pairs that no row was found for (0), and
+    ``aux_loss`` stays 0 (no balance loss is defined for it).
     """
 
     def __init__(self, input_size: int, hidden_size: int, n_experts: int,
                  capacity_factor: float = 1.25, router: str = "top1",
                  z_loss_weight: float = 0.0,
-                 w_init: Optional[InitializationMethod] = None):
+                 w_init: Optional[InitializationMethod] = None,
+                 top_k: Optional[int] = None, norm_topk_prob: bool = True,
+                 held: Optional[tuple] = None):
         super().__init__()
-        if router not in ("top1", "top2", "expert_choice"):
-            raise ValueError(f"router must be 'top1', 'top2' or "
-                             f"'expert_choice', got {router!r}")
+        if router not in ("top1", "top2", "expert_choice", "topk"):
+            raise ValueError(f"router must be 'top1', 'top2', "
+                             f"'expert_choice' or 'topk', got {router!r}")
         if n_experts < 2:
             raise ValueError(f"n_experts must be >= 2, got {n_experts!r}")
+        if router == "topk":
+            if top_k is None or not 1 <= int(top_k) <= n_experts:
+                raise ValueError(f"router='topk' needs 1 <= top_k <= "
+                                 f"n_experts, got {top_k!r}")
+            held = (0, n_experts) if held is None else tuple(int(v) for v in held)
+            if len(held) != 2 or held[0] < 0 or held[1] < 1 \
+                    or held[0] + held[1] > n_experts:
+                raise ValueError(f"held must be (first, count) within the "
+                                 f"{n_experts} experts, got {held!r}")
+        elif top_k is not None or held is not None:
+            raise ValueError("top_k and held belong to router='topk'")
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
         self.capacity_factor = capacity_factor
         self.router = router
         self.n_select = 2 if router == "top2" else 1
+        self.top_k = None if top_k is None else int(top_k)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.held = held
         self.z_loss_weight = float(z_loss_weight)
         self.w_init = w_init or RandomNormal(0.0, 0.02)
         self.reset()
@@ -85,13 +191,19 @@ class MoE(TensorModule):
             return jnp.asarray(self.w_init.init(shape, fan_in=fan_in,
                                                 fan_out=fan_out))
 
-        self._params = {
-            "w_gate": mk((d, e), d, e),
-            "w1": mk((e, d, h), d, h),
-            "b1": jnp.zeros((e, h), jnp.float32),
-            "w2": mk((e, h, d), h, d),
-            "b2": jnp.zeros((e, d), jnp.float32),
-        }
+        if getattr(self, "router", None) == "topk":
+            n = self.held[1]
+            self._params = {"w_gate": mk((d, e), d, e),
+                            "w_in": mk((n, d, 2 * h), d, h),
+                            "w_out": mk((n, h, d), h, d)}
+        else:
+            self._params = {
+                "w_gate": mk((d, e), d, e),
+                "w1": mk((e, d, h), d, h),
+                "b1": jnp.zeros((e, h), jnp.float32),
+                "w2": mk((e, h, d), h, d),
+                "b2": jnp.zeros((e, d), jnp.float32),
+            }
         # state structure is static (jit/donation): every observability leaf
         # exists from reset; penalty only when the layer trains a z-loss
         self._state = {"aux_loss": jnp.zeros((), jnp.float32),
@@ -99,6 +211,8 @@ class MoE(TensorModule):
                        "dropped_fraction": jnp.zeros((), jnp.float32),
                        "expert_load": jnp.zeros((e,), jnp.float32),
                        "expert_load_max": jnp.zeros((), jnp.float32)}
+        if getattr(self, "router", None) == "topk":
+            self._state["pairs_held"] = jnp.zeros((), jnp.float32)
         if self.z_loss_weight > 0:
             self._state["penalty"] = jnp.zeros((), jnp.float32)
         self.zero_grad_parameters()
@@ -169,12 +283,77 @@ class MoE(TensorModule):
             y = y.reshape(n, t, d)
         return y, new_state
 
+    def _apply_topk(self, params, state, x):
+        """The dropless routed layer over (tokens, D): see the module
+        docstring. Scopes: ``bigdl_moe_route`` (router, top-k, the sort and
+        the gather), ``bigdl_moe_experts`` (the two grouped products and the
+        gate between them), ``bigdl_moe_combine`` (the weighted sum back)."""
+        from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
+
+        tokens, k = x.shape[0], self.top_k
+        first, count = self.held
+        with jax.named_scope(trace.SCOPE_MOE_ROUTE):
+            logits = jnp.dot(x, params["w_gate"].astype(x.dtype),
+                             preferred_element_type=jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_e = jax.lax.top_k(probs, k)                  # (T, k)
+            if self.norm_topk_prob:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            here = (top_e >= first) & (top_e < first + count)
+            # pairs by held expert, those of experts held elsewhere last
+            group = jnp.where(here, top_e - first, count).reshape(-1)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            rows = jnp.arange(tokens * k, dtype=jnp.int32)
+            slot = jnp.zeros_like(rows).at[order].set(
+                rows, unique_indices=True).reshape(tokens, k)
+            sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :],
+                            axis=0, dtype=jnp.int32)
+            # the products run over at least twice the balanced expectation of
+            # rows: the last held group is given the rows after the held pairs
+            # (pairs of experts held elsewhere, weight 0) up to that floor, so
+            # that a step's time does not follow the router's imbalance
+            total = tokens * k
+            floor = min(total, -(-2 * total * count // self.n_experts // 8) * 8)
+            padded = sizes.at[-1].add(jnp.maximum(floor - jnp.sum(sizes), 0))
+            token_of = order // k
+            xs = _dispatch(x, token_of, slot)
+        with jax.named_scope(trace.SCOPE_MOE_EXPERTS):
+            h = grouped_matmul(xs, params["w_in"].astype(x.dtype), padded)
+            half = self.hidden_size
+            act = jax.nn.silu(h[:, :half]) * h[:, half:]
+            out = grouped_matmul(act, params["w_out"].astype(x.dtype), padded)
+        with jax.named_scope(trace.SCOPE_MOE_COMBINE):
+            y = _combine(out, jnp.where(here, top_p, 0.0), token_of, slot, order)
+
+        new_state = dict(state)
+        share = jax.lax.stop_gradient(jnp.mean(
+            (top_e.reshape(-1)[:, None] == jnp.arange(self.n_experts)[None, :])
+            .astype(jnp.float32), axis=0))
+        z = jax.scipy.special.logsumexp(jax.lax.stop_gradient(logits), axis=-1)
+        new_state["router_z_loss"] = jnp.mean(jnp.square(z))
+        if self.z_loss_weight > 0:
+            z = jax.scipy.special.logsumexp(logits, axis=-1)
+            new_state["penalty"] = self.z_loss_weight * jnp.mean(jnp.square(z))
+        pairs = jnp.sum(here).astype(jnp.float32)
+        new_state["aux_loss"] = jnp.zeros((), jnp.float32)
+        new_state["pairs_held"] = pairs
+        # every held pair has a row: the bound on rows is every pair there is
+        new_state["dropped_fraction"] = (
+            pairs - jnp.sum(sizes).astype(jnp.float32)) / jnp.maximum(pairs, 1.0)
+        new_state["expert_load"] = share
+        new_state["expert_load_max"] = jnp.max(share)
+        return y.astype(x.dtype), new_state
+
     def apply(self, params, state, input, *, training=False, rng=None):
         x = input
         flat = x.ndim == 3
         if flat:
             n, t, d = x.shape
             x = x.reshape(n * t, d)
+        if self.router == "topk":
+            with jax.named_scope(trace.SCOPE_MOE):
+                y, new_state = self._apply_topk(params, state, x)
+            return (y.reshape(n, t, d) if flat else y), new_state
         tokens = x.shape[0]
         e = self.n_experts
         cap = self._capacity(tokens)
@@ -249,6 +428,11 @@ def expert_parallel_rules(moe_path_prefix: str = "", axis: str = "model",
     r.add(f"{pre}b1$", P(axis, None))
     r.add(f"{pre}w2$", P(axis, None, None))
     r.add(f"{pre}b2$", P(axis, None))
+    # router="topk": the held experts' matrices. Rank r of an axis of n
+    # holds held=(r * E // n, E // n); the exchange that takes a token's rows
+    # to the rank holding its expert (shard_map) is not built yet
+    r.add(f"{pre}w_in$", P(axis, None, None))
+    r.add(f"{pre}w_out$", P(axis, None, None))
     return r
 
 

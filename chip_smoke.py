@@ -237,6 +237,58 @@ def _kernel_numerics(shape=(16, 8, 512, 64)) -> dict:
     return out
 
 
+def routed_kernels(length: int = 512, block: int = 4) -> None:
+    """What the routed block-diffusion decoder adds, on the chip against its
+    plain forms: the flash kernels under the block-diffusion mask at
+    (2, 32/4, 1024, 128), grouped-query heads at their own count, and the
+    grouped product over held groups with rows past them (``ragged_dot``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.kernels.flash_attention import (
+        BlockDiffusion, _reference_attention, flash_attention)
+    from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
+
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(2)
+    mask = BlockDiffusion(length, block)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, h, 2 * length, 128)), jnp.bfloat16)
+               for h in (32, 4, 4))
+
+    def sq(fn):
+        return lambda *a: jnp.sum(jnp.square(fn(*a).astype(jnp.float32)))
+
+    kernel = lambda a, b, c: flash_attention(a, b, c, False, None, mask)
+    got = [jax.jit(kernel)(q, k, v)] + list(
+        jax.jit(jax.grad(sq(kernel), argnums=(0, 1, 2)))(q, k, v))
+    with jax.default_matmul_precision("highest"):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        dense = lambda a, b, c: _reference_attention(a, b, c, mask)
+        want = [dense(*f32)] + list(jax.grad(sq(dense), argnums=(0, 1, 2))(*f32))
+    flash = [rel_err(a, b) for a, b in zip(got, want)]
+    check(max(flash) <= TOL_FLASH, f"flash attention under {mask} off its "
+          f"reference: fwd/dq/dk/dv errors {flash} > {TOL_FLASH}")
+
+    sizes = jnp.asarray([300, 0, 1024, 213], jnp.int32)
+    rows = jnp.asarray(rng.normal(size=(4096, 2048)), jnp.bfloat16)
+    mats = jnp.asarray(0.02 * rng.normal(size=(4, 2048, 1536)), jnp.bfloat16)
+    product = lambda force: lambda a, b: grouped_matmul(a, b, sizes, force)
+    got = [jax.jit(product(None))(rows, mats)] + list(
+        jax.jit(jax.grad(sq(product(None)), argnums=(0, 1)))(rows, mats))
+    with jax.default_matmul_precision("highest"):
+        f32 = [rows.astype(jnp.float32), mats.astype(jnp.float32)]
+        want = [product(False)(*f32)] + list(
+            jax.grad(sq(product(False)), argnums=(0, 1))(*f32))
+    grouped = [rel_err(a, b) for a, b in zip(got, want)]
+    check(max(grouped) <= TOL_FLASH, f"grouped product off ragged_dot: "
+          f"fwd/drows/dmats errors {grouped} > {TOL_FLASH}")
+    check(float(jnp.max(jnp.abs(got[0][int(sizes.sum()):]))) == 0.0,
+          "rows past the held groups are not zero")
+    say("routed-kernels", flash_block_diffusion_rel_err=[float(f"{e:.3g}") for e in flash],
+        grouped_matmul_rel_err=[float(f"{e:.3g}") for e in grouped],
+        seconds_compile_included=round(time.perf_counter() - t0, 1))
+
+
 def train_lm(batch: int = 16, steps: int = 8, require_kernels: bool = True):
     import jax
     import jax.numpy as jnp
@@ -435,6 +487,7 @@ def main() -> int:
     devices = attach()
     train_vision()
     train_lm()
+    routed_kernels()
     serve()
     all_devices()
     say("done", seconds_total=round(time.perf_counter() - t0, 1),

@@ -121,6 +121,46 @@ def test_untileable_length_is_reference_by_rule(on_tpu, t):
     assert "tpu_custom_call" not in text
 
 
+@pytest.mark.parametrize("shape,kv,length,dtype", [
+    ((2, 32, 8192, 128), 4, 4096, jnp.bfloat16),    # the SDAR cell
+    ((2, 8, 1024, 128), 2, 512, jnp.bfloat16),      # chip_smoke's
+    ((1, 4, 2048, 128), 1, 1024, jnp.float32),
+    ((1, 4, 128, 128), 1, 64, jnp.float32),         # whole-axis tiles
+])
+def test_flash_lowers_under_block_diffusion(on_tpu, shape, kv, length, dtype):
+    """Forward and both backward kernels with the block-diffusion mask and
+    key/value heads at their own count (an index map, nothing repeated)."""
+    mask = fa.BlockDiffusion(length, 4)
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    k = jax.ShapeDtypeStruct((shape[0], kv) + shape[2:], dtype)
+    grad = _tpu_module(
+        jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, False, None, mask)
+                 .astype(jnp.float32).sum(), argnums=(0, 1, 2)), q, k, k)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(grad, name) == 1, name
+    assert "repeat" not in grad
+
+
+def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch):
+    """The grouped products of the SDAR cell's expert layer: 131,072 rows
+    (16,384 positions x 8), 16 held experts, gate and up side by side, then
+    down; forward and the gradients for rows and matrices."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    rows = jax.ShapeDtypeStruct((131072, 2048), jnp.bfloat16)
+    w_in = jax.ShapeDtypeStruct((16, 2048, 1536), jnp.bfloat16)
+    w_out = jax.ShapeDtypeStruct((16, 768, 2048), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32)
+
+    def experts(x, a, b, n):
+        h = gm.grouped_matmul(x, a, n)
+        return gm.grouped_matmul(jax.nn.silu(h[:, :768]) * h[:, 768:], b, n) \
+            .astype(jnp.float32).sum()
+
+    text = _tpu_module(jax.grad(experts, argnums=(0, 1, 2)), rows, w_in, w_out, sizes)
+    assert text.count("tpu_custom_call") >= 5       # the first forward, 2 + 2 backward
+
+
 # ----------------------------------------------------------- layer norm
 @pytest.mark.parametrize("h", [64, 512])
 @pytest.mark.parametrize("rows", [1, 5, 6, 12, 13, 24, 300, 8192])

@@ -1,0 +1,149 @@
+"""The dropless routed expert layer (``MoE(router="topk")``): a layer that is
+told which experts it holds computes their part of the sum, the parts of all
+the shares add up to the uncut layer, and no pair for a held expert is
+dropped under any imbalance."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
+from bigdl_tpu.parallel import MoE, expert_parallel_rules
+
+D, HID, E, K, T = 64, 32, 16, 4, 96
+
+
+def _uncut(x, p, k, first=0, count=None, norm=True):
+    """The layer written out densely: softmax over all experts, the k
+    largest, renormalised, every expert of [first, first + count) over every
+    token with its routing weight."""
+    hid = p["w_out"].shape[1]
+    probs = jax.nn.softmax(x @ p["w_gate"], -1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    if norm:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(first, first + (p["w_in"].shape[0] if count is None else count)):
+        w = jnp.sum(jnp.where(top_e == e, top_p, 0.0), -1)
+        h = x @ p["w_in"][e]
+        y = y + w[:, None] * ((jax.nn.silu(h[:, :hid]) * h[:, hid:]) @ p["w_out"][e])
+    return y
+
+
+@pytest.fixture
+def layer():
+    key = jax.random.PRNGKey(1)
+    full = MoE(D, HID, E, router="topk", top_k=K)
+    params = {k: 0.1 * jax.random.normal(jax.random.fold_in(key, i), v.shape)
+              for i, (k, v) in enumerate(sorted(full.get_params().items()))}
+    return full, params, jax.random.normal(key, (T, D))
+
+
+def _share(params, first, count):
+    return {"w_gate": params["w_gate"], "w_in": params["w_in"][first:first + count],
+            "w_out": params["w_out"][first:first + count]}
+
+
+def test_all_experts_held_is_the_uncut_layer(layer):
+    full, params, x = layer
+    with jax.default_matmul_precision("highest"):
+        y, state = full.apply(params, full.get_state(), x)
+        np.testing.assert_allclose(y, _uncut(x, params, K), atol=2e-6)
+    assert float(state["pairs_held"]) == T * K
+    assert float(state["dropped_fraction"]) == 0.0
+    np.testing.assert_allclose(float(jnp.sum(state["expert_load"])), 1.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("norm", [True, False])
+def test_eight_shares_add_up_to_the_uncut_layer(layer, norm):
+    """The guide's tie of share to model: each of eight chips holds 2 of the
+    16 experts, routes over all 16 and computes its own part."""
+    _, params, x = layer
+    total, pairs = 0.0, 0.0
+    with jax.default_matmul_precision("highest"):
+        for rank in range(8):
+            m = MoE(D, HID, E, router="topk", top_k=K, held=(2 * rank, 2),
+                    norm_topk_prob=norm)
+            y, state = m.apply(_share(params, 2 * rank, 2), m.get_state(), x)
+            np.testing.assert_allclose(
+                y, _uncut(x, params, K, 2 * rank, 2, norm), atol=2e-6)
+            total, pairs = total + y, pairs + float(state["pairs_held"])
+        np.testing.assert_allclose(total, _uncut(x, params, K, norm=norm), atol=2e-6)
+    assert pairs == T * K
+
+
+@pytest.mark.parametrize("towards,pairs", [("held", T * K), ("elsewhere", 0)])
+def test_no_pair_is_dropped_under_imbalance(layer, towards, pairs):
+    """A router biased so that every token's k experts are held here (every
+    pair has a row: the static bound is tokens x k), and so that none is."""
+    _, params, x = layer
+    first, count = 4, 4
+    bias = jnp.where((jnp.arange(E) >= first) & (jnp.arange(E) < first + count),
+                     50.0, -50.0) * (1 if towards == "held" else -1)
+    # a column of ones carries the bias through the router's matrix
+    x = x.at[:, 0].set(1.0)
+    params = dict(params, w_gate=params["w_gate"].at[0].set(bias))
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
+    with jax.default_matmul_precision("highest"):
+        y, state = m.apply(_share(params, first, count), m.get_state(), x)
+        np.testing.assert_allclose(y, _uncut(x, params, K, first, count), atol=2e-6)
+    assert float(state["pairs_held"]) == pairs
+    assert float(state["dropped_fraction"]) == 0.0
+    if not pairs:
+        assert float(jnp.max(jnp.abs(y))) == 0.0
+
+
+def test_gradients_match_the_uncut_layer(layer):
+    _, params, x = layer
+    first, count = 4, 4
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
+    probe = jnp.cos(jnp.arange(D))
+
+    def routed(p, x):
+        return jnp.sum(m.apply(_share(p, first, count), m.get_state(), x)[0] * probe)
+
+    def dense(p, x):
+        return jnp.sum(_uncut(x, p, K, first, count) * probe)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(routed, (0, 1))(params, x)
+        want = jax.grad(dense, (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=5e-6)
+
+
+@pytest.mark.parametrize("sizes", [[40, 0, 100], [0, 0, 0], [128, 64, 64]])
+def test_grouped_matmul_kernel_matches_the_plain_form(sizes):
+    """The Pallas kernel through the interpreter against ``ragged_dot``,
+    forward and both gradients; rows past the groups come out zero."""
+    key = jax.random.PRNGKey(0)
+    lhs = jax.random.normal(key, (256, 64))
+    rhs = jax.random.normal(jax.random.fold_in(key, 1), (3, 64, 48))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    f = lambda a, b, force: jnp.sum(jnp.sin(grouped_matmul(a, b, sizes, force)))
+    with jax.default_matmul_precision("highest"):
+        got, want = grouped_matmul(lhs, rhs, sizes, True), grouped_matmul(lhs, rhs, sizes, False)
+        np.testing.assert_allclose(got, want, atol=1e-4)
+        assert float(jnp.max(jnp.abs(got[int(sizes.sum()):]), initial=0.0)) == 0.0
+        for a, b in zip(jax.grad(f, (0, 1))(lhs, rhs, True),
+                        jax.grad(f, (0, 1))(lhs, rhs, False)):
+            np.testing.assert_allclose(a, b, atol=1e-4)
+
+
+def test_topk_arguments_are_checked():
+    with pytest.raises(ValueError):
+        MoE(D, HID, E, router="topk")                       # no top_k
+    with pytest.raises(ValueError):
+        MoE(D, HID, E, router="topk", top_k=4, held=(12, 8))  # past the last
+    with pytest.raises(ValueError):
+        MoE(D, HID, E, router="top1", held=(0, 4))          # not this router's
+
+
+def test_expert_parallel_rules_shard_the_held_matrices():
+    from jax.sharding import PartitionSpec as P
+    m = MoE(D, HID, E, router="topk", top_k=K)
+    rules = expert_parallel_rules(axis="model")
+    specs = {k: rules.spec_for(f"moe/{k}", v.shape) for k, v in m.get_params().items()}
+    assert specs["w_in"] == P("model", None, None) == specs["w_out"]
+    assert specs["w_gate"] == P()                   # the router stays whole

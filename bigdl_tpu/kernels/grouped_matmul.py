@@ -3,9 +3,13 @@
 ``grouped_matmul(lhs, rhs, group_sizes)``: ``lhs`` is ``(m, k)`` with its rows
 sorted by group, ``rhs`` ``(g, k, n)`` one matrix a group, ``group_sizes``
 ``(g,)`` int32; rows ``[sum(sizes[:i]), sum(sizes[:i + 1]))`` meet ``rhs[i]``
-and the rows past ``sum(group_sizes)`` (a routed layer's pairs for experts it
-does not hold, under a static bound on rows) come out as zeros, with zero
-gradients. ``m`` is static, the sizes are data.
+and the rows past ``sum(group_sizes)`` come out as zeros, with zero
+gradients. ``m`` is static, the sizes are data. The routed layer
+(``parallel/moe.py``) calls it over one pass's rows, its static bound of twice
+the balanced expectation of held pairs (32,768 rows in the benchmark's SDAR
+cell, not the 131,072 pairs there are), with sizes that cover every row: the
+rows after the held pairs belong to the last held group, so the group of rows
+that no held group owns is empty there.
 
 On TPU this is the Pallas kernel jax ships (``jax.experimental.pallas.ops.tpu
 .megablox``: a grid over the row tiles that hold a group's rows, found from
@@ -28,12 +32,14 @@ from bigdl_tpu.kernels.layernorm import _on_tpu
 
 SCOPE = "bigdl_gmm"
 
-# The kernel's tiles (rows, contraction, columns), the best of a sweep on a
-# v5e at the benchmark's SDAR shapes (131,072 rows, 16 groups of about 1,024,
-# 2048 x 1536 and 768 x 2048; PERF.md, PR 29). A group's first and last row
-# tiles are shared with its neighbours and computed once for each, so a tile
-# well under a group's rows wastes least; the whole contraction in one tile
-# saves the accumulator's round trips.
+# The kernel's tiles (rows, contraction, columns), the best of two sweeps on a
+# v5e at the benchmark's SDAR shapes (2048 x 1536 and 768 x 2048, 16 groups):
+# at 131,072 rows (PERF.md, PR 29) and at a pass's 32,768 rows, about 1,024
+# live rows a group and the rest in the last (PR 30: (128, 2048, 768) and
+# (512, 2048, 512) within 3%, 512 or more rows by 768 columns or more out of
+# VMEM). A group's first and last row tiles are shared with its neighbours
+# and computed once for each, so a tile well under a group's rows wastes
+# least; the whole contraction in one tile saves the accumulator's round trips.
 _TILE_M, _TILE_K, _TILE_N = 256, 2048, 768
 
 

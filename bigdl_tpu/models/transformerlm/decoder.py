@@ -50,7 +50,7 @@ from bigdl_tpu.utils.table import Table
 # what warns, the sum for what counts
 _HEALTH = {"aux_loss": jnp.sum, "router_z_loss": jnp.mean,
            "dropped_fraction": jnp.max, "expert_load_max": jnp.max,
-           "pairs_held": jnp.sum}
+           "pairs_held": jnp.sum, "row_passes": jnp.max}
 
 
 class ConfigDecoder(TensorModule):

@@ -19,13 +19,23 @@ layer, by the router:
   rows gathered, one grouped product for gate and up and one for down run over
   the held groups (``kernels/grouped_matmul.py``), and the weighted rows are
   summed back per token. The layer returns the held experts' part of the sum.
-  The static bound on rows is every pair there is (tokens × top_k), so no pair
-  of a held expert can be dropped; the pairs of experts held elsewhere sort
-  last and cost a row and no product, except that the products always run
-  over at least twice the balanced expectation of rows (the rows after the
-  held pairs, weight 0), so that a step's time does not follow the router's
-  imbalance: at seeded weights the held pairs swing from 0.6 to 1.7 times the
-  expectation from batch to batch (PERF.md, PR 29).
+  The static bound on rows is what the layer holds, not every pair there is:
+  a pass works over ``R`` sorted positions, twice the balanced expectation of
+  held pairs (``2 * tokens * top_k * count / n_experts`` in whole sublanes;
+  every pair when all experts are held), and the layer is dropless by
+  repetition: ``row_passes = max(1, ceil(held pairs / R))`` is data, and one
+  hand-written VJP around the routed computation repeats the bounded pass so
+  often, forward and backward (the first pass stands outside a loop whose
+  trip count is the rest: once in every step unless the router sends this
+  share more than twice its expectation). So no pair of a held expert can be
+  dropped at any imbalance, nothing of (tokens × top_k, feature) exists, and
+  no capacity is set by anyone. A pass's products always run over all ``R``
+  rows (the rows after the held pairs, weight 0, go to the last held group),
+  so that a step's time follows the router's imbalance by whole passes only:
+  at the benchmark's seeded weights a layer's held pairs are 0.02 to 2.7
+  times the expectation from batch to batch, and where only the held experts
+  answer (one chip's share, run alone) training pulls the router towards
+  them within tens of steps (PERF.md, PR 29 and PR 30).
   ``held`` is what one rank of an
   expert-parallel mesh axis holds: rank ``r`` of ``n`` holds
   ``(r * E // n, E // n)``, routes over all ``E`` and computes its own part;
@@ -38,6 +48,7 @@ layer, by the router:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -50,60 +61,120 @@ from bigdl_tpu.parallel.tensor_parallel import TPRules
 from jax.sharding import PartitionSpec as P
 
 
-def _sum_by_token(rows, slot, weight=None):
-    """(tokens, D) in fp32: ``sum_k weight[t, k] * rows[slot[t, k]]`` (weights
-    of 1 if none are given), one of a token's k rows at a time, so that
-    nothing of (tokens, k, D) is held."""
-    total = 0.0
-    for j in range(slot.shape[1]):
-        part = rows[slot[:, j]].astype(jnp.float32)
-        total = total + (part if weight is None else part * weight[:, j, None])
-    return total
+def _held_rows(total: int, count: int, n_experts: int) -> int:
+    """The static bound on rows of one pass: twice the balanced expectation
+    of held pairs, in whole sublanes, and never more than every pair."""
+    return min(total, -(-2 * total * count // n_experts // 8) * 8)
 
 
-@jax.custom_vjp
-def _dispatch(x, token_of, slot):
-    """Row ``r`` of the result is token ``token_of[r]`` of ``x`` (tokens, D).
-    ``slot`` (tokens, k) is the inverse: where each of a token's pairs went.
-    The gradient is a gather too, a token's k rows summed: no scatter."""
-    return x[token_of]
+def _window(x, weight, order, sizes, start, rows: int):
+    """Positions ``[start, start + rows)`` of the pairs sorted by expert: the
+    pair of each row, whether it is a held pair, its token, the tokens' rows
+    of ``x``, the routing weight of each row (0 past the held pairs) and the
+    rows of each held group inside the window. The rows after the held pairs
+    (pairs of experts held elsewhere) go to the last held group, so that the
+    products always run over ``rows`` rows: a pass's time does not follow
+    the router's imbalance."""
+    with jax.named_scope(trace.SCOPE_MOE_ROUTE):
+        pair = jax.lax.dynamic_slice(order, (start,), (rows,))
+        token_of = pair // weight.shape[1]
+        ends = jnp.cumsum(sizes)
+        inside = (jnp.clip(ends, start, start + rows)
+                  - jnp.clip(ends - sizes, start, start + rows))
+        padded = inside.at[-1].add(rows - jnp.sum(inside))
+        live = start + jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+        by_row = jnp.where(live, weight.reshape(-1)[pair], 0.0)
+        return pair, live, token_of, x[token_of], by_row, padded
 
 
-def _dispatch_fwd(x, token_of, slot):
-    return x[token_of], slot
+def _sum_by_token(rows, token_of, tokens: int, weight=None):
+    """(tokens, D) in fp32: row ``r`` of ``rows``, times ``weight[r]`` if
+    given, added to token ``token_of[r]``."""
+    part = rows.astype(jnp.float32)
+    if weight is not None:
+        part = part * weight[:, None]
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token_of].add(part)
 
 
-def _dispatch_bwd(slot, g):
-    return _sum_by_token(g, slot).astype(g.dtype), None, None
+def _experts(xs, w_in, w_out, sizes, hidden: int):
+    """Gate and up in one grouped product, the SiLU gate, down in another."""
+    from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
+
+    with jax.named_scope(trace.SCOPE_MOE_EXPERTS):
+        h = grouped_matmul(xs, w_in, sizes)
+        act = jax.nn.silu(h[:, :hidden]) * h[:, hidden:]
+        return grouped_matmul(act, w_out, sizes)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _over_passes(one_pass, passes, rows: int, total: int):
+    """``one_pass(start)`` summed over ``start = 0, rows, ...``, ``passes`` of
+    them (data). The first stands outside the loop: its results are the
+    loop's carry, so a step that needs one pass adds nothing to zeros."""
+    first = one_pass(jnp.zeros((), jnp.int32))
+    if rows == total:               # every pair fits: never a second pass
+        return first
+
+    def more(p, acc):
+        return jax.tree_util.tree_map(
+            lambda a, b: (a.astype(jnp.float32) + b.astype(jnp.float32)).astype(a.dtype),
+            acc, one_pass(p * rows))
+
+    return jax.lax.fori_loop(1, passes, more, first)
 
 
-@jax.custom_vjp
-def _combine(out, weight, token_of, slot, order):
-    """(tokens, D) in fp32: ``sum_k weight[t, k] * out[slot[t, k]]``. ``order``
-    is the pair a row came from, for the weights as the rows' gradient
-    meets them."""
-    return _sum_by_token(out, slot, weight)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _routed(x, w_in, w_out, weight, order, sizes, passes, rows, hidden):
+    """The held experts over the tokens they were routed: (tokens, D) in fp32,
+    ``sum_k weight[t, k] * expert(x[t])`` over a token's held pairs. ``order``
+    is the pairs sorted by held expert (padded to whole passes), ``sizes`` the
+    pairs of each, ``passes`` how many windows of ``rows`` sorted positions
+    hold them all. Hand-written VJP: forward and backward each repeat the
+    bounded pass, so nothing sized for the worst case exists in either."""
+    return _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden)[0]
 
 
-def _combine_fwd(out, weight, token_of, slot, order):
-    return _sum_by_token(out, slot, weight), (out, weight, token_of, slot, order)
+def _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden):
+    tokens, k = weight.shape
+
+    def one_pass(start):
+        _, _, token_of, xs, by_row, padded = _window(x, weight, order, sizes, start, rows)
+        out = _experts(xs, w_in, w_out, padded, hidden)
+        with jax.named_scope(trace.SCOPE_MOE_COMBINE):
+            return _sum_by_token(out, token_of, tokens, by_row)
+
+    y = _over_passes(one_pass, passes, rows, tokens * k)
+    return y, (x, w_in, w_out, weight, order, sizes, passes)
 
 
-def _combine_bwd(res, g):
-    out, weight, token_of, slot, order = res
-    by_row = weight.reshape(-1)[order]
+def _routed_bwd(rows, hidden, res, g):
+    x, w_in, w_out, weight, order, sizes, passes = res
+    tokens, k = weight.shape
     # in the rows' own type before the gather: nothing of (rows, D) in fp32
-    d_out = g.astype(out.dtype)[token_of] * by_row[:, None].astype(out.dtype)
-    d_weight = jnp.stack(
-        [jnp.sum(out[slot[:, j]].astype(jnp.float32) * g, axis=-1)
-         for j in range(slot.shape[1])], axis=1)
-    return d_out, d_weight, None, None, None
+    g = g.astype(x.dtype)
+
+    def one_pass(start):
+        pair, live, token_of, xs, by_row, padded = _window(
+            x, weight, order, sizes, start, rows)
+        out, pull = jax.vjp(
+            lambda a, b, c: _experts(a, b, c, padded, hidden), xs, w_in, w_out)
+        with jax.named_scope(trace.SCOPE_MOE_COMBINE):
+            g_rows = g[token_of]
+            d_by_row = jnp.where(live, jnp.sum(
+                out.astype(jnp.float32) * g_rows, axis=-1), 0.0)
+            d_weight = jnp.zeros((tokens * k,), jnp.float32).at[pair].add(
+                d_by_row).reshape(tokens, k)
+            d_out = g_rows * by_row[:, None].astype(out.dtype)
+        d_xs, d_in, d_out_w = pull(d_out)
+        with jax.named_scope(trace.SCOPE_MOE_ROUTE):
+            d_x = _sum_by_token(d_xs, token_of, tokens)
+        return d_x, d_in, d_out_w, d_weight
+
+    d_x, d_in, d_out_w, d_weight = _over_passes(one_pass, passes, rows, tokens * k)
+    return (d_x.astype(x.dtype), d_in, d_out_w, d_weight.astype(weight.dtype),
+            None, None, None)
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class MoE(TensorModule):
@@ -143,8 +214,10 @@ class MoE(TensorModule):
     ``w_gate`` (D, E) over all experts, ``w_in`` (count, D, 2H) holding each
     held expert's gate and up matrices side by side and ``w_out`` (count, H, D).
     Its state adds ``pairs_held`` (the (token, expert) pairs that reached a held
-    expert); ``expert_load`` is each expert's share of all pairs,
-    ``dropped_fraction`` the held pairs that no row was found for (0), and
+    expert) and ``row_passes`` (the passes over its bound on rows that held
+    them: 1 unless this share got more than twice its balanced expectation);
+    ``expert_load`` is each expert's share of all pairs,
+    ``dropped_fraction`` the held pairs that no pass found a row for (0), and
     ``aux_loss`` stays 0 (no balance loss is defined for it).
     """
 
@@ -213,6 +286,7 @@ class MoE(TensorModule):
                        "expert_load_max": jnp.zeros((), jnp.float32)}
         if getattr(self, "router", None) == "topk":
             self._state["pairs_held"] = jnp.zeros((), jnp.float32)
+            self._state["row_passes"] = jnp.zeros((), jnp.float32)
         if self.z_loss_weight > 0:
             self._state["penalty"] = jnp.zeros((), jnp.float32)
         self.zero_grad_parameters()
@@ -285,13 +359,14 @@ class MoE(TensorModule):
 
     def _apply_topk(self, params, state, x):
         """The dropless routed layer over (tokens, D): see the module
-        docstring. Scopes: ``bigdl_moe_route`` (router, top-k, the sort and
-        the gather), ``bigdl_moe_experts`` (the two grouped products and the
-        gate between them), ``bigdl_moe_combine`` (the weighted sum back)."""
-        from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
-
+        docstring. Scopes: ``bigdl_moe_route`` (router, top-k, the sort, and
+        in each pass the gather and its gradient's sum by token),
+        ``bigdl_moe_experts`` (the two grouped products and the gate between
+        them), ``bigdl_moe_combine`` (the weighted sum back)."""
         tokens, k = x.shape[0], self.top_k
         first, count = self.held
+        total = tokens * k
+        rows = _held_rows(total, count, self.n_experts)
         with jax.named_scope(trace.SCOPE_MOE_ROUTE):
             logits = jnp.dot(x, params["w_gate"].astype(x.dtype),
                              preferred_element_type=jnp.float32)
@@ -303,27 +378,16 @@ class MoE(TensorModule):
             # pairs by held expert, those of experts held elsewhere last
             group = jnp.where(here, top_e - first, count).reshape(-1)
             order = jnp.argsort(group, stable=True).astype(jnp.int32)
-            rows = jnp.arange(tokens * k, dtype=jnp.int32)
-            slot = jnp.zeros_like(rows).at[order].set(
-                rows, unique_indices=True).reshape(tokens, k)
             sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :],
                             axis=0, dtype=jnp.int32)
-            # the products run over at least twice the balanced expectation of
-            # rows: the last held group is given the rows after the held pairs
-            # (pairs of experts held elsewhere, weight 0) up to that floor, so
-            # that a step's time does not follow the router's imbalance
-            total = tokens * k
-            floor = min(total, -(-2 * total * count // self.n_experts // 8) * 8)
-            padded = sizes.at[-1].add(jnp.maximum(floor - jnp.sum(sizes), 0))
-            token_of = order // k
-            xs = _dispatch(x, token_of, slot)
-        with jax.named_scope(trace.SCOPE_MOE_EXPERTS):
-            h = grouped_matmul(xs, params["w_in"].astype(x.dtype), padded)
-            half = self.hidden_size
-            act = jax.nn.silu(h[:, :half]) * h[:, half:]
-            out = grouped_matmul(act, params["w_out"].astype(x.dtype), padded)
-        with jax.named_scope(trace.SCOPE_MOE_COMBINE):
-            y = _combine(out, jnp.where(here, top_p, 0.0), token_of, slot, order)
+            # dropless by repetition: as many passes of `rows` sorted
+            # positions as the held pairs need, one unless the router sends
+            # this share more than twice its balanced expectation
+            passes = jnp.maximum(-(-jnp.sum(sizes) // rows), 1)
+            order = jnp.pad(order, (0, -total % rows))
+        y = _routed(x, params["w_in"].astype(x.dtype),
+                    params["w_out"].astype(x.dtype), top_p, order, sizes,
+                    passes, rows, self.hidden_size)
 
         new_state = dict(state)
         share = jax.lax.stop_gradient(jnp.mean(
@@ -337,7 +401,8 @@ class MoE(TensorModule):
         pairs = jnp.sum(here).astype(jnp.float32)
         new_state["aux_loss"] = jnp.zeros((), jnp.float32)
         new_state["pairs_held"] = pairs
-        # every held pair has a row: the bound on rows is every pair there is
+        new_state["row_passes"] = passes.astype(jnp.float32)
+        # every held pair has a row in one of the passes
         new_state["dropped_fraction"] = (
             pairs - jnp.sum(sizes).astype(jnp.float32)) / jnp.maximum(pairs, 1.0)
         new_state["expert_load"] = share

@@ -240,14 +240,18 @@ def _kernel_numerics(shape=(16, 8, 512, 64)) -> dict:
 def routed_kernels(length: int = 512, block: int = 4) -> None:
     """What the routed block-diffusion decoder adds, on the chip against its
     plain forms: the flash kernels under the block-diffusion mask at
-    (2, 32/4, 1024, 128), grouped-query heads at their own count, and the
-    grouped product over held groups with rows past them (``ragged_dot``)."""
+    (2, 32/4, 1024, 128), grouped-query heads at their own count, the
+    grouped product over held groups with rows past them (``ragged_dot``), and
+    the routed expert layer itself (top-4 of 16, 4 held) against the dense
+    form: at seeded routing (one pass over its bound on rows) and with every
+    pair routed to a held expert (twice the bound: the pass repeated)."""
     import jax
     import jax.numpy as jnp
 
     from bigdl_tpu.kernels.flash_attention import (
         BlockDiffusion, _reference_attention, flash_attention)
     from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
+    from bigdl_tpu.parallel.moe import MoE
 
     t0 = time.perf_counter()
     rng = np.random.default_rng(2)
@@ -284,8 +288,57 @@ def routed_kernels(length: int = 512, block: int = 4) -> None:
           f"fwd/drows/dmats errors {grouped} > {TOL_FLASH}")
     check(float(jnp.max(jnp.abs(got[0][int(sizes.sum()):]))) == 0.0,
           "rows past the held groups are not zero")
+
+    tokens, width, hidden, experts, top_k, (first, count) = 2048, 1024, 512, 16, 4, (4, 4)
+    layer = MoE(width, hidden, experts, router="topk", top_k=top_k, held=(first, count))
+    here = (np.arange(experts) >= first) & (np.arange(experts) < first + count)
+
+    def routed(p, x):
+        return layer.apply(p, layer.get_state(), x)
+
+    def dense(p, x):
+        """Every held expert over every token with its routing weight; the
+        router as the layer writes it, so that both choose the same experts."""
+        probs = jax.nn.softmax(jnp.dot(x, p["w_gate"], preferred_element_type=jnp.float32), -1)
+        top_p, top_e = jax.lax.top_k(probs, top_k)
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+        y = jnp.zeros_like(x)
+        for e in range(count):
+            weight = jnp.sum(jnp.where(top_e == first + e, top_p, 0.0), -1)
+            h = x @ p["w_in"][e]
+            y = y + weight[:, None] * ((jax.nn.silu(h[:, :hidden]) * h[:, hidden:]) @ p["w_out"][e])
+        return y
+
+    routed_layer = {}
+    for towards, passes in (("seeded", 1.0), ("held", 2.0)):
+        x = rng.normal(size=(tokens, width))
+        p = {"w_gate": 0.05 * rng.normal(size=(width, experts)),
+             "w_in": 0.03 * rng.normal(size=(count, width, 2 * hidden)),
+             "w_out": 0.03 * rng.normal(size=(count, hidden, width))}
+        if towards == "held":       # a column of ones carries a bias through the router
+            x[:, 0], p["w_gate"][0] = 1.0, np.where(here, 50.0, -50.0)
+        x, p = jnp.asarray(x, jnp.bfloat16), {k: jnp.asarray(v, jnp.bfloat16) for k, v in p.items()}
+        y, state = jax.jit(routed)(p, x)
+        grads = jax.jit(jax.grad(sq(lambda p, x: routed(p, x)[0]), argnums=(0, 1)))(p, x)
+        with jax.default_matmul_precision("highest"):
+            p32, x32 = {k: v.astype(jnp.float32) for k, v in p.items()}, x.astype(jnp.float32)
+            want = [dense(p32, x32)] + jax.tree_util.tree_leaves(
+                jax.grad(sq(dense), argnums=(0, 1))(p32, x32))
+        got = [y] + jax.tree_util.tree_leaves(grads)
+        # the column that carries the bias is no input: its gradient is the
+        # router's alone, +-50 times terms that cancel, at the chip's one
+        # bf16 pass a product (0.8 of the largest dx; the CPU reads 0.003)
+        got[-1], want[-1] = got[-1][:, 1:], want[-1][:, 1:]
+        errs = [rel_err(a, b) for a, b in zip(got, want)]
+        check(max(errs) <= TOL_FLASH, f"routed layer ({towards}) off the dense form: "
+              f"fwd/dw_gate/dw_in/dw_out/dx errors {errs} > {TOL_FLASH}")
+        check(float(state["row_passes"]) == passes and float(state["dropped_fraction"]) == 0.0,
+              f"routed layer ({towards}): {float(state['row_passes'])} passes for "
+              f"{float(state['pairs_held'])} held pairs, dropped {float(state['dropped_fraction'])}")
+        routed_layer[towards] = [float(f"{e:.3g}") for e in errs]
     say("routed-kernels", flash_block_diffusion_rel_err=[float(f"{e:.3g}") for e in flash],
         grouped_matmul_rel_err=[float(f"{e:.3g}") for e in grouped],
+        routed_layer_rel_err=routed_layer,
         seconds_compile_included=round(time.perf_counter() - t0, 1))
 
 

@@ -141,13 +141,15 @@ def test_flash_lowers_under_block_diffusion(on_tpu, shape, kv, length, dtype):
     assert "repeat" not in grad
 
 
-def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch):
-    """The grouped products of the SDAR cell's expert layer: 131,072 rows
-    (16,384 positions x 8), 16 held experts, gate and up side by side, then
-    down; forward and the gradients for rows and matrices."""
+@pytest.mark.parametrize("rows", [32768, 131072])
+def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch, rows):
+    """The grouped products of the SDAR cell's expert layer: a pass's 32,768
+    rows (twice the balanced expectation of 16,384 positions x 8 over 16 of
+    128 experts) and every pair there is, 16 held experts, gate and up side by
+    side, then down; forward and the gradients for rows and matrices."""
     from bigdl_tpu.kernels import grouped_matmul as gm
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    rows = jax.ShapeDtypeStruct((131072, 2048), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16)
     w_in = jax.ShapeDtypeStruct((16, 2048, 1536), jnp.bfloat16)
     w_out = jax.ShapeDtypeStruct((16, 768, 2048), jnp.bfloat16)
     sizes = jax.ShapeDtypeStruct((16,), jnp.int32)
@@ -157,8 +159,30 @@ def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch):
         return gm.grouped_matmul(jax.nn.silu(h[:, :768]) * h[:, 768:], b, n) \
             .astype(jnp.float32).sum()
 
-    text = _tpu_module(jax.grad(experts, argnums=(0, 1, 2)), rows, w_in, w_out, sizes)
+    text = _tpu_module(jax.grad(experts, argnums=(0, 1, 2)), x, w_in, w_out, sizes)
     assert text.count("tpu_custom_call") >= 5       # the first forward, 2 + 2 backward
+
+
+def test_routed_layer_lowers_at_the_sdar_shapes(monkeypatch):
+    """The whole routed layer as the SDAR cell runs it (16,384 positions, top-8
+    of 128, experts 48-63 held): the bounded pass and the loop that repeats it,
+    forward and the hand-written VJP. The kernels see a pass's 32,768 rows."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    from bigdl_tpu.parallel.moe import MoE
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    layer = MoE(2048, 768, 128, router="topk", top_k=8, held=(48, 16))
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16)
+              for k, v in layer.get_params().items()}
+    x = jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16)
+
+    def loss(p, x):
+        return layer.apply(p, layer.get_state(), x)[0].astype(jnp.float32).sum()
+
+    for fn, calls in ((loss, 2), (jax.grad(loss, argnums=(0, 1)), 6)):
+        text = _tpu_module(fn, params, x)
+        assert text.count("tpu_custom_call") >= calls
+        assert "stablehlo.while" in text            # the passes after the first
+        assert "32768x2048xbf16" in text and "131072x2048" not in text
 
 
 # ----------------------------------------------------------- layer norm

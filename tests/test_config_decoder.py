@@ -64,12 +64,53 @@ def test_loss_and_gradients_match_the_reference(bench, cell):
         want, want_grads = ref.make_loss_and_grad(cfg)(weights, jnp.asarray(x), jnp.asarray(y))
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
     assert float(state["dropped_fraction"]) == 0.0 and float(state["pairs_held"]) > 0
+    assert float(state["row_passes"]) >= 1.0
     grads = bench.names_from_tree(grads, names)
     assert set(grads) == set(want_grads)
     for k in want_grads:
         scale = float(jnp.linalg.norm(want_grads[k]))
         assert scale > 0, k
         assert float(jnp.linalg.norm(grads[k] - want_grads[k])) <= 1e-4 * scale, k
+
+
+@pytest.mark.parametrize("towards_held", [False, True])
+def test_rematerialised_scan_differentiates_through_the_routed_vjp(bench, cell, towards_held):
+    """The scanned decoder with every layer recomputed against the one that
+    keeps its layers' values: loss and gradients, with the held pairs inside
+    one pass of the expert layer and with every pair held (the pass repeated
+    inside the hand-written VJP)."""
+    cfg, mod, _ = cell
+    names = mod.names(cfg)
+    weights = _weights(bench, cfg, mod)
+    x, y = mod.make_batches(cfg, TRAFFIC, np.random.default_rng(6))[0]
+    got = {}
+    for remat in (True, False):
+        model, criterion = mod.build(cfg, TRAFFIC)
+        model.remat = remat
+        params = bench.tree_from_names(model.get_params(), names, weights)
+        if towards_held:
+            # one large channel in every embedding survives the norms as a
+            # constant, and the routers' first row turns it into a bias
+            first, count = cfg["held"]
+            experts = jnp.arange(cfg["router_experts"])
+            bias = jnp.where((experts >= first) & (experts < first + count), 6.0, -6.0)
+            params["embed"] = params["embed"].at[:, 0].set(50.0)
+            params["layers"]["moe"]["w_gate"] = \
+                params["layers"]["moe"]["w_gate"].at[:, 0, :].set(bias)
+
+        def loss(p):
+            out, state = model.apply(p, model.get_state(), jnp.asarray(x), training=True)
+            return criterion.apply(out, jnp.asarray(y)), state
+
+        with jax.default_matmul_precision("highest"):
+            got[remat] = jax.value_and_grad(loss, has_aux=True)(params)
+    (loss_r, state_r), grads_r = got[True]
+    (loss_k, state_k), grads_k = got[False]
+    assert float(state_r["row_passes"]) == float(state_k["row_passes"]) == (2.0 if towards_held else 1.0)
+    assert float(state_r["dropped_fraction"]) == 0.0
+    np.testing.assert_allclose(float(loss_r), float(loss_k), rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads_r), jax.tree_util.tree_leaves(grads_k)):
+        np.testing.assert_allclose(a, b, atol=1e-6 + 1e-5 * float(jnp.max(jnp.abs(b))))
 
 
 def test_names_follow_the_programs_tree(bench, cell):
@@ -142,3 +183,6 @@ def test_trains_through_local_optimizer(bench, cell):
     assert any(tag.endswith("dropped_fraction") for tag in losses)
     assert all(v == 0.0 for tag, vs in losses.items()
                if tag.endswith("dropped_fraction") for v in vs)
+    # the passes the expert layer ran ride the same batched fetch
+    assert len(losses["State/row_passes"]) == len(losses["Loss"])
+    assert all(v >= 1.0 for v in losses["State/row_passes"])

@@ -73,17 +73,25 @@ def test_eight_shares_add_up_to_the_uncut_layer(layer, norm):
     assert pairs == T * K
 
 
+def _towards_held(params, x, first, count, tokens=None, sign=1.0):
+    """A router biased so that every pair of the first ``tokens`` tokens (all
+    of them if None) is for a held expert and none of the others' is (the
+    other way round with ``sign=-1``): a column of ones carries the bias
+    through the router's matrix."""
+    held = (jnp.arange(E) >= first) & (jnp.arange(E) < first + count)
+    ones = jnp.ones((T,)) if tokens is None else jnp.where(jnp.arange(T) < tokens, 1.0, -1.0)
+    return (dict(params, w_gate=params["w_gate"].at[0].set(
+        sign * jnp.where(held, 50.0, -50.0))), x.at[:, 0].set(ones))
+
+
 @pytest.mark.parametrize("towards,pairs", [("held", T * K), ("elsewhere", 0)])
 def test_no_pair_is_dropped_under_imbalance(layer, towards, pairs):
     """A router biased so that every token's k experts are held here (every
     pair has a row: the static bound is tokens x k), and so that none is."""
     _, params, x = layer
     first, count = 4, 4
-    bias = jnp.where((jnp.arange(E) >= first) & (jnp.arange(E) < first + count),
-                     50.0, -50.0) * (1 if towards == "held" else -1)
-    # a column of ones carries the bias through the router's matrix
-    x = x.at[:, 0].set(1.0)
-    params = dict(params, w_gate=params["w_gate"].at[0].set(bias))
+    params, x = _towards_held(params, x, first, count,
+                              sign=1.0 if towards == "held" else -1.0)
     m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
     with jax.default_matmul_precision("highest"):
         y, state = m.apply(_share(params, first, count), m.get_state(), x)
@@ -94,23 +102,74 @@ def test_no_pair_is_dropped_under_imbalance(layer, towards, pairs):
         assert float(jnp.max(jnp.abs(y))) == 0.0
 
 
-def test_gradients_match_the_uncut_layer(layer):
+# held=(4, 4) of 16 at top-4 of 96 tokens: 384 pairs, a pass holds 192 rows
+@pytest.mark.parametrize("bias,pairs,passes", [
+    (None, None, 1),            # seeded: about 96 held pairs
+    ("held", T * K, 2),         # every pair held: twice a pass's rows
+    (61, 61 * K, 2),            # 244 pairs: not a multiple of a pass's rows
+])
+def test_gradients_match_the_uncut_layer(layer, bias, pairs, passes):
+    """Parameters' and input's gradients against the dense form, with the held
+    pairs inside one pass and under overflow (the pass repeated)."""
     _, params, x = layer
     first, count = 4, 4
+    if bias is not None:
+        params, x = _towards_held(params, x, first, count, None if bias == "held" else bias)
     m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
     probe = jnp.cos(jnp.arange(D))
 
     def routed(p, x):
-        return jnp.sum(m.apply(_share(p, first, count), m.get_state(), x)[0] * probe)
+        y, state = m.apply(_share(p, first, count), m.get_state(), x)
+        return jnp.sum(y * probe), state
 
     def dense(p, x):
         return jnp.sum(_uncut(x, p, K, first, count) * probe)
 
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(routed, (0, 1))(params, x)
+        got, state = jax.grad(routed, (0, 1), has_aux=True)(params, x)
         want = jax.grad(dense, (0, 1))(params, x)
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, atol=5e-6)
+    assert float(state["row_passes"]) == passes
+    assert float(state["dropped_fraction"]) == 0.0
+    if pairs is not None:
+        assert float(state["pairs_held"]) == pairs
+
+
+def test_one_pass_when_all_experts_are_held(layer):
+    """A pass's rows are every pair then, whatever the routing."""
+    full, params, x = layer
+    params, x = _towards_held(params, x, 4, 4)
+    _, state = full.apply(params, full.get_state(), x)
+    assert float(state["row_passes"]) == 1.0
+    assert float(state["dropped_fraction"]) == 0.0
+
+
+def _avals(jaxpr):
+    """Every value of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+@pytest.mark.parametrize("grad", [False, True])
+def test_no_tensor_of_every_pair_by_a_feature(layer, grad):
+    """With fewer experts held than there are, nothing between the sort and
+    the sum by token has tokens x top_k rows of a feature dimension (D, H or
+    2H): what is that long belongs to the routing (the pairs' order, their
+    groups, the experts' loads)."""
+    _, params, x = layer
+    first, count = 4, 4
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
+    fn = lambda p, x: m.apply(p, m.get_state(), x)[0]
+    if grad:
+        fn = jax.grad(lambda p, x, fn=fn: jnp.sum(fn(p, x)), (0, 1))
+    jaxpr = jax.make_jaxpr(fn)(_share(params, first, count), x).jaxpr
+    shapes = {tuple(a.shape) for a in _avals(jaxpr) if hasattr(a, "shape")}
+    assert (T * K // 2, D) in shapes                # a pass's rows are there
+    wide = {s for s in shapes if T * K in s or s[:2] == (T, K)}
+    assert wide and not any({D, HID, 2 * HID} & set(s) for s in wide), wide
 
 
 @pytest.mark.parametrize("sizes", [[40, 0, 100], [0, 0, 0], [128, 64, 64]])

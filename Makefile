@@ -1,6 +1,6 @@
 # Developer entry points (reference build-system analog, SURVEY.md §2.5 L8).
 SHELL := /bin/bash
-.PHONY: test t1 t1-faults t1-obs t1-cluster-obs t1-kernels t1-serving t1-serving-faults t1-streaming t1-fleet t1-recsys t1-elastic t1-promotion t1-paged dist bench bench-smoke bench-pipeline multichip clean
+.PHONY: test t1 t1-faults t1-obs t1-cluster-obs t1-kernels t1-serving t1-serving-faults t1-streaming t1-fleet t1-recsys t1-elastic t1-promotion t1-paged dist multichip clean
 
 test:
 	python -m pytest tests/ -x -q
@@ -40,8 +40,7 @@ t1-cluster-obs:
 
 # Kernel-equivalence suite only (docs/performance.md "Kernel fusion & memory"):
 # fused conv-bn(-relu) vs unfused fp32 bitwise, flat-param SGD/Adam updates vs
-# per-leaf, grad-accum M∈{1,2,4} vs M=1 on LeNet, remat policies, bench-probe
-# retry hardening. Unmarked-slow, so `make t1` runs these too; this target is
+# per-leaf, grad-accum M∈{1,2,4} vs M=1 on LeNet, remat policies. Unmarked-slow, so `make t1` runs these too; this target is
 # the fast inner loop for kernel work.
 t1-kernels:
 	set -o pipefail; timeout -k 10 600 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m kernels --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly
@@ -124,33 +123,6 @@ t1-paged:
 
 dist:
 	bash make-dist.sh
-
-bench:
-	python bench.py
-
-# CPU smoke of the bench's training + eval legs: catches loop-overhead
-# regressions (loop_step_ratio, fused vs per-step legs), eval-path
-# regressions (eval fused speedup, val_fetch_bytes_per_image), and kernel
-# regressions (conv-bn folding, flat updates, grad-accum/remat memory proxy)
-# without a TPU.
-bench-smoke:
-	JAX_PLATFORMS=cpu python bench.py --model lenet --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --model lenet --eval-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --model lenet --obs-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --kernel-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --serving-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --fleet-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --stream-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --recsys-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --ckpt-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --promotion-bench --no-compare-dtypes --no-streamed
-	JAX_PLATFORMS=cpu python bench.py --paging-bench --no-compare-dtypes --no-streamed
-
-# Host input-pipeline leg (decode→augment→stack on a synthetic image folder):
-# pipeline_images_per_sec at BIGDL_DATA_WORKERS 0/1/4/auto + per-stage ms.
-# Host-only — needs no accelerator.
-bench-pipeline:
-	JAX_PLATFORMS=cpu python bench.py --pipeline-bench --no-compare-dtypes --no-streamed
 
 multichip:
 	python -m bigdl_tpu.cli dryrun-multichip -n 8

@@ -4,7 +4,7 @@
 The reference launches training through ``spark-submit`` with env setup done by
 ``bigdl.sh`` and per-app scopt CLIs. TPU-native there is no cluster submitter:
 one console script fans out to the model training mains (each keeping its
-reference-style argparse options), the benchmark, and the multi-chip dry run.
+reference-style argparse options) and the multi-chip dry run.
 Environment flags (the ``bigdl.*`` property tier) are plain ``BIGDL_*`` env
 vars — see ``conf/bigdl-tpu.conf`` for the reference list.
 """
@@ -404,18 +404,15 @@ def main(argv=None) -> int:
         # artifact paths are known before any training output scrolls by
         from bigdl_tpu.obs import describe_config
         print(describe_config(), file=sys.stderr)
-    # bench forwards option-style args; argparse REMAINDER cannot capture a
-    # leading option (py3.12), so hand the tail to the benchmark CLI directly
-    if argv[:1] == ["bench"]:
-        from bigdl_tpu import benchmark
-        return benchmark.main(argv[1:])
+    # converge forwards option-style args; argparse REMAINDER cannot capture
+    # a leading option (py3.12), so hand the tail to its CLI directly
     if argv[:1] == ["converge"]:
         from bigdl_tpu import convergence
         return convergence.main(argv[1:])
     p = argparse.ArgumentParser(
         prog="bigdl-tpu",
-        description="TPU-native BigDL: train models, benchmark, validate "
-                    "multi-chip sharding")
+        description="TPU-native BigDL: train models, validate multi-chip "
+                    "sharding")
     sub = p.add_subparsers(dest="command")
 
     train = sub.add_parser("train", help="run a model training main")
@@ -423,8 +420,6 @@ def main(argv=None) -> int:
     train.add_argument("rest", nargs=argparse.REMAINDER,
                        help="arguments forwarded to the model's own CLI")
 
-    sub.add_parser("bench", help="single-chip ResNet-50 benchmark "
-                                  "(all bench.py options forwarded)")
     sub.add_parser("converge", help="accuracy-parity harness: train a "
                                     "BASELINE config on real data and judge "
                                     "the final metric against its target")

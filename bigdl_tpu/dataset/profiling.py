@@ -4,16 +4,16 @@ The training loop's ``feed_wait_ms`` says how long the step loop *waited* on
 data, but not where a slow feed actually spends its time. This module is the
 attribution layer: the pipeline stages (decode in the dataset sources, augment
 in the parallel transform workers, stack in ``SampleToMiniBatch``) report their
-wall time here, and the consumers (``Optimizer`` training summaries, the
-``--pipeline-bench`` leg) read snapshot deltas — so a regression in any single
+wall time here, and the consumer (``Optimizer``'s training summaries and
+end-of-run log line) reads snapshot deltas — so a regression in any single
 stage is visible instead of smearing into one opaque wait number.
 
 Kept dependency-free (no ``optim`` import): the dataset layer must not import
 the optimizer. Timings are wall-clock sums per stage occurrence; decode/augment
 count per IMAGE, stack per BATCH, h2d lives in the optimizer's own metrics
 (``put_batch``) and is merged by the consumer. Every add also publishes into
-the obs metric registry as ``feed/<stage>`` so the unified run report and
-bench legs read one source.
+the obs metric registry as ``feed/<stage>`` so the unified run report reads
+the same source.
 """
 
 from __future__ import annotations

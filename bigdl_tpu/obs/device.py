@@ -317,7 +317,7 @@ def start_from_env(interval_s: Optional[float] = None) -> Optional[DeviceMonitor
 
 
 def stats() -> dict:
-    """Device-memory block for /statusz and bench records."""
+    """Device-memory block for /statusz."""
     return {"devices": last_sample() or [],
             "live_buffers": live_buffer_census(publish=False)}
 

@@ -10,10 +10,11 @@ live gauges into the metric registry:
 - ``<domain>/model_flops_per_sec`` — achieved model FLOPs per second
 - ``<domain>/mfu``                 — the same divided by the backend's peak
 
-so every run — not just bench legs — carries the MFU number, and the
-``/metrics`` endpoint exposes it to scrapers. The peak-FLOPs table below is
-the single source for ``bench.py`` too; ``BIGDL_PEAK_FLOPS`` overrides it
-(e.g. on backends the table does not know).
+so every run carries the MFU number, and the ``/metrics`` endpoint exposes it
+to scrapers. The peak-FLOPs table below is the gauge's; ``BIGDL_PEAK_FLOPS``
+overrides it (e.g. on backends the table does not know). The benchmark keeps
+its own peaks in ``benchmarks/peaks.json``; ``tests/test_architecture.py``
+holds the two equal for every device the benchmark names.
 
 Lowering for cost analysis uses ``jax.ShapeDtypeStruct`` avals built from
 the call's argument trees — never live buffers — so it composes with
@@ -32,7 +33,7 @@ from typing import Optional
 
 #: peak dense (non-sparse) FLOPs/s per chip, matched by substring against
 #: ``jax.devices()[0].device_kind.lower()``. Order matters: first match wins
-#: ("v5 lite" before "v5"). bench.py re-exports this table.
+#: ("v5 lite" before "v5").
 PEAK_FLOPS = [
     ("v6", 918e12),
     ("v5p", 459e12),
@@ -54,13 +55,6 @@ _UNSET = object()
 _peak_cache = _UNSET       # cached table lookup for this process's backend
 
 
-def table_lookup(table, device_kind: Optional[str]) -> Optional[float]:
-    """First entry of a ``(substring, value)`` table whose substring occurs in
-    ``device_kind`` (case-insensitive), or None."""
-    kind = (device_kind or "").lower()
-    return next((v for sub, v in table if sub in kind), None) if kind else None
-
-
 def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
     """Peak FLOPs/s for a device kind string, or None when unknown.
 
@@ -75,7 +69,8 @@ def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
                 return v
         except ValueError:
             pass
-    return table_lookup(PEAK_FLOPS, device_kind)
+    kind = (device_kind or "").lower()
+    return next((v for sub, v in PEAK_FLOPS if sub in kind), None)
 
 
 def device_peak() -> Optional[float]:
@@ -152,7 +147,7 @@ def note(domain: str, flops: Optional[float], wall_s: float) -> None:
 
 
 def stats() -> dict:
-    """Current MFU accounting state for ``/statusz`` and bench records."""
+    """Current MFU accounting state for ``/statusz``."""
     with _lock:
         fps = dict(_ewma)
     peak = device_peak()

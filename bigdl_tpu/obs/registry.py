@@ -2,8 +2,8 @@
 
 The legacy rails (``optim/metrics.Metrics``, ``dataset/profiling.feed_stats``,
 ``utils/robustness.events``) keep their public APIs but publish through this
-registry, so the end-of-run report, the ``TrainSummary`` curves, and the bench
-legs all read ONE accumulator instead of merging three bespoke snapshots.
+registry, so the end-of-run report, the ``TrainSummary`` curves and the live
+endpoints all read ONE accumulator instead of merging three bespoke snapshots.
 
 Naming conventions in use:
 

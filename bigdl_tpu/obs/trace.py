@@ -98,7 +98,7 @@ def _truthy(raw: Optional[str]) -> bool:
 
 def configure(enabled: Optional[bool] = None, trace_dir: Optional[str] = None,
               jsonl: Optional[str] = None) -> None:
-    """Explicit configuration (tests / bench legs). Overrides the environment
+    """Explicit configuration (tests, the benchmark's runner). Overrides the environment
     until :func:`reset`."""
     global _ENABLED, _EXPLICIT, _TRACE_DIR, _JSONL_PATH
     with _lock:

@@ -2309,7 +2309,7 @@ class Optimizer:
 
         ``ckpt/stall_ms`` records how long the TRAINING thread was blocked
         here — snapshot-only when async (``BIGDL_CKPT_ASYNC``, default on),
-        snapshot+write+fsync when sync — the --ckpt-bench comparison."""
+        snapshot+write+fsync when sync."""
         os.makedirs(self.checkpoint_path, exist_ok=True)
         t0 = time.perf_counter()
         try:

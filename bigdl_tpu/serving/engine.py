@@ -17,7 +17,7 @@ programs:
 - **Static-shape buckets**: prompts prefill right-padded to a small
   length grid, so the engine compiles exactly ``len(buckets)`` prefill
   programs + 1 decode program + 1 slot-assign program — ever. ``stats()``
-  counts them; the bench asserts the bound.
+  counts them; the tests and ``chip_smoke.py`` assert the bound.
 - **SLO knob** (``admit_wait_ms``): on an idle engine, wait this long for
   more arrivals before the first prefill — trades batch fill (throughput)
   against TTFT. 0 (default) = serve immediately.
@@ -65,7 +65,7 @@ under test, and each recovery action is a ``Robustness/serving_*`` event.
 Per-request latency lands in the obs metric registry (``serving/ttft_ms``,
 ``serving/tpot_ms``, ``serving/queue_wait_ms``, ``serving/e2e_ms``
 histograms): p50/p99 TTFT and time-per-token are one ``registry.snapshot()``
-away, the same rail the run report and bench legs read. Decode is greedy —
+away, the same rail the run report reads. Decode is greedy —
 the bitwise-equality contract with ``nn.greedy_generate`` is pinned by
 ``tests/test_serving.py``.
 

@@ -21,7 +21,7 @@ engine is an admission queue plus ONE static-shape program:
   cross d2h — ``O(max_batch * max_candidates)`` floats per tick.
 - **Observability**: ``ranking/requests``, ``ranking/batch_fill``,
   ``ranking/latency_ms`` land in the obs metric registry — the same rail the
-  bench's ``--recsys-bench`` leg and the run report read.
+  run report reads.
 
 A sharded snapshot (``NeuralCF(..., sharded=True)``) serves through this
 engine unchanged: the forward is bitwise-equal to the replicated table, and

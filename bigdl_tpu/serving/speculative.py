@@ -120,7 +120,7 @@ def build_spec_step(model, draft, k: int):
 
 class SpeculativeDecoder:
     """Standalone (offline) speculative greedy decode over a batch of
-    same-length prompts — the engine-free form for tests and the bench.
+    same-length prompts — the engine-free form (tests use it).
 
     ``model`` is the served target, ``draft`` the proposer (any
     cached-decode-capable causal LM over the same vocabulary; a smaller/
@@ -134,7 +134,7 @@ class SpeculativeDecoder:
         import jax.numpy as jnp
 
         if draft is model:
-            pass   # allowed: pins acceptance at ~100% (tests, bench)
+            pass   # allowed: pins acceptance at ~100% (tests)
         if spec_tokens is None:
             spec_tokens = _env_spec_tokens()
         if spec_tokens < 1:

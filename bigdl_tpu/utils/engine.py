@@ -32,7 +32,7 @@ def place_compile_cache() -> str:
     directory is set here. Otherwise the cache goes to ``.jax_cache`` beside the
     package — a fixed path, because the path is part of what a later process
     must reproduce to hit. Called once, from ``import bigdl_tpu``, so every
-    entry point (trainer, serving engine, bench, CLI) passes it before its
+    entry point (trainer, serving engine, CLI) passes it before its
     first compile."""
     import jax
 

@@ -3,7 +3,8 @@
 Drives the main path once through the entry points a user calls, at the full
 width of the models the repo benchmarks, with seeded random weights:
 
-  attach        Engine.init(); every device must be a TPU the peak tables know
+  attach        Engine.init(); every device must be a TPU that
+                benchmarks/peaks.json knows
   train-vision  ResNet-50 (ImageNet shape, bf16 compute / fp32 masters, b256,
                 NHWC + space-to-depth stem) through LocalOptimizer.optimize():
                 the per-step program and fused windows
@@ -49,6 +50,9 @@ TOL_LN_F32 = 1e-5
 # the chip, closer than this (nats)
 TOL_TIE = 5e-2
 
+PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                     "benchmarks", "peaks.json")
+
 CACHE_EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "requests",
                 "/jax/compilation_cache/cache_hits": "hits",
                 "/jax/compilation_cache/cache_misses": "writes"}
@@ -76,8 +80,7 @@ def attach():
     import jax
     import jaxlib
 
-    from bigdl_tpu import Engine, benchmark, native
-    from bigdl_tpu.obs import mfu
+    from bigdl_tpu import Engine, native
 
     Engine.init()
     devices = Engine.devices()
@@ -86,33 +89,46 @@ def attach():
         print(f"chip_smoke: no TPU — Engine.devices() are on {platforms}; "
               f"this script only runs on the chip", file=sys.stderr)
         raise SystemExit(1)
-    check(not os.environ.get("BIGDL_PEAK_FLOPS"),
-          "BIGDL_PEAK_FLOPS is set: the smoke reads the peak tables only")
     kind = devices[0].device_kind
-    peak_flops = mfu.peak_flops_for(kind)
-    check(peak_flops is not None, f"obs.mfu has no peak FLOP/s for {kind!r}")
-    peak_hbm = benchmark._peak_hbm(kind)   # raises on an unknown device
+    with open(PEAKS) as f:
+        peaks = json.load(f)["devices"]
+    check(kind in peaks, f"benchmarks/peaks.json has no entry for {kind!r}; "
+          f"add it there with its source")
     say("attach", platform="tpu", device_kind=kind, count=len(devices),
         jax=jax.__version__, jaxlib=jaxlib.__version__,
         libtpu=version("libtpu"),
         compile_cache_dir=jax.config.jax_compilation_cache_dir,
         cache_dir_from_env=bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
-        peak_flops=peak_flops, peak_hbm_bytes_per_s=peak_hbm,
+        peak_flops=peaks[kind]["flops_per_s"],
+        peak_hbm_bytes_per_s=peaks[kind]["hbm_bytes_per_s"],
         native_available=native.native_available())
     return devices
 
 
 # ---------------------------------------------------------- train-vision
-def _fold_labels(dataset, n_classes: int):
-    """The bench's batches with labels folded onto ``n_classes``: random
-    pixels teach nothing, but a marginal over a few classes is learned in a
-    handful of steps, so 'the loss fell' is a property of the trainer and
-    not of luck."""
+def build_resnet50(batch: int, n_batches: int):
+    """ResNet-50 as the benchmark's ResNet cell trains it: NHWC, the
+    space-to-depth stem, uint8 pixels normalised on the device. Labels are
+    folded onto 8 classes: random pixels teach nothing, but a marginal over a
+    few classes is learned in a handful of steps, so 'the loss fell' is a
+    property of the trainer and not of luck."""
+    from bigdl_tpu import nn
     from bigdl_tpu.dataset.dataset import DataSet
     from bigdl_tpu.dataset.sample import MiniBatch
+    from bigdl_tpu.models.resnet import ResNet
+    from bigdl_tpu.nn import layout
 
-    return DataSet.array([MiniBatch(b.input, b.target % n_classes)
-                          for b in dataset.data(train=False)])
+    layout.set_image_format("NHWC")
+    model = ResNet(1000, {"depth": 50, "dataSet": "ImageNet",
+                          "conv1SpaceToDepth": True})
+    model = nn.Sequential().add(nn.ImageNormalize()).add(model)
+    rng = np.random.default_rng(0)
+    batches = []
+    for _ in range(n_batches):
+        x = rng.integers(0, 256, size=(batch, 224, 224, 3)).astype(np.uint8)
+        y = rng.integers(0, 1000, size=(batch,)).astype(np.int32)
+        batches.append(MiniBatch(x, y % 8))
+    return model, DataSet.array(batches), nn.ClassNLLCriterion()
 
 
 def _leaf_delta(before, after) -> float:
@@ -123,19 +139,17 @@ def _leaf_delta(before, after) -> float:
                         jax.tree_util.tree_leaves(after))))
 
 
-def train_vision(model_name: str = "resnet50", batch: int = 256):
+def train_vision(batch: int = 256):
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu import Engine, benchmark
+    from bigdl_tpu import Engine
     from bigdl_tpu.optim import SGD, LocalOptimizer, Trigger
 
     t0 = time.perf_counter()
     Engine.set_compute_dtype(jnp.bfloat16)
-    fuse = benchmark._bench_fuse_steps()
-    model, dataset, criterion = benchmark._build(model_name, batch,
-                                                 n_batches=fuse, dtype="bf16")
-    dataset = _fold_labels(dataset, 8)
+    fuse = 8    # a window is the whole dataset, whatever BIGDL_FUSE_STEPS says
+    model, dataset, criterion = build_resnet50(batch, n_batches=fuse)
     before = jax.tree_util.tree_map(np.asarray, model.get_params())
     opt = (LocalOptimizer(model, dataset, criterion)
            .set_optim_method(SGD(learningrate=0.1, momentum=0.9,
@@ -155,7 +169,7 @@ def train_vision(model_name: str = "resnet50", batch: int = 256):
     delta = _leaf_delta(before, model.get_params())
     check(delta > 0, "parameters did not change")
     dev = Engine.devices()[0]
-    say("train-vision", platform=dev.platform, model=model_name, batch=batch,
+    say("train-vision", platform=dev.platform, model="resnet50", batch=batch,
         compute_dtype="bfloat16", fuse_steps=fuse, iterations=1 + 2 * fuse,
         loss_first=round(loss0, 4), loss_last=round(loss1, 4),
         param_abs_delta=round(delta, 3),
@@ -342,21 +356,23 @@ def routed_kernels(length: int = 512, block: int = 4) -> None:
         seconds_compile_included=round(time.perf_counter() - t0, 1))
 
 
-def train_lm(batch: int = 16, steps: int = 8, require_kernels: bool = True):
+def train_lm(batch: int = 16, seq: int = 512, steps: int = 8,
+             require_kernels: bool = True):
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu import Engine, benchmark
+    from bigdl_tpu import Engine
     from bigdl_tpu.dataset.dataset import DataSet
     from bigdl_tpu.dataset.sample import MiniBatch
+    from bigdl_tpu.models.transformerlm import TransformerLM, lm_criterion
     from bigdl_tpu.obs.mfu import avals_of
     from bigdl_tpu.optim import Adam, LocalOptimizer, Trigger
 
     t0 = time.perf_counter()
     Engine.set_compute_dtype(jnp.bfloat16)
-    model, dataset, criterion = benchmark._build("transformerlm", batch,
-                                                 n_batches=1, dtype="bf16")
-    seq = next(iter(dataset.data(train=False))).input.shape[1]
+    model = TransformerLM(32000, embed_dim=512, num_heads=8, num_layers=6,
+                          max_len=seq)
+    criterion = lm_criterion()
     # a map a model can learn: 64 tokens in use, target a function of input
     rng = np.random.default_rng(1)
     xs = [rng.integers(0, 64, size=(batch, seq)).astype(np.int32)
@@ -474,12 +490,11 @@ def serve(vocab: int = 32000, embed_dim: int = 512, num_heads: int = 8,
 
 
 # ----------------------------------------------------------- all devices
-def all_devices(model_name: str = "resnet50", batch: int = 256,
-                steps: int = 3):
+def all_devices(batch: int = 256, steps: int = 3):
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu import Engine, benchmark
+    from bigdl_tpu import Engine
     from bigdl_tpu.optim import SGD, DistriOptimizer, Trigger
 
     mesh = Engine.mesh()
@@ -488,9 +503,7 @@ def all_devices(model_name: str = "resnet50", batch: int = 256,
     Engine.set_compute_dtype(jnp.bfloat16)
     for sync in ("allreduce", "zero1"):
         t0 = time.perf_counter()
-        model, dataset, criterion = benchmark._build(model_name, batch,
-                                                     n_batches=2, dtype="bf16")
-        dataset = _fold_labels(dataset, 8)
+        model, dataset, criterion = build_resnet50(batch, n_batches=2)
         opt = (DistriOptimizer(model, dataset, criterion, parameter_sync=sync)
                .set_optim_method(SGD(learningrate=0.1, momentum=0.9,
                                      dampening=0.0))

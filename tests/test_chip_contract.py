@@ -3,6 +3,8 @@ refuses to run, ``Engine.init`` names the CPU it landed on, the multichip dry
 run refuses a mesh it was not given, and ``launch`` refuses to let N processes
 fight over one host's chips."""
 
+import ast
+import importlib
 import logging
 import os
 import shutil
@@ -36,10 +38,32 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
 
 
 def test_chip_smoke_is_one_process_and_never_imports_tests():
+    """... and stands on what the package has: every ``bigdl_tpu`` module it
+    imports exists, with the names it asks for. Its imports sit inside its
+    stages and nothing runs them off the chip, so a name that went away would
+    otherwise be found by the next chip run."""
     text = open(os.path.join(ROOT, "chip_smoke.py")).read()
     assert "subprocess" not in text and "multiprocessing" not in text
     assert "import tests" not in text and "from tests" not in text
     assert "except Exception" not in text and "except:" not in text
+    asked = 0
+    for node in ast.walk(ast.parse(text)):
+        if isinstance(node, ast.Import):
+            modules = [(a.name, []) for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, "chip_smoke.py is a script, not a package"
+            modules = [(node.module, [a.name for a in node.names])]
+        else:
+            continue
+        for module, names in modules:
+            if module.split(".")[0] != "bigdl_tpu":
+                continue
+            found = importlib.import_module(module)
+            for name in names:
+                asked += 1
+                if not hasattr(found, name):        # a sub-module not yet imported
+                    importlib.import_module(f"{module}.{name}")
+    assert asked > 20       # the walk saw the imports inside the stages
 
 
 def test_engine_warns_when_it_lands_on_cpu_unasked(monkeypatch, caplog):

@@ -271,12 +271,6 @@ class TestDeviceMemory:
         assert isinstance(st["devices"], list)
         mon.stop()
 
-    def test_bench_device_memory_record(self):
-        from bigdl_tpu import benchmark
-        rec = benchmark._device_memory_record()
-        assert set(rec) >= {"devices", "hbm_bytes_in_use", "hbm_peak_bytes"}
-        assert isinstance(rec["devices"], list)
-
 
 # --------------------------------------------------------- profiler capture
 class TestProfilez:

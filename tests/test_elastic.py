@@ -312,7 +312,7 @@ class TestElasticOptimizer:
             [1, 2, 3]
 
     def test_sync_mode_blocks_training_thread(self, tmp_path, monkeypatch):
-        """BIGDL_CKPT_ASYNC=0 (the --ckpt-bench sync leg): the training
+        """BIGDL_CKPT_ASYNC=0 (the synchronous write): the training
         thread eats the whole write, stall ≥ the injected writer stall."""
         monkeypatch.setenv("BIGDL_FAULT_STALL_S", "0.5")
         monkeypatch.setenv("BIGDL_CKPT_ASYNC", "0")

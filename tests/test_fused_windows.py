@@ -253,18 +253,3 @@ class TestWindowedFeed:
             feed.close()
         assert any("leaked producer thread" in r.getMessage()
                    for r in caplog.records)
-
-
-class TestBenchProbe:
-    def test_probe_healthy_cpu(self):
-        from bigdl_tpu.benchmark import _probe_backend
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        assert _probe_backend(env, timeout=120) is None
-
-    def test_probe_reports_broken_backend(self):
-        from bigdl_tpu.benchmark import _probe_backend
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "no_such_platform"
-        reason = _probe_backend(env, timeout=120)
-        assert reason is not None and "probe" in reason

@@ -1,8 +1,8 @@
 """Kernel-equivalence suite (make t1-kernels): fused conv-bn(-relu) vs the
 unfused stack (fp32 bitwise on the train/eval paths, tolerance on the folded
 inference kernel), flat-param SGD/Adam updates vs the per-leaf reference
-(jitted bitwise), grad-accum M∈{1,2,4} vs M=1 on the LeNet CPU smoke, the
-remat policies, and the bench probe's retry/backoff hardening."""
+(jitted bitwise), grad-accum M∈{1,2,4} vs M=1 on the LeNet CPU smoke, and the
+remat policies."""
 
 import os
 
@@ -372,69 +372,3 @@ def test_convbn_fuse_env_knob_end_to_end():
     assert fused.state["loss"] == ref.state["loss"]
     assert any(isinstance(m, FusedConvBNReLU)
                for m in fused.model.modules)
-
-
-# ------------------------------------------------------- probe hardening
-def test_probe_backend_retries_with_backoff(monkeypatch):
-    from bigdl_tpu import benchmark
-    sleeps = []
-    monkeypatch.setattr(benchmark.sys, "executable", "/bin/false")
-    err = benchmark._probe_backend({}, timeout=5, retries=3, backoff=2.0,
-                                   sleep=sleeps.append)
-    assert err is not None and "after 3 attempts" in err
-    assert sleeps == [2.0, 4.0]  # exponential backoff between attempts
-
-
-def test_probe_backend_success_no_retries():
-    from bigdl_tpu import benchmark
-    sleeps = []
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    err = benchmark._probe_backend(env, timeout=120, retries=2,
-                                   sleep=sleeps.append)
-    assert err is None and sleeps == []
-
-
-def test_failed_probe_is_reported_and_nothing_stands_in(capsys):
-    """A backend that does not answer: the orchestrator says so, exits
-    non-zero and measures nothing — no CPU LeNet line in its place."""
-    import argparse
-    import json as _json
-
-    from bigdl_tpu import benchmark
-    args = argparse.Namespace(
-        model="lenet", batch=8, iters=2, warmup=1, dtype="bf16",
-        compare_dtypes=False, streamed=False, timeout=5, int8_infer=False,
-        serving=False, decode_infer=False, ablate=False, eval_bench=False,
-        pipeline_bench=False, obs_bench=False, kernel_bench=True,
-        precision_bench=False)
-    env = {"JAX_PLATFORMS": "tpu",
-           "BIGDL_BENCH_PROBE_TIMEOUT": "1",
-           "BIGDL_BENCH_PROBE_RETRIES": "1"}
-    old = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        rc = benchmark.run_orchestrator(args)
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    lines = capsys.readouterr().out.strip().splitlines()
-    assert rc == 1 and len(lines) == 1      # the failure record, nothing else
-    rec = _json.loads(lines[0])
-    assert rec["value"] is None and rec.get("probe_error")
-    assert "kernel_bench" in rec["metric"]
-    assert "degraded" not in rec and "platform" not in rec
-
-
-# ------------------------------------------------------------- bench leg
-@pytest.mark.slow
-def test_kernel_bench_leg_smoke():
-    from bigdl_tpu.benchmark import _measure_kernel_bench
-    res = _measure_kernel_bench(batch=16, iters=2)
-    assert res["convbn_fused_speedup"] is not None
-    assert res["convbn_fused_flops_ratio"] < 1.0  # folding removes ops
-    assert res["flat_update_speedup"] is not None
-    assert res["grad_accum_temp_bytes_m4"] < res["grad_accum_temp_bytes_m1"]

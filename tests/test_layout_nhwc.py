@@ -187,32 +187,19 @@ class TestInceptionNHWC:
         assert np.allclose(np.transpose(o1, (0, 2, 3, 1)), o2, atol=1e-4)
 
 
-class TestBenchFastPathBuild:
-    """The committed bench must build the TPU fast config by default: the
-    round-4 headline (NHWC + s2d) has to be reproducible by a plain
-    ``python bench.py``, not only via out-of-tree env overrides."""
-
-    def test_build_resnet50_is_nhwc_s2d(self, monkeypatch):
-        monkeypatch.delenv("BIGDL_BENCH_LAYOUT", raising=False)
-        monkeypatch.delenv("BIGDL_BENCH_S2D", raising=False)
-        from bigdl_tpu import benchmark
-        from bigdl_tpu.models.resnet.resnet import _Conv1SpaceToDepth
-        model, dataset, _ = benchmark._build("resnet50", 2, 1, "fp32")
-        assert layout.image_format() == "NHWC"
-        # the s2d stem must actually be in the built model (the committed
-        # default, not an env-dependent accident)
-        assert "_Conv1SpaceToDepth" in repr(model)
-        batch = next(dataset.data(train=True))
-        assert batch.input.shape == (2, 224, 224, 3)
-        # uint8 feed + device-side nn.ImageNormalize: 4x less wire traffic
-        assert batch.input.dtype == np.uint8
-        out, _ = model.apply(model.get_params(), model.get_state(),
-                             jnp.asarray(batch.input), training=True, rng=None)
-        assert out.shape == (2, 1000)
-
-    def test_layout_opt_out(self, monkeypatch):
-        monkeypatch.setenv("BIGDL_BENCH_LAYOUT", "nchw")
-        from bigdl_tpu import benchmark
-        _, dataset, _ = benchmark._build("vgg16", 2, 1, "fp32")
-        assert layout.image_format() == "NCHW"
-        assert next(dataset.data(train=True)).input.shape == (2, 3, 32, 32)
+def test_build_resnet50_is_nhwc_s2d():
+    """ResNet-50 as the benchmark's ResNet cell and ``chip_smoke.py`` build it,
+    on the public API: channels-last, the space-to-depth stem in the model, a
+    uint8 batch normalised on the device by ``nn.ImageNormalize``."""
+    from bigdl_tpu.models.resnet import ResNet
+    layout.set_image_format("NHWC")
+    net = ResNet(1000, {"depth": 50, "dataSet": "ImageNet",
+                        "conv1SpaceToDepth": True})
+    model = nn.Sequential().add(nn.ImageNormalize()).add(net)
+    assert layout.image_format() == "NHWC"
+    assert "_Conv1SpaceToDepth" in repr(model)
+    x = np.random.default_rng(0).integers(0, 256, size=(2, 224, 224, 3)).astype(np.uint8)
+    out, _ = model.apply(model.get_params(), model.get_state(),
+                         jnp.asarray(x), training=True, rng=None)
+    assert out.shape == (2, 1000) and out.dtype == jnp.float32
+    assert bool(jnp.all(jnp.isfinite(out)))

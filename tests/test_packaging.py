@@ -68,6 +68,20 @@ class TestCli:
         assert main([]) == 2
         assert "train" in capsys.readouterr().out
 
+    def test_bench_is_no_subcommand(self, capsys):
+        """The package has no benchmark of its own (the repo's is
+        ``benchmarks/run.py``): ``bigdl-tpu bench`` is refused as any unknown
+        sub-command is, and ``--help`` names none."""
+        from bigdl_tpu.cli import main
+        with pytest.raises(SystemExit) as e:
+            main(["bench"])
+        assert e.value.code == 2
+        assert "invalid choice: 'bench'" in capsys.readouterr().err
+        with pytest.raises(SystemExit) as e:
+            main(["--help"])
+        assert e.value.code == 0
+        assert "bench" not in capsys.readouterr().out
+
 
 class TestLauncherScript:
     def test_launcher_script_syntax(self):
@@ -103,59 +117,17 @@ class TestLauncherScript:
 
 
 class TestPackagedContract:
-    def test_bench_and_dryrun_are_packaged(self):
-        """The console script's bench/dryrun must not depend on repo-root
-        modules (the wheel has no bench.py / __graft_entry__.py)."""
-        import bigdl_tpu.benchmark
+    def test_dryrun_is_packaged(self):
+        """The console script's dryrun must not depend on repo-root modules
+        (the wheel has no __graft_entry__.py)."""
         import bigdl_tpu.dryrun
-        assert callable(bigdl_tpu.benchmark.main)
         assert callable(bigdl_tpu.dryrun.dryrun_multichip)
 
-    def test_repo_root_shims_delegate(self):
-        import bench
+    def test_repo_root_shim_delegates(self):
         import __graft_entry__
-        import bigdl_tpu.benchmark
         import bigdl_tpu.dryrun
-        assert bench.main is bigdl_tpu.benchmark.main
         assert __graft_entry__.dryrun_multichip is bigdl_tpu.dryrun.dryrun_multichip
         assert __graft_entry__.entry is bigdl_tpu.dryrun.entry
-
-
-class TestCliBench:
-    def test_bench_subcommand_parses(self, monkeypatch):
-        """`bigdl-tpu bench` must not re-parse sys.argv (review fix)."""
-        import bigdl_tpu.benchmark as bm
-        from bigdl_tpu.cli import main
-        called = {}
-
-        def orchestrator(args):
-            called["model"] = args.model
-            return 0
-
-        monkeypatch.setattr(bm, "run_orchestrator", orchestrator)
-        monkeypatch.setattr("sys.argv", ["bigdl-tpu", "bench"])
-        assert main(["bench"]) == 0
-        assert called["model"] == "resnet50"
-
-    def test_worker_spawn_sets_pythonpath(self):
-        """Spawned workers must import bigdl_tpu from any cwd (review fix)."""
-        import json
-        import subprocess
-        import sys
-        env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-        env["JAX_PLATFORMS"] = "cpu"
-        # ORCHESTRATOR mode so the `-m bigdl_tpu.benchmark` worker is actually
-        # spawned: parent finds bigdl_tpu via sys.path[0] (the script dir); the
-        # worker subprocess must get it from _spawn's PYTHONPATH propagation
-        r = subprocess.run(
-            [sys.executable, os.path.join(ROOT, "bench.py"),
-             "--model", "lenet", "--batch", "16", "--iters", "2",
-             "--warmup", "1", "--dtype", "fp32", "--no-compare-dtypes",
-             "--timeout", "500"],
-            cwd="/tmp", capture_output=True, text=True, timeout=600, env=env)
-        assert r.returncode == 0, r.stderr[-1500:]
-        line = json.loads(r.stdout.strip().splitlines()[-1])
-        assert line["value"] is not None
 
     def test_no_build_artifacts_tracked(self):
         r = subprocess.run(["git", "ls-files", "build", "dist",
@@ -164,16 +136,3 @@ class TestCliBench:
         assert r.stdout.strip() == "", "generated artifacts tracked in git"
 
 
-class TestCliBenchArgs:
-    def test_bench_forwards_args(self, monkeypatch):
-        import bigdl_tpu.benchmark as bm
-        from bigdl_tpu.cli import main
-        seen = {}
-
-        def orchestrator(args):
-            seen.update(model=args.model, iters=args.iters)
-            return 3       # the CLI hands the bench's exit code through
-
-        monkeypatch.setattr(bm, "run_orchestrator", orchestrator)
-        assert main(["bench", "--model", "lenet", "--iters", "5"]) == 3
-        assert seen == {"model": "lenet", "iters": 5}

@@ -181,14 +181,16 @@ class TestStallDeadlineWatchdog:
     def test_stall_trips_middecode_deadline(self, lm, monkeypatch):
         """serve_stall@2 wedges the decode loop past the request's
         deadline: the request fails with RequestTimeout mid-decode (tokens
-        already emitted) and its slot is recycled."""
+        already emitted) and its slot is recycled. The deadline (2 s) has to
+        outlast admission and prefill on a loaded host, or the request dies
+        "while queued"; the stall (3 s) has to outlast the deadline."""
         c0 = events.counts()
-        monkeypatch.setenv("BIGDL_FAULT_STALL_S", "0.5")
+        monkeypatch.setenv("BIGDL_FAULT_STALL_S", "3.0")
         with ServingEngine(lm, max_len=48, slots=2, buckets=(8,)) as warm:
             warm.submit(_prompt(460, 4), 2).result(timeout=180)
         with inject_faults("serve_stall@2") as plan:
             with ServingEngine(lm, max_len=48, slots=2, buckets=(8,)) as eng:
-                h = eng.submit(_prompt(461, 4), 20, deadline_ms=250)
+                h = eng.submit(_prompt(461, 4), 20, deadline_ms=2000)
                 with pytest.raises(RequestTimeout, match="mid-decode"):
                     h.result(timeout=180)
                 assert eng.stats()["timeouts"] == 1

@@ -184,7 +184,16 @@ def test_sparse_update_matches_dense_on_touched_rows(make_method):
     gradient is a single occurrence, so dense scatter-add and dedup
     segment-sum associate identically), and untouched rows are
     bitwise-unchanged from initialization. Duplicate ids reorder the
-    per-occurrence sum — that last-ulp case is pinned separately below."""
+    per-occurrence sum — that last-ulp case is pinned separately below.
+
+    Adam's touched rows are held to two units in the last place of the
+    largest weight, not to the bit: the dense and the sparse step are two
+    compiled programs, and XLA fuses the division and the square root of
+    ``m / (sqrt(v) + eps)`` differently in each. The first step is bitwise;
+    from the second on a few rows differ by one such unit (1.19e-07 on weights
+    of order 1, at 4 steps and at 8), which on an element near zero is many of
+    that element's own ulps, so the bound is absolute. SGD with momentum and
+    Adagrad stay bitwise."""
     V, D, B = 50, 8, 32
     rng = np.random.default_rng(3)
     ids = rng.permutation(np.arange(2, 2 + B, dtype=np.int32))  # 1-based, const
@@ -202,7 +211,12 @@ def test_sparse_update_matches_dense_on_touched_rows(make_method):
 
     w_dense = np.asarray(dense_t.get_params()["weight"])
     w_sparse = np.asarray(sparse_t.get_params()["table"]["weight"])
-    assert np.array_equal(w_sparse[touched], w_dense[touched])
+    if isinstance(opt.optim_method, Adam):
+        np.testing.assert_allclose(
+            w_sparse[touched], w_dense[touched], rtol=0,
+            atol=2 * np.spacing(np.abs(w_dense[touched]).max()))
+    else:
+        assert np.array_equal(w_sparse[touched], w_dense[touched])
     untouched = np.setdiff1d(np.arange(V), touched)
     assert np.array_equal(w_sparse[untouched], w0[untouched])
     assert not np.array_equal(w_sparse[touched], w0[touched])  # it DID train

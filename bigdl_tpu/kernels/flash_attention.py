@@ -82,6 +82,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu.kernels.layernorm import _on_tpu, out_struct
 
@@ -702,8 +703,29 @@ def flash_attention(q, k, v, causal: bool = False,
     return _fa_fwd(q, k, v, causal, force_pallas, mask)[0]
 
 
+#: What the backward kernels read, by the names ``_fa_fwd`` tags them with
+#: (``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` whose policy
+#: saves these names keeps them and does not run the forward kernel again.
+RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
+
+
 def _fa_fwd(q, k, v, causal, force_pallas, mask):
+    """The forward kernel and what the backward kernels read: ``q``, ``k``,
+    ``v`` as they enter (after a caller's norms, RoPE and layout copies),
+    ``out`` and the rows' logsumexp, each tagged with its name of
+    ``RESIDUAL_NAMES``. A tag is the identity unless a ``jax.checkpoint``
+    policy asks for its name (``ConfigDecoder``'s does): then these stay
+    across the backward pass and the forward kernel is not run again, for
+    ``2 * (2 * heads + 2 * kv_heads) * T * head_dim`` bytes a batch row in
+    bf16 (q and out, k and v) and ``4 * heads * T`` of logsumexp. The
+    logsumexp is tagged as the kernel writes it, ``(bh, T, 1)``, which pads to
+    128 lanes only where a kernel reads it: XLA lays a stack of them out with
+    the unit axis outermost, so a scan holds the numbers alone (PERF.md, PR
+    32). On the reference path there is no logsumexp, and ``out`` alone is
+    tagged."""
     mask = _as_mask(causal, mask)
+    q, k, v = (checkpoint_name(q, "flash_q"), checkpoint_name(k, "flash_k"),
+               checkpoint_name(v, "flash_v"))
     if isinstance(mask, BlockDiffusion) and q.shape[2] != 2 * mask.length:
         raise ValueError(f"{mask} is over {2 * mask.length} positions, the "
                          f"operands have {q.shape[2]}")
@@ -712,12 +734,13 @@ def _fa_fwd(q, k, v, causal, force_pallas, mask):
     hkv = k.shape[1]
     tiles = _tiles_under(mask, t, d, q.dtype.itemsize)
     if not use_pallas or tiles is None:
-        return _reference_attention(q, k, v, mask), (q, k, v, None, None)
+        out = checkpoint_name(_reference_attention(q, k, v, mask), "flash_out")
+        return out, (q, k, v, None, None)
     out, lse = _pallas_flash_call(
         q.reshape(b * h, t, d), k.reshape(b * hkv, t, d),
         v.reshape(b * hkv, t, d), mask, tiles, interpret=not _on_tpu())
-    out = out.reshape(b, h, t, d)
-    return out, (q, k, v, out, lse)
+    out = checkpoint_name(out.reshape(b, h, t, d), "flash_out")
+    return out, (q, k, v, out, checkpoint_name(lse, "flash_lse"))
 
 
 def _fa_bwd(causal, force_pallas, mask, res, g):

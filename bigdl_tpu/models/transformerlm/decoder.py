@@ -16,8 +16,16 @@ with grouped-query heads of their own ``head_dim``, per-head RMSNorm on
 queries and keys, RoPE by position ids, and the routed SiLU-gated expert
 layer, which is told which experts it holds (``held``). The two modules are
 the layer's templates: the scan body calls their ``apply`` on a layer's
-slice of the stacked parameters. ``remat`` recomputes each layer in the
-backward pass (the scan then keeps one hidden state a layer).
+slice of the stacked parameters. ``remat`` rematerialises the layers: the
+scan keeps, a layer, its input and what is dear to make again (``KEPT``: the
+flash kernels' ``q``, ``k``, ``v``, ``out`` and logsumexp, the routing, the
+hidden state after attention), ``N * T * ((2 * heads + 2 * kv_heads) *
+head_dim + 2 * hidden) * 2`` bytes and the routing's ``12 * N * T * top_k``,
+0.44 GB a layer at 2 x 8,192 positions, 32 + 4 + 4 heads of 128 and hidden
+2,048 in bf16; the norms, the projections into the heads (the per-head norms'
+backward reads their results), the router's logits and its softmax run again
+in the backward pass, the flash forward kernel, the output projection, top-k
+and the sort do not. ``remat=False`` keeps every value of every layer.
 
 ``block_diffusion=(L, b)`` trains by diffusion over blocks (BD3-LMs,
 arXiv:2503.09573): the input is ``[x_t ; x_0]``, a noised copy of a sequence of
@@ -37,14 +45,22 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu import nn
+from bigdl_tpu.kernels.flash_attention import RESIDUAL_NAMES, BlockDiffusion
 from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.criterion import AbstractCriterion
 from bigdl_tpu.nn.fused_loss import chunked_softmax_xent
-from bigdl_tpu.parallel.moe import MoE
+from bigdl_tpu.parallel.moe import ROUTING_NAMES, MoE
 from bigdl_tpu.utils.random_generator import RandomGenerator
 from bigdl_tpu.utils.table import Table
+
+#: What a rematerialised layer keeps across the backward pass beside its
+#: input, by the names the values are tagged with where they are made: the
+#: flash kernels' operands and residuals, the routing, and the hidden state
+#: after attention (tagged in ``apply``).
+KEPT = RESIDUAL_NAMES + ROUTING_NAMES + ("decoder_after_attention",)
 
 # the layers' health leaves as the decoder's own state: the worst layer for
 # what warns, the sum for what counts
@@ -79,10 +95,7 @@ class ConfigDecoder(TensorModule):
         self.initializer_range = float(initializer_range)
         self.remat = bool(remat)
         self.block_diffusion = block_diffusion and tuple(block_diffusion)
-        mask = None
-        if self.block_diffusion:
-            from bigdl_tpu.kernels.flash_attention import BlockDiffusion
-            mask = BlockDiffusion(*self.block_diffusion)
+        mask = BlockDiffusion(*self.block_diffusion) if self.block_diffusion else None
         self.norm = nn.RMSNorm(hidden_size, eps=rms_norm_eps)
         self.attention = nn.MultiHeadAttention(
             hidden_size, num_attention_heads, causal=mask is None,
@@ -165,14 +178,15 @@ class ConfigDecoder(TensorModule):
             a, _ = norm.apply({"weight": p["attn_norm"]}, {}, h)
             a, _ = self.attention.apply(p["attn"], {}, (a, positions),
                                         training=training)
-            h = h + a
+            h = checkpoint_name(h + a, "decoder_after_attention")
             m, _ = norm.apply({"weight": p["moe_norm"]}, {}, h)
             m, health = self.experts.apply(p["moe"], experts_state, m,
                                            training=training)
             return h + m, {k: health[k] for k in _HEALTH}
 
         if self.remat:
-            layer = jax.checkpoint(layer)
+            layer = jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
         h, health = jax.lax.scan(layer, h, params["layers"])
         if self.block_diffusion:
             h = h[:, :self.block_diffusion[0]]      # the noised half predicts

@@ -53,12 +53,45 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.initialization import InitializationMethod, RandomNormal
 from bigdl_tpu.obs import trace
 from bigdl_tpu.parallel.tensor_parallel import TPRules
 from jax.sharding import PartitionSpec as P
+
+
+#: The routing of ``router="topk"`` by the names ``_apply_topk`` tags it with
+#: (``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` whose policy
+#: saves these names runs top-k and the sort once a step.
+ROUTING_NAMES = ("moe_top_p", "moe_top_e", "moe_order", "moe_sizes",
+                 "moe_passes")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _top_k(probs, k: int, n_experts: int):
+    """``lax.top_k`` over the ``n_experts`` columns of ``probs``, its two
+    results tagged ``moe_top_p`` and ``moe_top_e``, with a gradient that reads
+    the tagged indices: top-k's own JVP reads its untagged result, so a policy
+    that keeps the names would still run top-k again in the backward pass.
+    The gradient goes back by comparison (one loop fusion over (T, k, E)),
+    where the transposed gather is a scatter-add, serial on the chip."""
+    return _top_k_fwd(probs, k, n_experts)[0]
+
+
+def _top_k_fwd(probs, k, n_experts):
+    top_p, top_e = jax.lax.top_k(probs, k)
+    top_e = checkpoint_name(top_e, "moe_top_e")
+    return (checkpoint_name(top_p, "moe_top_p"), top_e), top_e
+
+
+def _top_k_bwd(k, n_experts, top_e, g):
+    chosen = top_e[:, :, None] == jnp.arange(n_experts)
+    return (jnp.sum(jnp.where(chosen, g[0][:, :, None], 0.0), axis=1),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
 def _held_rows(total: int, count: int, n_experts: int) -> int:
@@ -362,7 +395,15 @@ class MoE(TensorModule):
         docstring. Scopes: ``bigdl_moe_route`` (router, top-k, the sort, and
         in each pass the gather and its gradient's sum by token),
         ``bigdl_moe_experts`` (the two grouped products and the gate between
-        them), ``bigdl_moe_combine`` (the weighted sum back)."""
+        them), ``bigdl_moe_combine`` (the weighted sum back). The routing is
+        tagged by ``ROUTING_NAMES`` where it is made: the top-k's
+        probabilities and indices (``_top_k``), the pairs' order, the held
+        groups' sizes and the passes. A tag is the identity unless a
+        ``jax.checkpoint`` policy asks for its name (``ConfigDecoder``'s
+        does): then top-k, the sort and the counts run once a step, for
+        ``4 * tokens * (3 * top_k)`` bytes and a few numbers a layer (1.6 MB
+        at 16,384 tokens and top-8), and ``_routed``'s residuals are these
+        and its recomputed input."""
         tokens, k = x.shape[0], self.top_k
         first, count = self.held
         total = tokens * k
@@ -371,7 +412,7 @@ class MoE(TensorModule):
             logits = jnp.dot(x, params["w_gate"].astype(x.dtype),
                              preferred_element_type=jnp.float32)
             probs = jax.nn.softmax(logits, axis=-1)
-            top_p, top_e = jax.lax.top_k(probs, k)                  # (T, k)
+            top_p, top_e = _top_k(probs, k, self.n_experts)         # (T, k)
             if self.norm_topk_prob:
                 top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
             here = (top_e >= first) & (top_e < first + count)
@@ -385,6 +426,9 @@ class MoE(TensorModule):
             # this share more than twice its balanced expectation
             passes = jnp.maximum(-(-jnp.sum(sizes) // rows), 1)
             order = jnp.pad(order, (0, -total % rows))
+            order = checkpoint_name(order, "moe_order")
+            sizes = checkpoint_name(sizes, "moe_sizes")
+            passes = checkpoint_name(passes, "moe_passes")
         y = _routed(x, params["w_in"].astype(x.dtype),
                     params["w_out"].astype(x.dtype), top_p, order, sizes,
                     passes, rows, self.hidden_size)

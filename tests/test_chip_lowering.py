@@ -319,3 +319,52 @@ def test_off_tpu_default_is_the_reference():
     np.testing.assert_array_equal(
         np.asarray(ln.fused_layer_norm(x, g, b)),
         np.asarray(ln._reference_layer_norm(x, g, b, 1e-5)))
+
+
+# ------------------------------------- what the scanned decoder keeps
+def _decoder_gradient(monkeypatch, remat, body=None) -> str:
+    """The gradient of a two-layer ``ConfigDecoder`` under ``BlockDiffusion``,
+    lowered for the TPU. ``body`` wraps the scan's layer in ``remat``'s place."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    from bigdl_tpu.models.transformerlm import ConfigDecoder
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    model = ConfigDecoder(
+        vocab_size=512, hidden_size=256, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        moe_intermediate_size=128, num_experts=16, num_experts_per_tok=4,
+        held=(4, 4), block_diffusion=(512, 4), remat=remat)
+    if body is not None:
+        scan = jax.lax.scan
+        monkeypatch.setattr(jax.lax, "scan",
+                            lambda f, *a, **k: scan(body(f), *a, **k))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), model.get_params())
+
+    def loss(p, x):
+        out, _ = model.apply(p, model.get_state(), x, training=True)
+        return out[1].astype(jnp.float32).sum()
+
+    return _tpu_module(jax.grad(loss), params,
+                       jax.ShapeDtypeStruct((2, 1024), jnp.int32))
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_scanned_decoder_runs_attention_and_routing_once(on_tpu, monkeypatch, remat):
+    """Rematerialised or not, the step holds each flash kernel once (the scan
+    body's forward, the backward body's two), one top-k and one sort: what
+    ``ConfigDecoder``'s policy keeps is not run again in the backward pass."""
+    text = _decoder_gradient(monkeypatch, remat)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(text, name) == 1, name
+    assert text.count("stablehlo.sort") == 1
+    assert text.count("@mhlo.topk") == 1
+
+
+def test_whole_layer_rematerialisation_would_show(on_tpu, monkeypatch):
+    """The control: with a bare ``jax.checkpoint`` around the layer the same
+    count reads two, so the test above would see the policy go."""
+    text = _decoder_gradient(monkeypatch, False, body=jax.checkpoint)
+    assert _kernel_calls(text, "bigdl_flash_fwd") == 2
+    assert _kernel_calls(text, "bigdl_flash_bwd_dq") == 1
+    assert text.count("stablehlo.sort") == 2
+    assert text.count("@mhlo.topk") == 2

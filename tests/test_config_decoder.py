@@ -4,6 +4,7 @@ one scanned body, against the benchmark's plain reference for
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -111,6 +112,47 @@ def test_rematerialised_scan_differentiates_through_the_routed_vjp(bench, cell, 
     np.testing.assert_allclose(float(loss_r), float(loss_k), rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(grads_r), jax.tree_util.tree_leaves(grads_k)):
         np.testing.assert_allclose(a, b, atol=1e-6 + 1e-5 * float(jnp.max(jnp.abs(b))))
+
+
+def test_scan_keeps_the_named_values_and_the_layers_input(bench, cell, capsys):
+    """What the rematerialised scan holds a layer across the backward pass:
+    the values tagged by name where they are made (``RESIDUAL_NAMES``,
+    ``ROUTING_NAMES``, the decoder's own) and the layer's input, nothing
+    else, and nothing of (tokens * top_k, feature)."""
+    from jax.ad_checkpoint import print_saved_residuals
+    cfg, mod, _ = cell
+    model, criterion = mod.build(cfg, TRAFFIC)
+    x, y = mod.make_batches(cfg, TRAFFIC, np.random.default_rng(6))[0]
+
+    def loss(p):
+        out, _ = model.apply(p, model.get_state(), jnp.asarray(x), training=True)
+        return criterion.apply(out, jnp.asarray(y))
+
+    print_saved_residuals(loss, model.get_params())
+    kept = sorted(re.match(r"(\w+\[[\d,]*\])", line).group(1)
+                  for line in capsys.readouterr().out.splitlines()
+                  if "output of scan" in line)
+    layers, n, t, d = cfg["num_hidden_layers"], TRAFFIC["batch"], 2 * TRAFFIC["seq_len"], cfg["hidden_size"]
+    heads, kv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
+    tokens, k = n * t, cfg["num_experts_per_tok"]
+    f32, i32 = "f32[{}]", "i32[{}]"
+    shape = lambda *dims: ",".join(str(v) for v in (layers,) + dims)
+    want = sorted([
+        f32.format(shape(n, t, d)),                 # the layer's input (the carry)
+        f32.format(shape(n, heads, t, hd)),         # flash_q
+        f32.format(shape(n, kv, t, hd)),            # flash_k
+        f32.format(shape(n, kv, t, hd)),            # flash_v
+        f32.format(shape(n, heads, t, hd)),         # flash_out (no lse off the chip:
+                                                    # the reference path has none)
+        f32.format(shape(n, t, d)),                 # decoder_after_attention
+        f32.format(shape(tokens, k)),               # moe_top_p
+        i32.format(shape(tokens, k)),               # moe_top_e
+        i32.format(shape(tokens * k)),              # moe_order
+        i32.format(shape(cfg["held"][1])),          # moe_sizes
+        i32.format(shape()),                        # moe_passes
+    ])
+    assert kept == want
+    assert not any(f"[{layers},{tokens * k}," in a for a in kept)
 
 
 def test_names_follow_the_programs_tree(bench, cell):

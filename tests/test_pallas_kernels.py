@@ -121,6 +121,24 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-5)
 
+    def test_tags_are_the_identity_where_no_policy_names_them(self, monkeypatch):
+        """``_fa_fwd`` names what the backward kernels read; without a
+        ``jax.checkpoint`` policy that asks for the names, value and gradient
+        are bitwise those of the untagged kernels."""
+        from bigdl_tpu.kernels import flash_attention as fa
+        q, k, v = self._qkv(t=64, d=16, seed=5)
+
+        def value_and_grads():
+            return jax.value_and_grad(
+                lambda a, b, c: jnp.sum(jnp.sin(fa.flash_attention(a, b, c, True, True))),
+                argnums=(0, 1, 2))(q, k, v)
+
+        tagged = value_and_grads()
+        monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+        for a, b in zip(jax.tree_util.tree_leaves(tagged),
+                        jax.tree_util.tree_leaves(value_and_grads())):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_matches_full_attention_module_path(self):
         from bigdl_tpu.kernels.flash_attention import flash_attention
         from bigdl_tpu.parallel.ring_attention import full_attention

@@ -1,10 +1,9 @@
 """`resnet50` as the program runs it, and as the benchmark names its weights.
 
 `build` is the only function here that touches the program: the configuration
-of `bigdl_tpu.benchmark._build("resnet50", ...)` (NHWC, space-to-depth stem,
-uint8 feed normalised on the device) without its environment reads. `names`
-lists the weights in the order the program's parameter tree holds them (paths
-sorted with numbers as numbers).
+of `chip_smoke.py:build_resnet50` (NHWC, space-to-depth stem, uint8 feed
+normalised on the device). `names` lists the weights in the order the
+program's parameter tree holds them (paths sorted with numbers as numbers).
 """
 
 import jax

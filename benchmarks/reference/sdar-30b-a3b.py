@@ -100,7 +100,8 @@ def _attention(qh, kh, vh, length, b, q):
 
 
 def _layer(h, p, cfg, q):
-    """One layer over one sequence `h` (2L, hidden)."""
+    """One layer over one sequence `h` (2L, hidden): its output, and the
+    experts (2L, k) that each position reaches."""
     eps, theta = cfg["rms_norm_eps"], float(cfg["rope_theta"])
     heads, kv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
     t = h.shape[0]
@@ -134,7 +135,7 @@ def _layer(h, p, cfg, q):
 
     y, _ = jax.lax.scan(expert, jnp.zeros_like(h), (
         jnp.arange(cfg["held"][1]), p["experts.in"], p["experts.out"]))
-    return h + y
+    return h + y, top_e
 
 
 def loss(params, x, y, cfg, q=lambda a: a):
@@ -142,7 +143,7 @@ def loss(params, x, y, cfg, q=lambda a: a):
     length = x.shape[1] // 2
     h = params["embed"][x]
     layers = {k: params["layers." + k] for k in LAYER_KEYS}
-    one = jax.checkpoint(lambda hs, p: _layer(hs, p, cfg, q))
+    one = jax.checkpoint(lambda hs, p: _layer(hs, p, cfg, q)[0])
     h, _ = jax.lax.scan(
         lambda h, p: (jax.lax.map(lambda hs: one(hs, p), h), None), h, layers)
 
@@ -156,6 +157,20 @@ def loss(params, x, y, cfg, q=lambda a: a):
         return jnp.sum(jnp.where(target >= 0, ys[1] * nll, 0.0)) / length
 
     return jnp.mean(jax.lax.map(sequence_loss, (h, y)))
+
+
+def routing(params, x, cfg):
+    """(layers, batch, 2L, k) int32: the experts that each position of the
+    batch `x` reaches in each layer, forward only. The program's expert layer
+    works over a bound of twice the balanced expectation of the pairs that
+    reach a held expert; what a layer holds of them under given weights is
+    counted from this (`drift.py`), apart from the program."""
+    layers = {k: params["layers." + k] for k in LAYER_KEYS}
+
+    def layer(h, p):
+        return jax.lax.map(lambda hs: _layer(hs, p, cfg, lambda a: a), h)
+
+    return jax.lax.scan(layer, params["embed"][x], layers)[1]
 
 
 def make_loss_and_grad(cfg, q=lambda a: a):

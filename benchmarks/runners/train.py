@@ -12,6 +12,7 @@ program, and hands the same optimizer to the window. The window is one `optimize
 
 import gc
 import shutil
+import sys
 import tempfile
 import time
 
@@ -48,11 +49,13 @@ class _Clock:
         self.min_steps, self.first_step = min_steps, None
         self.trace_dir, self.spans = trace_dir, spans
         self.t_first = None
+        self.evaluated = []     # the host's clock at each evaluation
         self.traced = None      # the traced seconds: clock, steps and spans at both ends
 
     def __call__(self, state):
         import jax
         now = time.perf_counter()
+        self.evaluated.append(now)
         if self.t_first is None:
             self.t_first, self.first_step = now, state["neval"]
         left = self.t_first + self.seconds - now
@@ -174,8 +177,34 @@ def window(cell, st, seconds, trace):
     t0 = time.perf_counter()
     opt.optimize()
     wall = time.perf_counter() - t0
-    return {"steps": opt.state["neval"] - first, "wall_s": wall,
-            "trace_dir": trace_dir, "traced": clock.traced}
+    return {"steps": opt.state["neval"] - first, "wall_s": wall, "t0": t0,
+            "evaluated": clock.evaluated, "trace_dir": trace_dir, "traced": clock.traced}
+
+
+def observable_state(opt, model):
+    """Every scalar leaf of the model's state that the program names as worth
+    watching (`Optimizer.OBSERVABLE_STATE_LEAVES`), as the last step left it,
+    by its path; empty for a model that has none. One fetch, outside any
+    window."""
+    import jax
+    found = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(model.get_state())[0]:
+        keys = [str(getattr(k, "key", k)) for k in path]
+        if keys[-1] in opt.OBSERVABLE_STATE_LEAVES and getattr(leaf, "shape", None) == ():
+            found["/".join(keys)] = leaf
+    return {k: float(v) for k, v in jax.device_get(found).items()}
+
+
+def over_the_traffics_bounds(cell, state):
+    """A line for each leaf of `state` that reads over what the traffic file
+    allows it (`state_at_most`, by the leaf's name): the window then measured
+    something else than the cell, as when a routed layer repeats its pass."""
+    most = cell.traffic.get("state_at_most", {})
+    return [f"{cell.name}: after the window's last step the state leaf {path} reads "
+            f"{value:g}, over the traffic file's state_at_most "
+            f"{most[path.rsplit('/', 1)[-1]]:g}: not a sound measurement of the cell"
+            for path, value in state.items()
+            if value > most.get(path.rsplit("/", 1)[-1], float("inf"))]
 
 
 def release(st, programs=True):
@@ -218,6 +247,15 @@ def run(cell, seed, seconds, trace, t_start, devices, peak):
               "setup_s": setup_s}
     extra = {"steps": win["steps"], "window_s": win["wall_s"],
              "setup_laps": st["laps"]}
+    # the host's view of where the window's time went: seconds into the call
+    # at each evaluation of the end trigger (after every dispatch, and at an
+    # epoch's end after the flush that waits for the device)
+    extra["evaluated_s"] = [round(t - win["t0"], 3) for t in win["evaluated"]]
+    win["state"] = observable_state(st["opt"], st["model"])
+    if win["state"]:
+        extra["state"] = win["state"]
+    for line in over_the_traffics_bounds(cell, win["state"]):
+        print(line, file=sys.stderr)
     observed = st["observed"]
     release(st)
     per_layer = {}

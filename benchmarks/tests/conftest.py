@@ -27,27 +27,12 @@ for p in (BENCH, ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY = {
-    "gpt2-medium": {"n_embd": 64, "n_head": 4, "n_layer": 2, "vocab_size": 512,
-                    "n_positions": 32, "n_ctx": 32},
-    "resnet50": {"image_size": 32, "class_num": 10},
-}
-# ResNet-50 on 8 images of 32x32 is chaotic at the cell's learning rate (the
-# loss rises from 2.7 to 10 in two steps); a tenth of it keeps nine steps close
-TINY_TRAFFIC = {
-    "train-t1024": {"batch": 4, "seq_len": 32},
-    "train-stream": {"batch": 8, "n_batches": 16, "label_classes": 4,
-                     "optim_method": {"class": "SGD", "args": {
-                         "learningrate": 0.002, "momentum": 0.9, "dampening": 0.0}}},
-}
-# limits at these sizes, from readings on the CPU (the cells' own are read on
-# the chip): GPT-2 reads grad1 0.0034-0.0045 and change 0.001-0.007 over 4
-# seeds, its float8 control 0.031 and 0.030 on 2; ResNet reads change_median
-# 0.01-0.05 and momentum_median 0.02-0.06 over 2 seeds, a fault 0.8 and more
-TINY_LIMITS = {
-    "gpt2-medium": {"grad1": 0.012, "change": 0.015},
-    "resnet50": {"change_median": 0.3, "momentum_median": 0.3},
-}
+# `tiny/<config>.json`: a configuration's cut to what a CPU holds in a test
+# (`config`, laid over its file), its traffic mixes' cuts (`traffic`, by mix)
+# and the limits read at that size on the CPU (`limits`). A configuration
+# brings its own as a new file; the cells of one that brings none are not in
+# the tiny copy, and a test that names one skips (`test_run.py`, `_run`).
+TINY_DIR = os.path.join(HERE, "tiny")
 
 
 @pytest.fixture(scope="session")
@@ -59,12 +44,18 @@ def tiny_bench(tmp_path_factory):
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         "__pycache__", "tests", "limits"))
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    for name, cut in TINY.items():
+    found = {c["name"]: os.path.join(TINY_DIR, c["name"] + ".json")
+             for c in manifest["configs"]}
+    cuts = {name: json.load(open(path)) for name, path in found.items()
+            if os.path.exists(path)}
+    manifest["configs"] = [c for c in manifest["configs"] if c["name"] in cuts]
+    manifest["workloads"] = [w for w in manifest["workloads"] if w["config"] in cuts]
+    for name, cut in cuts.items():
         path = bench / "configs" / f"{name}.json"
-        path.write_text(json.dumps({**json.loads(path.read_text()), **cut}))
-    for name, cut in TINY_TRAFFIC.items():
-        path = bench / "traffic" / f"{name}.json"
-        path.write_text(json.dumps({**json.loads(path.read_text()), **cut}))
+        path.write_text(json.dumps({**json.loads(path.read_text()), **cut["config"]}))
+        for mix, less in cut["traffic"].items():
+            path = bench / "traffic" / f"{mix}.json"
+            path.write_text(json.dumps({**json.loads(path.read_text()), **less}))
     # the throw-away cell: a new traffic file, a new entry, no edit elsewhere
     extra = json.loads((bench / "traffic" / "train-t1024.json").read_text())
     extra.update(batch=2, fuse_steps=2)
@@ -75,6 +66,6 @@ def tiny_bench(tmp_path_factory):
     (bench / "limits").mkdir()
     for w in manifest["workloads"]:
         (bench / "limits" / f"{w['name']}.json").write_text(
-            json.dumps({"limits": TINY_LIMITS[w["config"]]}))
+            json.dumps({"limits": cuts[w["config"]]["limits"]}))
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     return str(bench)

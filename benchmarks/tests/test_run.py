@@ -17,16 +17,21 @@ import run
 
 CELL = "gpt2-medium.train-t1024"
 FUSED = "resnet50.train-stream"
+ROUTED = "sdar-30b-a3b.train-bd4k"
 
 
 def _run(capsys, bench, cell, seed=3_000_000_007, trace=0):
+    manifest = harness.load_json(os.path.join(os.path.dirname(bench), "BENCHMARK.json"))
+    if cell not in [w["name"] for w in manifest["workloads"]]:
+        pytest.skip(f"{cell}: its configuration brings no benchmarks/tests/tiny/"
+                    f"<configuration>.json, so the tiny copy has no such cell")
     rc = run.main(["--workload", cell, "--seed", str(seed), "--seconds", "1",
                    "--trace", str(trace)], bench_dir=bench, require_chip=False)
     out, err = capsys.readouterr()
     return rc, json.loads(out.strip().splitlines()[-1]), err
 
 
-@pytest.mark.parametrize("cell", [CELL, "gpt2-medium.train-throwaway", FUSED])
+@pytest.mark.parametrize("cell", [CELL, "gpt2-medium.train-throwaway", FUSED, ROUTED])
 def test_a_sound_run_is_correct(capsys, tiny_bench, cell):
     # the throw-away cell exists only in the temporary copy: a new traffic
     # file and a new entry of BENCHMARK.json, no other file touched
@@ -38,6 +43,12 @@ def test_a_sound_run_is_correct(capsys, tiny_bench, cell):
     assert line["compared"]["compiles_in_window"] == {"value": 0, "limit": 0}
     assert list(line)[-1] == "compared" and "compared compiles_in_window: 0" in err
     assert set(line["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
+    # the host's clock at each evaluation of the end trigger, in every line
+    assert len(line["evaluated_s"]) > line["steps"] // 8 and line["evaluated_s"][0] >= 0
+    # a routed model's last step, in every line: one pass, some pairs held
+    assert ("state" in line) == (cell == ROUTED) and "not a sound measurement" not in err
+    assert cell != ROUTED or (line["state"]["row_passes"] == 1.0
+                              and line["state"]["pairs_held"] > 0)
 
 
 def test_a_program_compiled_inside_the_window_is_not_correct(

@@ -186,7 +186,7 @@ def test_the_epoch_end_rule(scoped):
 # ----------------------------------------------------------- the benchmark
 def test_new_per_layer_entries_and_their_readers():
     by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert [m["name"] for m in MANIFEST["per_layer"]][-len(NEW):] == NEW
+    assert set(NEW) <= set(by_name)     # later PRs append entries of their own
     for name in NEW:
         entry = by_name[name]
         assert entry["moves"] == "train_samples_per_s"

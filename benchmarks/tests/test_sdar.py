@@ -83,17 +83,134 @@ def test_reference_matches_program():
     x, y = mod.make_batches(cfg, traffic, np.random.default_rng(0))[0]
 
     def program_loss(p):
-        out, _ = model.apply(p, model.get_state(), jnp.asarray(x), training=True)
-        return criterion.apply(out, jnp.asarray(y))
+        out, state = model.apply(p, model.get_state(), jnp.asarray(x), training=True)
+        return criterion.apply(out, jnp.asarray(y)), state
 
     with jax.default_matmul_precision("highest"):
-        lp, gp = jax.value_and_grad(program_loss)(params)
+        (lp, state), gp = jax.value_and_grad(program_loss, has_aux=True)(params)
         lr, gr = ref.make_loss_and_grad(cfg)(weights, jnp.asarray(x), jnp.asarray(y))
+        top_e = ref.routing(weights, jnp.asarray(x), cfg)
+    first, count = cfg["held"]
+    pairs = ((top_e >= first) & (top_e < first + count)).sum((1, 2, 3))
     assert abs(float(lp) - float(lr)) <= 1e-5 * abs(float(lr))
+    # the reference's count of held pairs, by layer, is the program's own
+    assert pairs.shape == (2,) and int(pairs.sum()) == int(state["pairs_held"])
+    assert float(state["row_passes"]) == 1.0
     gp = harness.names_from_tree(gp, names)
     for k in gr:
         scale = float(jnp.linalg.norm(gr[k])) + 1e-12
         assert float(jnp.linalg.norm(gp[k] - gr[k])) <= 1e-3 * scale + 1e-7, k
+
+
+def test_noise_levels_are_the_same_strata_in_an_order_drawn_from_the_seed():
+    traffic = json.load(open(os.path.join(harness.HERE, "traffic", "train-bd4k.json")))
+    mod = _load("configs")
+    a, b = (mod.noise_levels(traffic, np.random.default_rng(s)) for s in (1, 2 ** 31 + 5))
+    assert a.shape == b.shape == (traffic["n_batches"], traffic["batch"])
+    lo, hi = traffic["noise_t"]
+    strata = lo + (hi - lo) * (np.arange(a.size) + 0.5) / a.size
+    assert np.allclose(np.sort(a, None), strata) and np.allclose(np.sort(b, None), strata)
+    assert not np.array_equal(a, b)
+    # every batch masks as many positions as any other: one sum of t a batch
+    assert np.allclose(a.sum(1), a.sum() / len(a)) and np.allclose(b.sum(1), a.sum(1))
+    x, y = mod.make_batches(_config(), traffic, np.random.default_rng(7))[0]
+    masked = (x[:, :traffic["seq_len"]] == _config()["mask_token_id"])
+    assert np.array_equal(masked, y[:, 0] >= 0) and np.array_equal(masked, y[:, 1] > 0)
+    assert abs(masked.sum() - a.sum(1)[0] * traffic["seq_len"]) < 200
+
+
+def test_the_mask_row_routes_as_the_balanced_expectation_says():
+    """Of the candidates drawn from the seed, the `[MASK]` row is one whose own
+    top experts hold 4 x 4 / 16 = 1 held expert in each layer; every other
+    weight is what the seed gives without the choice: the embedding N(0, 1),
+    the other matrices at the source's range."""
+    cfg = dict(_config(), **json.load(open(os.path.join(
+        os.path.dirname(__file__), "tiny", NAME + ".json")))["config"])
+    mod = _load("configs")
+    for seed in (3, 2 ** 31 + 11):
+        key = harness.seed_key(seed)
+        w = mod.make_weights(cfg, key)
+        row = w["embed"][cfg["mask_token_id"]]
+        unit = row / jnp.sqrt(jnp.mean(row ** 2))
+        top = jax.lax.top_k(jnp.einsum("d,lde->le", unit, w["layers.router"]), 4)[1]
+        first, count = cfg["held"]
+        assert ((top >= first) & (top < first + count)).sum(-1).tolist() == [1, 1]
+        assert 0.8 < float(jnp.std(row)) < 1.2
+        plain = jax.random.normal(jax.random.fold_in(key, 0), w["embed"].shape, jnp.float32)
+        assert np.array_equal(w["embed"][:-1], plain[:-1])
+        assert not np.array_equal(row, plain[-1])
+        assert abs(float(jnp.std(w["head"])) - cfg["initializer_range"]) < 0.002
+
+
+def _epoch_means(line, epoch=4):
+    """Held pairs by epoch of 4 steps: each holds the run's 4 batches once."""
+    by_step = dict(zip(line["steps"], line["pairs_held"]))
+    return [sum(by_step[s] for s in range(e, e + epoch)) / epoch
+            for e in range(5, line["steps"][-1] - epoch + 2, epoch)]
+
+
+def test_at_the_cells_rate_the_routing_stays_and_at_1e_4_it_drifts(capsys, tiny_bench):
+    """`drift.py` over the tiny cut, 40 steps of the cell's own optimizer with
+    4 of 16 experts held: at the traffic file's rate the held pairs of an
+    epoch stay where the seeded weights put them and no layer repeats its
+    pass; at 1e-4, the rate the cell ran at before PR 34, only the held
+    experts answer and Adam pulls the router towards them."""
+    import drift
+    rate = json.load(open(os.path.join(harness.HERE, "traffic", "train-bd4k.json")))[
+        "optim_method"]["args"]["learningrate"]
+    assert rate <= 1e-5     # guarded by value too: PERF.md, PR 34, has the sweep
+    old = {"traffic": {"optim_method": {"args": {"learningrate": 1e-4}}}}
+    drift.main(["--workload", "sdar-30b-a3b.train-bd4k", "--seeds", "1", "--steps", "40",
+                "--variant", "{}", "--variant", json.dumps(old)],
+               bench_dir=tiny_bench, require_chip=False)
+    kept, before = (json.loads(l) for l in capsys.readouterr().out.strip().splitlines()[-2:])
+    assert kept["variant"] == {} and before["variant"] == old
+    means = _epoch_means(kept)
+    assert len(means) == 9 and set(kept["row_passes"]) == {1.0}
+    assert max(abs(m / means[0] - 1.0) for m in means) < 0.05
+    assert max(kept["pairs_held"]) < 1.25 * kept["pairs_held"][0]
+    assert _epoch_means(before)[-1] > 1.15 * means[-1]
+
+
+def test_the_survey_counts_held_pairs_and_distinct_routings_by_layer(capsys, tiny_bench):
+    """`drift.py --survey` runs no program: the reference's routing of every
+    batch at the seeded weights, a count a layer."""
+    import drift
+    drift.main(["--workload", "sdar-30b-a3b.train-bd4k", "--seeds", "5", "--survey"],
+               bench_dir=tiny_bench, require_chip=False)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    positions = 2 * 2 * 32          # the tiny cut: 2 sequences of 32 tokens and their copies
+    assert len(line["layers_seeded"]) == 4 and len(line["layers_seeded"][0]) == 2
+    for masked, tokens, pairs, distinct in zip(line["masked"], line["tokens_distinct"],
+                                               line["layers_seeded"], line["routings_distinct"]):
+        assert 0 < masked < positions // 2 and 1 < tokens <= positions - masked + 1
+        assert all(0 <= p <= 4 * positions for p in pairs)
+        assert all(1 <= d <= positions for d in distinct)
+    assert line["row_bound_a_layer"] == 2 * 4 * positions * 4 // 16
+
+
+def test_the_row_passes_reader_reads_the_observable_state_or_nothing():
+    reader = harness.load_module(os.path.join(harness.HERE, "metrics",
+                                              "moe_row_passes.train.py"))
+    state = {"row_passes": 1.0, "pairs_held": 9.0, "decoder/moe/row_passes": 2.0}
+    assert reader.read(SimpleNamespace(state=state)) == 2.0       # the fullest layer's
+    assert reader.read(SimpleNamespace(state={"aux_loss": 0.1})) is None
+    assert reader.read(SimpleNamespace(state={})) is None    # a model that routes nothing
+
+
+def test_a_state_leaf_over_the_traffic_files_bound_is_said_on_standard_error():
+    """`state_at_most` in the cell's traffic file: the runner knows no leaf's
+    name, and says which leaf read over what the file allows it."""
+    runner = harness.load_module(os.path.join(harness.HERE, "runners", "train.py"))
+    traffic = json.load(open(os.path.join(harness.HERE, "traffic", "train-bd4k.json")))
+    assert traffic["state_at_most"] == {"row_passes": 1.0}
+    cell = SimpleNamespace(name="a.cell", traffic=traffic)
+    assert runner.over_the_traffics_bounds(cell, {"row_passes": 1.0, "pairs_held": 9e4}) == []
+    said = runner.over_the_traffics_bounds(cell, {"moe/row_passes": 2.0, "pairs_held": 9e4})
+    assert len(said) == 1 and "moe/row_passes reads 2" in said[0]
+    assert "not a sound measurement" in said[0]
+    assert runner.over_the_traffics_bounds(SimpleNamespace(name="b", traffic={}),
+                                           {"row_passes": 3.0}) == []
 
 
 def test_scope_reader_sums_leaves_under_a_scope(monkeypatch):

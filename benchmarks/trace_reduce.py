@@ -191,6 +191,8 @@ def per_layer(cell, win, device, peak):
         work=cell.work, peak=peak, chips=cell.chips,
         memory_peak_bytes=device["memory_peak_bytes"],
         dispatched_steps=noted["step1"] - noted["step0"],
+        # the model's observable state leaves after the window's last step, by path
+        state=win.get("state", {}),
         # name -> (count, seconds) of the program's spans in the traced seconds
         spans={k: (v["count"] - s0.get(k, {}).get("count", 0),
                    (v["total_ms"] - s0.get(k, {}).get("total_ms", 0.0)) / 1e3)

@@ -22,11 +22,18 @@ second body without the mask for the chunks wholly below the diagonal was
 built and dropped: the compare and select hide under the MXU's time (1,231
 against 1,261 bundles a 512x512 forward chunk, 1,761 against 1,740 for dq,
 2,253 both ways for dk/dv) and it doubles the kernel's code. Block and chunk
-need not be equal. The mask is a static description (``None``, ``"causal"``
-or ``BlockDiffusion(L, b)``): from it each kernel derives, a tile, the one or
-two ranges of chunks that hold a live pair (``_live_keys``, ``_live_queries``)
-and walks them in the same one loop (``_walk``); a live chunk under block
-diffusion builds its mask from two compares of block indices. Keys and values
+need not be equal. The mask is a static description (``None``, ``"causal"``,
+``CausalWindow(size)`` or ``BlockDiffusion(L, b)``): from it each kernel
+derives, a tile, the one or two ranges of chunks that hold a live pair
+(``_live_keys``, ``_live_queries``) and walks them in the same one loop
+(``_walk``); a live chunk under block diffusion builds its mask from two
+compares of block indices. Under a causal window the range of chunks is cut
+on both sides of the band: it starts at the chunk the tile's first row still
+reaches back to and ends at the diagonal (by keys: from the diagonal to the
+last row that still reaches the tile's keys), a span wholly outside the band
+is clamped onto a live one and copies nothing, and a live chunk builds its
+mask from two compares of ``_lead``. At T 16,384, a window of 4,096 and
+512-row tiles that is 252 tiles a head against the causal mask's 528. Keys and values
 may hold fewer heads than the queries: the index maps read a group's shared
 head, and the dk/dv kernel's last grid axis walks the group's query heads.
 The running (m, l, acc) state lives in VMEM scratch across
@@ -100,13 +107,22 @@ class BlockDiffusion(NamedTuple):
     block: int
 
 
+class CausalWindow(NamedTuple):
+    """Causal attention within a sliding window: query ``i`` sees key ``j``
+    iff ``0 <= i - j < size``, the ``size`` newest keys with its own. A window
+    no shorter than the axis is the causal mask. Static and hashable: the
+    kernels derive the band's chunks and a chunk's mask from the one number."""
+    size: int
+
+
 def _as_mask(causal, mask=None):
     """The one static description the kernels take: ``None`` (every key),
-    ``"causal"`` or a :class:`BlockDiffusion`. ``causal`` is the older
-    boolean spelling (a description is passed through)."""
+    ``"causal"``, a :class:`CausalWindow` or a :class:`BlockDiffusion`.
+    ``causal`` is the older boolean spelling (a description is passed
+    through)."""
     if mask is not None:
         return mask
-    if isinstance(causal, (BlockDiffusion, str)):
+    if isinstance(causal, (BlockDiffusion, CausalWindow, str)):
         return causal
     return "causal" if causal else None
 
@@ -119,6 +135,9 @@ def dense_mask(mask, t: int):
     if mask == "causal":
         return jnp.tril(jnp.ones((t, t), bool))
     pos = jnp.arange(t)
+    if isinstance(mask, CausalWindow):
+        lead = pos[:, None] - pos[None, :]
+        return (lead >= 0) & (lead < mask.size)
     noised = pos < mask.length
     blk = (pos % mask.length) // mask.block
     qn, kn, qb, kb = noised[:, None], noised[None, :], blk[:, None], blk[None, :]
@@ -233,7 +252,8 @@ def _lanes(x, n):
 def _live_keys(mask, row0, rows, chunk, n_chunks):
     """The chunks of keys that some query of ``[row0, row0 + rows)`` sees:
     one or two ``(lo, hi)`` for ``_walk``. Causal: the chunks up to the one
-    the last row reaches. Block diffusion: the noised keys of the rows' own
+    the last row reaches; under a window, from the chunk that holds the
+    oldest key the first row sees. Block diffusion: the noised keys of the rows' own
     blocks, then the clean keys from the axis' middle up to the last block a
     row sees (two ranges, the second cut where a tile straddles both)."""
     from jax.experimental import pallas as pl
@@ -242,6 +262,9 @@ def _live_keys(mask, row0, rows, chunk, n_chunks):
         return [(0, n_chunks)]
     if mask == "causal":
         return [(0, pl.cdiv(row0 + rows, chunk))]
+    if isinstance(mask, CausalWindow):
+        return [(jnp.maximum(row0 - mask.size + 1, 0) // chunk,
+                 pl.cdiv(row0 + rows, chunk))]
     length, b = mask
     # the last noised row's block: its clean keys stop before it, a clean
     # row's after it
@@ -259,7 +282,8 @@ def _live_keys(mask, row0, rows, chunk, n_chunks):
 
 def _live_queries(mask, col0, cols, chunk, n_chunks):
     """The chunks of queries that see some key of ``[col0, col0 + cols)``.
-    Causal: from the chunk the first key reaches to the end. Block
+    Causal: from the chunk the first key reaches to the end; under a window,
+    to the chunk of the last row that still sees the last key. Block
     diffusion: a noised key is seen by its own block's noised rows; a clean
     key by the noised rows of later blocks and the clean rows from its own
     block on."""
@@ -269,6 +293,9 @@ def _live_queries(mask, col0, cols, chunk, n_chunks):
         return [(0, n_chunks)]
     if mask == "causal":
         return [(col0 // chunk, n_chunks)]
+    if isinstance(mask, CausalWindow):
+        return [(col0 // chunk, jnp.minimum(
+            pl.cdiv(col0 + cols + mask.size - 1, chunk), n_chunks))]
     length, b = mask
     has_noised, has_clean = col0 < length, col0 + cols > length
     first_clean = (jnp.maximum(col0, length) - length) // b
@@ -285,6 +312,25 @@ def _live_queries(mask, col0, cols, chunk, n_chunks):
     lo2 = jnp.where(has_clean, (length + first_clean * b) // chunk, n_chunks)
     lo2 = jnp.maximum(lo2, hi1)
     return [(lo1, hi1), (lo2, jnp.maximum(n_chunks, lo2))]
+
+
+# A dead score under a causal window: finite, where the other masks write
+# -inf (``_banded``).
+_DEAD = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def _banded(s, lead, diag, size: int):
+    """A chunk's scores under a causal window of ``size`` keys. ``lead`` is
+    the key's index in the tile minus the query's (``_lead``) and ``diag`` the
+    tile's first row minus its first column: the key is not after the query
+    iff ``lead <= diag`` and within its window iff ``lead > diag - size``. The
+    dead scores get a finite value: a tile's later rows see no key of the
+    first chunks the tile walks (those are there for its first rows), and a
+    row's running maximum has to stay finite for ``exp(m_prev - m_new)``. What
+    such a row gathers before its first live key (every ``p`` reads 1) that
+    factor wipes, as exactly 0, when the key comes; in the backward kernels
+    ``exp(_DEAD - lse)`` is 0."""
+    return jnp.where((lead <= diag) & (lead > diag - size), s, _DEAD)
 
 
 _NOISED = 1 << 30       # added to a noised position's block index
@@ -338,10 +384,15 @@ def _kv_map(block_q, span, mask, group):
     """Index map of the keys' and values' resident span under a grid of
     (query heads, query blocks, spans): query head ``b`` reads key/value head
     ``b // group``. Under the causal mask a span above the diagonal names the
-    last live one, which is already resident: no copy."""
+    last live one, which is already resident: no copy. Under a causal window
+    a span before the band names the first live one likewise."""
+    windowed = isinstance(mask, CausalWindow)
+
     def index(b, i, s):
-        if mask == "causal":
+        if mask == "causal" or windowed:
             s = jnp.minimum(s, (i * block_q + block_q - 1) // span)
+        if windowed:
+            s = jnp.maximum(s, jnp.maximum(i * block_q - mask.size + 1, 0) // span)
         return (b if group == 1 else b // group, s, 0)
     return index
 
@@ -363,6 +414,7 @@ def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
     group = bh // k3.shape[0]
     block_q, block_k, span = tiles
     diffusion = isinstance(mask, BlockDiffusion)
+    windowed = isinstance(mask, CausalWindow)
     scale = 1.0 / (d ** 0.5)
     n_span, per_span = t // span, span // block_k
     # the running max and sum keep a row's value in every lane of a register
@@ -392,13 +444,16 @@ def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
             col0 = (s_idx * per_span + j) * block_k
             if mask == "causal":
                 s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
+            elif windowed:
+                s = _banded(s, _lead(s.shape, 1, 0), row0 - col0, mask.size)
             elif diffusion:
                 s = _block_diffusion(s, query_keys, _key_value(
                     mask, _positions(block_k, 1, col0)))
             # every row has a live key in the first chunk it meets (the axis'
             # first under the causal mask, its own block's under block
-            # diffusion), so from the first step on m is finite and no
-            # exponent reads inf - inf
+            # diffusion) or reads a finite score there (a causal window's
+            # dead scores, `_banded`), so from the first step on m is finite
+            # and no exponent reads inf - inf
             m_prev = m_scr[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -459,6 +514,7 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, mask,
     group = bh // k3.shape[0]
     block_q, block_k, span = tiles
     diffusion = isinstance(mask, BlockDiffusion)
+    windowed = isinstance(mask, CausalWindow)
     scale = 1.0 / (d ** 0.5)
     n_span, per_span = t // span, span // block_k
 
@@ -485,6 +541,8 @@ def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, mask,
             col0 = (s_idx * per_span + j) * block_k
             if mask == "causal":
                 s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
+            elif windowed:
+                s = _banded(s, _lead(s.shape, 1, 0), row0 - col0, mask.size)
             elif diffusion:
                 s = _block_diffusion(s, query_keys, _key_value(
                     mask, _positions(block_k, 1, col0)))
@@ -543,6 +601,7 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
     group = bh // bkv
     block_k, block_q, span = tiles
     diffusion = isinstance(mask, BlockDiffusion)
+    windowed = isinstance(mask, CausalWindow)
     scale = 1.0 / (d ** 0.5)
     n_span, per_span = t // span, span // block_q
 
@@ -576,6 +635,8 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
             if mask == "causal":
                 s_t = jnp.where(_lead(s_t.shape, 0, 1) <= row0 - col0,
                                 s_t, -jnp.inf)
+            elif windowed:
+                s_t = _banded(s_t, _lead(s_t.shape, 0, 1), row0 - col0, mask.size)
             elif diffusion:
                 s_t = _block_diffusion(s_t, _query_keys(
                     mask, _positions(block_q, 1, row0)), key_value)
@@ -591,7 +652,8 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
                 preferred_element_type=jnp.float32)
 
         # under a mask the loop walks the query chunks that see the block's
-        # keys: from the diagonal on, or the blocks' own and later rows
+        # keys: from the diagonal on (to the band's end under a window), or
+        # the blocks' own and later rows
         _walk(_live_queries(mask, col0, block_k, block_q, n_span * per_span),
               s_idx, per_span, n_span, step)
 
@@ -602,9 +664,12 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
 
     def q_map(b, j, s):
         head, s = split(s)
-        if mask == "causal":
+        if mask == "causal" or windowed:
             # a span of queries above the diagonal names the first live one
             s = jnp.maximum(s, (j * block_k) // span)
+        if windowed:
+            # and one past the last row that reaches the block's keys the last
+            s = jnp.minimum(s, (j * block_k + block_k + mask.size - 2) // span)
         return (b if group == 1 else b * group + head, s, 0)
 
     def row_map(b, j, s):
@@ -695,7 +760,7 @@ def flash_attention(q, k, v, causal: bool = False,
     maps do that, nothing is repeated in memory).
 
     ``mask``: ``None`` for what ``causal`` says, or a static description:
-    ``"causal"`` or :class:`BlockDiffusion`. ``force_pallas``: None = pallas on
+    ``"causal"``, :class:`CausalWindow` or :class:`BlockDiffusion`. ``force_pallas``: None = pallas on
     TPU, reference jnp elsewhere; True = pallas (interpreted off-TPU — tests);
     False = reference. Whatever the setting, a ``T`` that ``_pick_block``
     cannot tile runs the reference.

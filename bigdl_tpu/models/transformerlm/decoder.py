@@ -6,17 +6,37 @@ algebra, one kind of layer. ``ConfigDecoder`` is built from the keys a model's
 ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
 ``moe_intermediate_size``, ``num_experts``, ``num_experts_per_tok``,
 ``vocab_size``, ...) and runs its layers as ONE ``lax.scan`` body over
-stacked weights: the step program traces and compiles one layer whatever the
-depth. A layer is
+stacked weights: the step program traces and compiles one period of the
+layer pattern whatever the depth. A layer is
 
     h = h + Attention(RMSNorm(h), positions)      nn.MultiHeadAttention
     h = h + Experts(RMSNorm(h))                   parallel.MoE, router="topk"
 
 with grouped-query heads of their own ``head_dim``, per-head RMSNorm on
-queries and keys, RoPE by position ids, and the routed SiLU-gated expert
-layer, which is told which experts it holds (``held``). The two modules are
-the layer's templates: the scan body calls their ``apply`` on a layer's
-slice of the stacked parameters. ``remat`` rematerialises the layers: the
+queries and keys (``qk_norm``), RoPE by position ids, and the routed gated
+expert layer (``expert_gate``: ``"silu"`` or ``"relu"``), which is told which
+experts it holds (``held``). With ``router_input="layer"`` the router reads
+the layer's input ``h`` as it enters, before the first RMSNorm (a router that
+stands before attention), where by default it reads what the experts read.
+
+Layers may be of several kinds (``LayerKind``): ``sliding_window_layout`` and
+``rope_layout``, a config's lists of one number a layer, say which layers see
+a causal window of ``sliding_window_size`` keys (1) or every key (0), and
+which turn queries and keys by RoPE (1) or carry no positions at all (0).
+The lists may be longer than the depth (a config cut in depth keeps its
+published lists); the first ``num_hidden_layers`` entries count. The shortest
+period of the pattern is written out inside the scan body, one attention
+template a kind with its own static mask and its RoPE or none, and the scan
+runs over the periods: no layer computes two kinds and selects. The stacked
+weights keep the depth in front (all kinds hold the same shapes) and are cut
+into periods where the scan takes them. A pattern of one kind is a period of
+one: the scan body is then the one layer and takes the stacked weights as
+they are.
+
+The modules are the layer's templates: the scan body calls their ``apply`` on
+a layer's slice of the stacked parameters, attention under the scope
+``bigdl_attn_window`` or ``bigdl_attn_full`` by kind
+(``obs/trace.py``). ``remat`` rematerialises the layers, each on its own: the
 scan keeps, a layer, its input and what is dear to make again (``KEPT``: the
 flash kernels' ``q``, ``k``, ``v``, ``out`` and logsumexp, the routing, the
 hidden state after attention), ``N * T * ((2 * heads + 2 * kv_heads) *
@@ -31,7 +51,8 @@ and the sort do not. ``remat=False`` keeps every value of every layer.
 arXiv:2503.09573): the input is ``[x_t ; x_0]``, a noised copy of a sequence of
 ``L`` tokens and the clean sequence, ``2L`` positions with position ids
 ``[0..L-1 ; 0..L-1]`` under ``kernels.flash_attention.BlockDiffusion(L, b)``;
-only the noised half reaches the head. Without it the decoder is causal.
+only the noised half reaches the head. Without it the decoder is causal
+(every layer, or within its window). Block diffusion takes no windowed layer.
 
 In training the output is ``Table(hidden, head weight)`` for a criterion that
 streams the vocabulary (``nn/fused_loss.py``): ``WeightedTokenCriterion``
@@ -41,7 +62,7 @@ In evaluation it is the logits.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +73,7 @@ from bigdl_tpu.kernels.flash_attention import RESIDUAL_NAMES, BlockDiffusion
 from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.criterion import AbstractCriterion
 from bigdl_tpu.nn.fused_loss import chunked_softmax_xent
+from bigdl_tpu.obs import trace
 from bigdl_tpu.parallel.moe import ROUTING_NAMES, MoE
 from bigdl_tpu.utils.random_generator import RandomGenerator
 from bigdl_tpu.utils.table import Table
@@ -69,6 +91,22 @@ _HEALTH = {"aux_loss": jnp.sum, "router_z_loss": jnp.mean,
            "pairs_held": jnp.sum, "row_passes": jnp.max}
 
 
+class LayerKind(NamedTuple):
+    """What tells one kind of layer from another: the keys its attention
+    sees (``window`` newest, or every one the decoder's mask allows for
+    ``None``) and whether queries and keys are turned by RoPE."""
+    window: Optional[int]
+    rope: bool
+
+
+def _period(kinds: list) -> list:
+    """The shortest leading run of ``kinds`` that, repeated, gives them all."""
+    n = len(kinds)
+    for p in range(1, n + 1):
+        if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n)):
+            return kinds[:p]
+
+
 class ConfigDecoder(TensorModule):
     """Token ids ``(N, T)`` int32 → ``Table(hidden, head)`` in training, logits
     in evaluation; see the module docstring. ``num_experts`` is the router's
@@ -78,7 +116,12 @@ class ConfigDecoder(TensorModule):
                    "num_attention_heads", "num_key_value_heads", "head_dim",
                    "moe_intermediate_size", "num_experts",
                    "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps",
-                   "rope_theta", "initializer_range")
+                   "rope_theta", "initializer_range", "sliding_window_layout",
+                   "sliding_window_size", "rope_layout")
+    #: other spellings of those keys that published configs use
+    CONFIG_ALIASES = {"moe_ffn_hidden_size": "moe_intermediate_size",
+                      "moe_num_primary_experts": "num_experts",
+                      "moe_num_active_primary_experts": "num_experts_per_tok"}
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  num_hidden_layers: int, num_attention_heads: int,
@@ -88,39 +131,64 @@ class ConfigDecoder(TensorModule):
                  rms_norm_eps: float = 1e-6, rope_theta: float = 10000.0,
                  initializer_range: float = 0.02,
                  held: Optional[tuple] = None, qk_norm: bool = True,
-                 block_diffusion: Optional[tuple] = None, remat: bool = True):
+                 block_diffusion: Optional[tuple] = None, remat: bool = True,
+                 sliding_window_layout: Optional[Sequence[int]] = None,
+                 sliding_window_size: Optional[int] = None,
+                 rope_layout: Optional[Sequence[int]] = None,
+                 router_input: str = "experts", expert_gate: str = "silu"):
         super().__init__()
         self.vocab_size, self.hidden_size = int(vocab_size), int(hidden_size)
-        self.num_hidden_layers = int(num_hidden_layers)
+        self.num_hidden_layers = n = int(num_hidden_layers)
         self.initializer_range = float(initializer_range)
         self.remat = bool(remat)
         self.block_diffusion = block_diffusion and tuple(block_diffusion)
+        if router_input not in ("experts", "layer"):
+            raise ValueError(f"router_input must be 'experts' or 'layer', got "
+                             f"{router_input!r}")
+        self.router_input = router_input
+        windowed = [0] * n if sliding_window_layout is None else \
+            list(sliding_window_layout)[:n]
+        turned = [1] * n if rope_layout is None else list(rope_layout)[:n]
+        if len(windowed) != n or len(turned) != n:
+            raise ValueError(f"a layout shorter than the {n} layers")
+        if any(windowed) and sliding_window_size is None:
+            raise ValueError("sliding_window_layout names windowed layers and "
+                             "sliding_window_size is not given")
+        #: the layer pattern's shortest period, a ``LayerKind`` a layer
+        self.period = _period([
+            LayerKind(int(sliding_window_size) if w else None, bool(r))
+            for w, r in zip(windowed, turned)])
         mask = BlockDiffusion(*self.block_diffusion) if self.block_diffusion else None
         self.norm = nn.RMSNorm(hidden_size, eps=rms_norm_eps)
-        self.attention = nn.MultiHeadAttention(
+        #: one attention template a layer of the period
+        self.attentions = [nn.MultiHeadAttention(
             hidden_size, num_attention_heads, causal=mask is None,
-            with_bias=False, num_kv_heads=num_key_value_heads, rope=True,
-            rope_base=rope_theta, head_dim=head_dim, qk_norm=qk_norm,
-            qk_norm_eps=rms_norm_eps, mask=mask)
+            with_bias=False, num_kv_heads=num_key_value_heads, rope=kind.rope,
+            rope_base=rope_theta, window=kind.window, head_dim=head_dim,
+            qk_norm=qk_norm, qk_norm_eps=rms_norm_eps, mask=mask)
+            for kind in self.period]
         self.experts = MoE(hidden_size, moe_intermediate_size, num_experts,
                            router="topk", top_k=num_experts_per_tok,
-                           norm_topk_prob=norm_topk_prob, held=held)
+                           norm_topk_prob=norm_topk_prob, held=held,
+                           gate=expert_gate)
         # the templates lend their shapes; their own copies are never read
         self._layer_shapes = jax.tree_util.tree_map(lambda a: a.shape, {
-            "attn": self.attention.get_params(),
+            "attn": self.attentions[0].get_params(),
             "attn_norm": self.norm.get_params()["weight"],
             "moe": self.experts.get_params(),
             "moe_norm": self.norm.get_params()["weight"]})
-        for template in (self.attention, self.experts, self.norm):
+        for template in (*self.attentions, self.experts, self.norm):
             template._params, template._grads = {}, {}
         self.reset()
 
     @classmethod
     def from_config(cls, config: dict, **more) -> "ConfigDecoder":
-        """From a ``config.json``'s keys (those of ``CONFIG_KEYS`` it has);
-        ``more`` overrides them and gives what a config has no key for."""
-        return cls(**{**{k: config[k] for k in cls.CONFIG_KEYS if k in config},
-                      **more})
+        """From a ``config.json``'s keys (those of ``CONFIG_KEYS`` it has, or
+        of ``CONFIG_ALIASES`` in their place); ``more`` overrides them and
+        gives what a config has no key for."""
+        keys = {**{v: k for k, v in cls.CONFIG_ALIASES.items() if k in config},
+                **{k: k for k in cls.CONFIG_KEYS if k in config}}
+        return cls(**{**{k: config[named] for k, named in keys.items()}, **more})
 
     def reset(self) -> None:
         """Matrices N(0, ``initializer_range``), gains 1, drawn on the device
@@ -174,20 +242,48 @@ class ConfigDecoder(TensorModule):
         positions = self._positions(input.shape[1])
         norm, experts_state = self.norm, self.experts.get_state()
 
-        def layer(h, p):
-            a, _ = norm.apply({"weight": p["attn_norm"]}, {}, h)
-            a, _ = self.attention.apply(p["attn"], {}, (a, positions),
-                                        training=training)
-            h = checkpoint_name(h + a, "decoder_after_attention")
-            m, _ = norm.apply({"weight": p["moe_norm"]}, {}, h)
-            m, health = self.experts.apply(p["moe"], experts_state, m,
-                                           training=training)
-            return h + m, {k: health[k] for k in _HEALTH}
+        def layer_of(attention):
+            scope = trace.SCOPE_ATTN_FULL if attention.window is None \
+                else trace.SCOPE_ATTN_WINDOW
 
-        if self.remat:
-            layer = jax.checkpoint(
-                layer, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
-        h, health = jax.lax.scan(layer, h, params["layers"])
+            def layer(h, p):
+                a, _ = norm.apply({"weight": p["attn_norm"]}, {}, h)
+                with jax.named_scope(scope):
+                    a, _ = attention.apply(p["attn"], {}, (a, positions),
+                                           training=training)
+                after = checkpoint_name(h + a, "decoder_after_attention")
+                m, _ = norm.apply({"weight": p["moe_norm"]}, {}, after)
+                m, health = self.experts.apply(
+                    p["moe"], experts_state,
+                    (m, h) if self.router_input == "layer" else m,
+                    training=training)
+                return after + m, {k: health[k] for k in _HEALTH}
+
+            if self.remat:
+                return jax.checkpoint(layer, policy=jax.checkpoint_policies
+                                      .save_only_these_names(*KEPT))
+            return layer
+
+        layers = [layer_of(attention) for attention in self.attentions]
+        if len(layers) == 1:
+            body, stacked = layers[0], params["layers"]
+        else:
+            # the period written out: each of its layers on its own slice of
+            # the period's weights, the health leaves a row a layer
+            stacked = jax.tree_util.tree_map(
+                lambda a: a.reshape((-1, len(layers)) + a.shape[1:]),
+                params["layers"])
+
+            def body(h, period):
+                health = []
+                for i, layer in enumerate(layers):
+                    h, leaves = layer(h, jax.tree_util.tree_map(
+                        lambda a: a[i], period))
+                    health.append(leaves)
+                return h, {k: jnp.stack([leaves[k] for leaves in health])
+                           for k in _HEALTH}
+
+        h, health = jax.lax.scan(body, h, stacked)
         if self.block_diffusion:
             h = h[:, :self.block_diffusion[0]]      # the noised half predicts
         h, _ = norm.apply({"weight": params["final_norm"]}, {}, h)
@@ -198,8 +294,9 @@ class ConfigDecoder(TensorModule):
         return h @ params["head"].T, new_state
 
     def __repr__(self):
-        return (f"ConfigDecoder({self.num_hidden_layers} layers, "
-                f"hidden={self.hidden_size}, {self.attention!r}, "
+        kinds = "" if len(self.period) == 1 else f" in periods of {list(self.period)}"
+        return (f"ConfigDecoder({self.num_hidden_layers} layers{kinds}, "
+                f"hidden={self.hidden_size}, {self.attentions[0]!r}, "
                 f"{self.experts!r})")
 
 

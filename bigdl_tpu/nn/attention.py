@@ -55,7 +55,11 @@ class MultiHeadAttention(TensorModule):
     (32 heads of 128 over a width of 2048). ``qk_norm`` puts an RMSNorm with
     a gain of its own over each head's query and key before RoPE. ``mask`` is
     a static description the flash kernels take in ``causal``'s place
-    (``kernels.flash_attention.BlockDiffusion``). The input may be
+    (``kernels.flash_attention.BlockDiffusion``). ``window`` (with
+    ``causal``) is sliding-window attention, a position sees the ``window``
+    newest keys with its own: where the flash kernels are taken it reaches
+    them as the description ``CausalWindow(window)`` and they walk the band's
+    tiles alone; the ``"full"`` path writes the same mask out. The input may be
     ``(x, positions)``: RoPE then turns by those position ids, ``(t,)`` or
     ``(b, t)``, which may repeat (a sequence and its noised copy), in place
     of ``0 .. t-1``. On the flash path keys and values reach the kernel at
@@ -124,8 +128,8 @@ class MultiHeadAttention(TensorModule):
         # sliding-window attention (Mistral-style): each position attends to
         # the last `window` positions only — O(T·W) scores and a W-bounded
         # decode cache REACH (the cache itself stays max_len; the mask bounds
-        # what the softmax sees). Served by the masked fused path; the flash
-        # kernel's banded tile-skip is a future fast path.
+        # what the softmax sees). The flash kernels take it as a static mask
+        # description and skip the tiles outside the band (`_attend`).
         self.window = None if window is None else int(window)
         if lora_rank is not None and int(lora_rank) < 1:
             raise ValueError(f"lora_rank must be >= 1, got {lora_rank!r}")
@@ -262,21 +266,28 @@ class MultiHeadAttention(TensorModule):
         group's shared head through their index maps; the other paths get
         them widened."""
         from bigdl_tpu.parallel.ring_attention import full_attention, ring_attention
-        mask = getattr(self, "mask", None)
+        mask, window = getattr(self, "mask", None), getattr(self, "window", None)
+        if window is not None:
+            # the constructor refuses a window beside a mask, and under 'ring'
+            from bigdl_tpu.kernels.flash_attention import CausalWindow
+            mask = CausalWindow(window)
 
         def flash():
             from bigdl_tpu.kernels.flash_attention import flash_attention
             return flash_attention(q, k, v, self.causal, None, mask)
 
-        if self.attention_impl == "flash":
-            return flash()
-        if self.attention_impl == "full":
+        def full():
             if mask is None:
                 return full_attention(q, self._expand_kv(k), self._expand_kv(v),
                                       causal=self.causal)
             from bigdl_tpu.kernels.flash_attention import dense_mask
             return full_attention(q, self._expand_kv(k), self._expand_kv(v),
                                   kv_mask=dense_mask(mask, q.shape[2])[None, None])
+
+        if self.attention_impl == "flash":
+            return flash()
+        if self.attention_impl == "full":
+            return full()
         from bigdl_tpu.utils.engine import Engine
         mesh = Engine.mesh() if Engine.is_initialized() else None
         if mesh is None or Engine.SEQ_AXIS not in mesh.axis_names:
@@ -287,6 +298,8 @@ class MultiHeadAttention(TensorModule):
             # single chip: the flash kernel engages on TPU and degrades to the
             # plain fused attention elsewhere (kernels/flash_attention.py)
             return flash()
+        if window is not None:
+            return full()       # no banded ring attention: the mask written out
         if mask is not None:
             raise ValueError("ring attention takes no mask but causal")
         return ring_attention(q, self._expand_kv(k), self._expand_kv(v),
@@ -339,16 +352,7 @@ class MultiHeadAttention(TensorModule):
             pos = jnp.arange(t) if positions is None else positions
             q = rope_rotate(q, pos, self.rope_base)
             k = rope_rotate(k, pos, self.rope_base)
-        if getattr(self, "window", None) is not None:
-            # masked single-device path (constructor rejects 'ring'+window);
-            # one fused band mask, mirroring _decode_step's composition
-            from bigdl_tpu.parallel.ring_attention import full_attention
-            diff = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
-            band = (diff >= 0) & (diff < self.window)
-            o = full_attention(q, self._expand_kv(k), self._expand_kv(v),
-                               causal=False, kv_mask=band[None, None])
-        else:
-            o = self._attend(q, k, v)
+        o = self._attend(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, e)
         out = o @ self._w(params, "out_weight").T
         if self.with_bias:

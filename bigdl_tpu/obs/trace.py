@@ -54,6 +54,12 @@ SCOPE_MOE = "bigdl_moe"
 SCOPE_MOE_ROUTE = "bigdl_moe_route"
 SCOPE_MOE_EXPERTS = "bigdl_moe_experts"
 SCOPE_MOE_COMBINE = "bigdl_moe_combine"
+#: and of ``ConfigDecoder``'s attention by the layer's kind, around the whole
+#: module (projections, RoPE where the kind has it, the kernels, the output
+#: projection): a layer that sees every key the mask allows, and one that
+#: sees a sliding window of them
+SCOPE_ATTN_FULL = "bigdl_attn_full"
+SCOPE_ATTN_WINDOW = "bigdl_attn_window"
 
 
 class SpanRecord(NamedTuple):

@@ -13,9 +13,12 @@ layer, by the router:
   same TPRules machinery as tensor parallelism (``expert_parallel_rules``),
   and XLA inserts the token all-to-all implied by the dispatch einsums.
 - ``topk``: the routed layer of today's open models (softmax over all
-  experts, the ``top_k`` largest, their weights renormalised, SiLU-gated
-  experts without biases), dropless, and told which experts it holds:
-  ``held=(first, count)``. The (token, expert) pairs are sorted by expert, the
+  experts, the ``top_k`` largest, their weights renormalised, gated experts
+  without biases, the gate's activation ``gate="silu"`` or ``"relu"``),
+  dropless, and told which experts it holds: ``held=(first, count)``. The
+  router may read another tensor than the experts do: the input ``(x, r)``
+  routes on ``r`` (a decoder whose router stands before attention hands it
+  the layer's input) and feeds the experts ``x``. The (token, expert) pairs are sorted by expert, the
   rows gathered, one grouped product for gate and up and one for down run over
   the held groups (``kernels/grouped_matmul.py``), and the weighted rows are
   summed back per token. The layer returns the held experts' part of the sum.
@@ -129,13 +132,18 @@ def _sum_by_token(rows, token_of, tokens: int, weight=None):
     return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token_of].add(part)
 
 
-def _experts(xs, w_in, w_out, sizes, hidden: int):
-    """Gate and up in one grouped product, the SiLU gate, down in another."""
+#: The gate's activation by the name ``MoE(gate=)`` takes.
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _experts(xs, w_in, w_out, sizes, hidden: int, gate: str):
+    """Gate and up in one grouped product, the gate (``GATES[gate]`` of the
+    first half times the second), down in another."""
     from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
 
     with jax.named_scope(trace.SCOPE_MOE_EXPERTS):
         h = grouped_matmul(xs, w_in, sizes)
-        act = jax.nn.silu(h[:, :hidden]) * h[:, hidden:]
+        act = GATES[gate](h[:, :hidden]) * h[:, hidden:]
         return grouped_matmul(act, w_out, sizes)
 
 
@@ -155,23 +163,24 @@ def _over_passes(one_pass, passes, rows: int, total: int):
     return jax.lax.fori_loop(1, passes, more, first)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _routed(x, w_in, w_out, weight, order, sizes, passes, rows, hidden):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _routed(x, w_in, w_out, weight, order, sizes, passes, rows, hidden, gate):
     """The held experts over the tokens they were routed: (tokens, D) in fp32,
     ``sum_k weight[t, k] * expert(x[t])`` over a token's held pairs. ``order``
     is the pairs sorted by held expert (padded to whole passes), ``sizes`` the
     pairs of each, ``passes`` how many windows of ``rows`` sorted positions
     hold them all. Hand-written VJP: forward and backward each repeat the
     bounded pass, so nothing sized for the worst case exists in either."""
-    return _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden)[0]
+    return _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden,
+                       gate)[0]
 
 
-def _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden):
+def _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden, gate):
     tokens, k = weight.shape
 
     def one_pass(start):
         _, _, token_of, xs, by_row, padded = _window(x, weight, order, sizes, start, rows)
-        out = _experts(xs, w_in, w_out, padded, hidden)
+        out = _experts(xs, w_in, w_out, padded, hidden, gate)
         with jax.named_scope(trace.SCOPE_MOE_COMBINE):
             return _sum_by_token(out, token_of, tokens, by_row)
 
@@ -179,7 +188,7 @@ def _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden):
     return y, (x, w_in, w_out, weight, order, sizes, passes)
 
 
-def _routed_bwd(rows, hidden, res, g):
+def _routed_bwd(rows, hidden, gate, res, g):
     x, w_in, w_out, weight, order, sizes, passes = res
     tokens, k = weight.shape
     # in the rows' own type before the gather: nothing of (rows, D) in fp32
@@ -189,7 +198,7 @@ def _routed_bwd(rows, hidden, res, g):
         pair, live, token_of, xs, by_row, padded = _window(
             x, weight, order, sizes, start, rows)
         out, pull = jax.vjp(
-            lambda a, b, c: _experts(a, b, c, padded, hidden), xs, w_in, w_out)
+            lambda a, b, c: _experts(a, b, c, padded, hidden, gate), xs, w_in, w_out)
         with jax.named_scope(trace.SCOPE_MOE_COMBINE):
             g_rows = g[token_of]
             d_by_row = jnp.where(live, jnp.sum(
@@ -241,9 +250,11 @@ class MoE(TensorModule):
     Scalars among these are auto-logged to TrainSummary/TB by the training
     loop (``Optimizer.OBSERVABLE_STATE_LEAVES``).
 
-    ``router="topk"`` (module docstring) takes ``top_k``, ``norm_topk_prob``
-    and ``held=(first, count)`` (default: all experts) and ignores
-    ``capacity_factor``: nothing is dropped. Its parameters are the router
+    ``router="topk"`` (module docstring) takes ``top_k``, ``norm_topk_prob``,
+    ``held=(first, count)`` (default: all experts) and ``gate`` (``"silu"`` or
+    ``"relu"``: the activation of an expert's gate) and ignores
+    ``capacity_factor``: nothing is dropped. Its input may be ``(x, r)``: the
+    router then reads ``r`` and the experts ``x``, both (N, D) or (N, T, D). Its parameters are the router
     ``w_gate`` (D, E) over all experts, ``w_in`` (count, D, 2H) holding each
     held expert's gate and up matrices side by side and ``w_out`` (count, H, D).
     Its state adds ``pairs_held`` (the (token, expert) pairs that reached a held
@@ -259,7 +270,7 @@ class MoE(TensorModule):
                  z_loss_weight: float = 0.0,
                  w_init: Optional[InitializationMethod] = None,
                  top_k: Optional[int] = None, norm_topk_prob: bool = True,
-                 held: Optional[tuple] = None):
+                 held: Optional[tuple] = None, gate: str = "silu"):
         super().__init__()
         if router not in ("top1", "top2", "expert_choice", "topk"):
             raise ValueError(f"router must be 'top1', 'top2', "
@@ -275,8 +286,10 @@ class MoE(TensorModule):
                     or held[0] + held[1] > n_experts:
                 raise ValueError(f"held must be (first, count) within the "
                                  f"{n_experts} experts, got {held!r}")
-        elif top_k is not None or held is not None:
-            raise ValueError("top_k and held belong to router='topk'")
+            if gate not in GATES:
+                raise ValueError(f"gate must be one of {sorted(GATES)}, got {gate!r}")
+        elif top_k is not None or held is not None or gate != "silu":
+            raise ValueError("top_k, held and gate belong to router='topk'")
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -286,6 +299,7 @@ class MoE(TensorModule):
         self.top_k = None if top_k is None else int(top_k)
         self.norm_topk_prob = bool(norm_topk_prob)
         self.held = held
+        self.gate = gate
         self.z_loss_weight = float(z_loss_weight)
         self.w_init = w_init or RandomNormal(0.0, 0.02)
         self.reset()
@@ -390,9 +404,9 @@ class MoE(TensorModule):
             y = y.reshape(n, t, d)
         return y, new_state
 
-    def _apply_topk(self, params, state, x):
-        """The dropless routed layer over (tokens, D): see the module
-        docstring. Scopes: ``bigdl_moe_route`` (router, top-k, the sort, and
+    def _apply_topk(self, params, state, x, routed_on):
+        """The dropless routed layer over (tokens, D), the router reading
+        ``routed_on`` (tokens, D): see the module docstring. Scopes: ``bigdl_moe_route`` (router, top-k, the sort, and
         in each pass the gather and its gradient's sum by token),
         ``bigdl_moe_experts`` (the two grouped products and the gate between
         them), ``bigdl_moe_combine`` (the weighted sum back). The routing is
@@ -409,7 +423,7 @@ class MoE(TensorModule):
         total = tokens * k
         rows = _held_rows(total, count, self.n_experts)
         with jax.named_scope(trace.SCOPE_MOE_ROUTE):
-            logits = jnp.dot(x, params["w_gate"].astype(x.dtype),
+            logits = jnp.dot(routed_on, params["w_gate"].astype(routed_on.dtype),
                              preferred_element_type=jnp.float32)
             probs = jax.nn.softmax(logits, axis=-1)
             top_p, top_e = _top_k(probs, k, self.n_experts)         # (T, k)
@@ -431,7 +445,7 @@ class MoE(TensorModule):
             passes = checkpoint_name(passes, "moe_passes")
         y = _routed(x, params["w_in"].astype(x.dtype),
                     params["w_out"].astype(x.dtype), top_p, order, sizes,
-                    passes, rows, self.hidden_size)
+                    passes, rows, self.hidden_size, getattr(self, "gate", "silu"))
 
         new_state = dict(state)
         share = jax.lax.stop_gradient(jnp.mean(
@@ -454,14 +468,24 @@ class MoE(TensorModule):
         return y.astype(x.dtype), new_state
 
     def apply(self, params, state, input, *, training=False, rng=None):
+        routed_on = None
+        if isinstance(input, (tuple, list)):
+            if self.router != "topk":
+                raise ValueError("a router's input apart from the experts' "
+                                 "belongs to router='topk'")
+            input, routed_on = input
+            if routed_on.shape != input.shape:
+                raise ValueError(f"the router reads {routed_on.shape}, the "
+                                 f"experts {input.shape}")
         x = input
         flat = x.ndim == 3
         if flat:
             n, t, d = x.shape
             x = x.reshape(n * t, d)
         if self.router == "topk":
+            routed_on = x if routed_on is None else routed_on.reshape(x.shape)
             with jax.named_scope(trace.SCOPE_MOE):
-                y, new_state = self._apply_topk(params, state, x)
+                y, new_state = self._apply_topk(params, state, x, routed_on)
             return (y.reshape(n, t, d) if flat else y), new_state
         tokens = x.shape[0]
         e = self.n_experts
@@ -519,8 +543,10 @@ class MoE(TensorModule):
         return y, new_state
 
     def __repr__(self):
+        gate = getattr(self, "gate", "silu")
         return (f"MoE({self.input_size}, hidden={self.hidden_size}, "
-                f"experts={self.n_experts}, router={self.router})")
+                f"experts={self.n_experts}, router={self.router}"
+                + (f", gate={gate})" if gate != "silu" else ")"))
 
 
 def expert_parallel_rules(moe_path_prefix: str = "", axis: str = "model",

@@ -50,8 +50,8 @@ MAY = {
     "parallel": {"utils", "obs", "kernels", "nn"},
     "optim": {"utils", "obs", "kernels", "nn", "dataset", "parallel"},
     "visualization": set(),
-    "models": {"utils", "kernels", "nn", "dataset", "transform", "parallel",
-               "optim", "visualization"},
+    "models": {"utils", "obs", "kernels", "nn", "dataset", "transform",
+               "parallel", "optim", "visualization"},
     "serving": {"utils", "obs", "nn", "optim", "models"},
     "dlframes": {"utils", "nn", "dataset", "optim"},
     "top": {"utils", "obs", "nn", "dataset", "parallel", "optim", "models"},

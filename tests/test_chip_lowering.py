@@ -7,6 +7,8 @@ that catches an illegal BlockSpec, which is how the flash-2 residual and the
 6/12-row LayerNorm once went unnoticed behind interpret-mode tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -133,6 +135,30 @@ def test_flash_lowers_under_block_diffusion(on_tpu, shape, kv, length, dtype):
     mask = fa.BlockDiffusion(length, 4)
     q = jax.ShapeDtypeStruct(shape, dtype)
     k = jax.ShapeDtypeStruct((shape[0], kv) + shape[2:], dtype)
+    grad = _tpu_module(
+        jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, False, None, mask)
+                 .astype(jnp.float32).sum(), argnums=(0, 1, 2)), q, k, k)
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(grad, name) == 1, name
+    assert "repeat" not in grad
+
+
+@pytest.mark.parametrize("shape,kv,size,dtype", [
+    ((1, 28, 16384, 128), 4, 4096, jnp.bfloat16),   # the SmallThinker cell: two spans
+    ((1, 28, 16384, 128), 4, 4096, jnp.float32),    # four spans of 4,096
+    ((2, 8, 2048, 128), 2, 300, jnp.bfloat16),      # a window under one chunk
+    ((1, 4, 128, 64), 1, 16, jnp.float32),          # whole-axis tiles
+])
+def test_flash_lowers_under_a_causal_window(on_tpu, shape, kv, size, dtype):
+    """Forward and both backward kernels with the windowed mask and key/value
+    heads at their own count, at the SmallThinker cell's shape among them:
+    28 query heads on 4 key/value heads of 128 over 16,384 positions."""
+    mask = fa.CausalWindow(size)
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    k = jax.ShapeDtypeStruct((shape[0], kv) + shape[2:], dtype)
+    tiles = fa._tiles_under(mask, shape[2], shape[3], jnp.dtype(dtype).itemsize)
+    if shape[2] == 16384:
+        assert tiles == fa._Tiles(512, 512, 8192 if dtype == jnp.bfloat16 else 4096)
     grad = _tpu_module(
         jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, False, None, mask)
                  .astype(jnp.float32).sum(), argnums=(0, 1, 2)), q, k, k)
@@ -322,17 +348,19 @@ def test_off_tpu_default_is_the_reference():
 
 
 # ------------------------------------- what the scanned decoder keeps
-def _decoder_gradient(monkeypatch, remat, body=None) -> str:
-    """The gradient of a two-layer ``ConfigDecoder`` under ``BlockDiffusion``,
+def _decoder_gradient(monkeypatch, remat, body=None, **kinds) -> str:
+    """The gradient of a two-layer ``ConfigDecoder`` under ``BlockDiffusion``
+    (or, with ``kinds``, of a causal one of eight layers of those kinds),
     lowered for the TPU. ``body`` wraps the scan's layer in ``remat``'s place."""
     from bigdl_tpu.kernels import grouped_matmul as gm
     from bigdl_tpu.models.transformerlm import ConfigDecoder
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     model = ConfigDecoder(
-        vocab_size=512, hidden_size=256, num_hidden_layers=2,
+        vocab_size=512, hidden_size=256, num_hidden_layers=8 if kinds else 2,
         num_attention_heads=4, num_key_value_heads=2, head_dim=128,
         moe_intermediate_size=128, num_experts=16, num_experts_per_tok=4,
-        held=(4, 4), block_diffusion=(512, 4), remat=remat)
+        held=(4, 4), block_diffusion=None if kinds else (512, 4), remat=remat,
+        **kinds)
     if body is not None:
         scan = jax.lax.scan
         monkeypatch.setattr(jax.lax, "scan",
@@ -358,6 +386,28 @@ def test_scanned_decoder_runs_attention_and_routing_once(on_tpu, monkeypatch, re
         assert _kernel_calls(text, name) == 1, name
     assert text.count("stablehlo.sort") == 1
     assert text.count("@mhlo.topk") == 1
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_a_period_of_four_holds_each_kind_once_a_layer(on_tpu, monkeypatch, remat):
+    """Eight layers in two periods of (full, window, window, window): the scan
+    body holds the period written out, four layers each with its own static
+    mask and none that computes two kinds: every flash kernel once under the
+    causal mask and three times under the window (forward body and backward
+    body), four top-k and four sorts (kept, not run again), one scan over two
+    periods."""
+    text = _decoder_gradient(
+        monkeypatch, remat, sliding_window_layout=[0, 1, 1, 1] * 2,
+        sliding_window_size=256, rope_layout=[0, 1, 1, 1] * 2,
+        router_input="layer", expert_gate="relu", qk_norm=False)
+    for jitted in ("_pallas_flash_call", "_pallas_flash_bwd_dq", "_pallas_flash_bwd_dkv"):
+        assert len(re.findall(rf"call @{jitted}(_\d+)?\(", text)) == 4, jitted
+    # two masks and no more: the kernels' bodies as lowered (a body that
+    # several layers share is lowered once)
+    assert 2 <= _kernel_calls(text, "bigdl_flash_bwd_dq") <= 4
+    if remat:       # (without it the sort is one shared function, called four times)
+        assert text.count("stablehlo.sort") == 4
+        assert text.count("@mhlo.topk") == 4
 
 
 def test_whole_layer_rematerialisation_would_show(on_tpu, monkeypatch):

@@ -14,12 +14,13 @@ from bigdl_tpu.parallel import MoE, expert_parallel_rules
 D, HID, E, K, T = 64, 32, 16, 4, 96
 
 
-def _uncut(x, p, k, first=0, count=None, norm=True):
-    """The layer written out densely: softmax over all experts, the k
-    largest, renormalised, every expert of [first, first + count) over every
-    token with its routing weight."""
+def _uncut(x, p, k, first=0, count=None, norm=True, routed_on=None, gate=jax.nn.silu):
+    """The layer written out densely: softmax over all experts (the router
+    reading ``routed_on`` where given), the k largest, renormalised, every
+    expert of [first, first + count) over every token with its routing
+    weight."""
     hid = p["w_out"].shape[1]
-    probs = jax.nn.softmax(x @ p["w_gate"], -1)
+    probs = jax.nn.softmax((x if routed_on is None else routed_on) @ p["w_gate"], -1)
     top_p, top_e = jax.lax.top_k(probs, k)
     if norm:
         top_p = top_p / top_p.sum(-1, keepdims=True)
@@ -27,7 +28,7 @@ def _uncut(x, p, k, first=0, count=None, norm=True):
     for e in range(first, first + (p["w_in"].shape[0] if count is None else count)):
         w = jnp.sum(jnp.where(top_e == e, top_p, 0.0), -1)
         h = x @ p["w_in"][e]
-        y = y + w[:, None] * ((jax.nn.silu(h[:, :hid]) * h[:, hid:]) @ p["w_out"][e])
+        y = y + w[:, None] * ((gate(h[:, :hid]) * h[:, hid:]) @ p["w_out"][e])
     return y
 
 
@@ -136,6 +137,46 @@ def test_gradients_match_the_uncut_layer(layer, bias, pairs, passes):
         assert float(state["pairs_held"]) == pairs
 
 
+@pytest.mark.parametrize("gate", ["relu", "silu"])
+@pytest.mark.parametrize("apart", [True, False])
+def test_the_routers_input_apart_from_the_experts_and_the_relu_gate(layer, gate, apart):
+    """``(x, r)``: the router reads ``r`` and the experts ``x`` (a router that
+    stands before attention reads the layer's input); ``gate`` is the
+    activation of an expert's gate. Output and all gradients against the
+    dense form, ``r``'s gradient through the routing weights among them; a
+    3-D input is routed position by position alike."""
+    _, params, x = layer
+    first, count = 4, 4
+    r = jax.random.normal(jax.random.PRNGKey(9), x.shape) if apart else None
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count), gate=gate)
+    assert ("gate=relu" in repr(m)) == (gate == "relu")
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[gate]
+    probe = jnp.cos(jnp.arange(D))
+
+    def routed(p, x, r):
+        y, _ = m.apply(_share(p, first, count), m.get_state(), (x, r) if apart else x)
+        return jnp.sum(y * probe), y
+
+    def dense(p, x, r):
+        y = _uncut(x, p, K, first, count, routed_on=r if apart else None, gate=act)
+        return jnp.sum(y * probe), y
+
+    r_arg = r if apart else jnp.zeros(())
+    with jax.default_matmul_precision("highest"):
+        got, y = jax.grad(routed, (0, 1, 2), has_aux=True)(params, x, r_arg)
+        want, y_want = jax.grad(dense, (0, 1, 2), has_aux=True)(params, x, r_arg)
+        np.testing.assert_allclose(y, y_want, atol=2e-6)
+        for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(a, b, atol=5e-6)
+        if apart:
+            assert float(jnp.max(jnp.abs(got[2]))) > 1e-4       # the router's input matters
+            other = _uncut(x, params, K, first, count, gate=act)    # routed on x instead
+            assert float(jnp.max(jnp.abs(y - other))) > 1e-3
+            y3, _ = m.apply(_share(params, first, count), m.get_state(),
+                            (x.reshape(2, T // 2, D), r.reshape(2, T // 2, D)))
+            np.testing.assert_allclose(y3.reshape(T, D), y, atol=2e-6)
+
+
 def test_one_pass_when_all_experts_are_held(layer):
     """A pass's rows are every pair then, whatever the routing."""
     full, params, x = layer
@@ -197,6 +238,16 @@ def test_topk_arguments_are_checked():
         MoE(D, HID, E, router="topk", top_k=4, held=(12, 8))  # past the last
     with pytest.raises(ValueError):
         MoE(D, HID, E, router="top1", held=(0, 4))          # not this router's
+    with pytest.raises(ValueError):
+        MoE(D, HID, E, router="topk", top_k=4, gate="gelu")   # no such gate
+    with pytest.raises(ValueError):
+        MoE(D, HID, E, router="top2", gate="relu")          # not this router's
+    x = jnp.zeros((T, D))
+    with pytest.raises(ValueError):                         # nor a router's own input
+        MoE(D, HID, E).apply(MoE(D, HID, E).get_params(), {}, (x, x))
+    m = MoE(D, HID, E, router="topk", top_k=4)
+    with pytest.raises(ValueError):                         # the two of one shape
+        m.apply(m.get_params(), m.get_state(), (x, x[:8]))
 
 
 def test_expert_parallel_rules_shard_the_held_matrices():

@@ -9,36 +9,37 @@ so HBM traffic drops from O(T^2) to O(T·d) and the two matmuls per tile stay
 on the MXU.
 
 Tile program (``_Tiles``, chosen by ``_tiles`` from T, head_dim and the
-input's itemsize): the grid is (batch*heads, blocks, spans). A grid step owns
-one ``block`` of queries (forward, dq) or of keys (dk/dv) and has a ``span`` of
-the other side resident in VMEM: the whole axis wherever two operands of it,
+input's itemsize): the forward's grid is (batch*heads, blocks, spans). A grid
+step owns one ``block`` of queries and has a ``span`` of keys and values
+resident in VMEM: the whole axis wherever two operands of it,
 double-buffered, fit ``_RESIDENT_BYTES`` (T up to 16k at d=64 in bf16), so
 their block index moves only with the head and they are fetched once a head.
-Inside, a rolled ``fori_loop`` walks the span in ``chunk``s. Under a causal
-mask its trip count ends at the diagonal (a dead chunk is no step at all, a
-dead span is clamped onto the resident one and copies nothing) and every
-live chunk builds the mask, one compare of ``_lead`` against a scalar. A
-second body without the mask for the chunks wholly below the diagonal was
-built and dropped: the compare and select hide under the MXU's time (1,231
-against 1,261 bundles a 512x512 forward chunk, 1,761 against 1,740 for dq,
-2,253 both ways for dk/dv) and it doubles the kernel's code. Block and chunk
-need not be equal. The mask is a static description (``None``, ``"causal"``,
-``CausalWindow(size)`` or ``BlockDiffusion(L, b)``): from it each kernel
-derives, a tile, the one or two ranges of chunks that hold a live pair
-(``_live_keys``, ``_live_queries``) and walks them in the same one loop
-(``_walk``); a live chunk under block diffusion builds its mask from two
-compares of block indices. Under a causal window the range of chunks is cut
-on both sides of the band: it starts at the chunk the tile's first row still
-reaches back to and ends at the diagonal (by keys: from the diagonal to the
-last row that still reaches the tile's keys), a span wholly outside the band
-is clamped onto a live one and copies nothing, and a live chunk builds its
-mask from two compares of ``_lead``. At T 16,384, a window of 4,096 and
-512-row tiles that is 252 tiles a head against the causal mask's 528. Keys and values
-may hold fewer heads than the queries: the index maps read a group's shared
-head, and the dk/dv kernel's last grid axis walks the group's query heads.
-The running (m, l, acc) state lives in VMEM scratch across
-a block's chunks and spans. Each kernel is a jitted function, so a model's
-layers share one trace and one lowering of it.
+The backward's grid step owns a ``block`` of keys and has a ``span`` of
+queries (q and dO) resident. Inside, a rolled ``fori_loop`` walks the span in
+``chunk``s. Under a causal mask its trip count ends at the diagonal (a dead
+chunk is no step at all, a dead span is clamped onto the resident one and
+copies nothing) and every live chunk builds the mask, one compare of
+``_lead`` against a scalar. A second body without the mask for the chunks
+wholly below the diagonal was built and dropped: the compare and select hide
+under the MXU's time (1,231 against 1,261 bundles a 512x512 forward chunk)
+and it doubles the kernel's code. Block and chunk need not be equal. The mask
+is a static description (``None``, ``"causal"``, ``CausalWindow(size)`` or
+``BlockDiffusion(L, b)``): from it each kernel derives, a tile, the one or two
+ranges of chunks that hold a live pair (``_live_keys`` forward,
+``_live_queries`` backward) and walks them in the same one loop (``_walk``); a
+live chunk under block diffusion builds its mask from two compares of block
+indices. Under a causal window the range of chunks is cut on both sides of
+the band: it starts at the chunk the tile's first row still reaches back to
+and ends at the diagonal (by keys: from the diagonal to the last row that
+still reaches the tile's keys), a span wholly outside the band is clamped
+onto a live one and copies nothing, and a live chunk builds its mask from two
+compares of ``_lead``. At T 16,384, a window of 4,096 and 512-row tiles that
+is 252 tiles a head against the causal mask's 528. Keys and values may hold
+fewer heads than the queries: the index maps read a group's shared head, and
+the backward's grid walks the group's query heads. The forward's running
+(m, l, acc) state lives in VMEM scratch across a block's chunks and spans.
+Each kernel is a jitted function, so a model's layers share one trace and one
+lowering of it.
 
 Operands go to the MXU in the input's dtype with fp32 accumulation
 (``preferred_element_type``): bf16 q/k/v/dO tiles as they are, ``p`` and ``ds``
@@ -54,10 +55,15 @@ once at the flush: one lane reduction (the max) a chunk instead of two
 reductions and two lane broadcasts. Statistics and accumulators are fp32.
 
 Semantics: forward AND backward are Pallas kernels on TPU (interpreter
-elsewhere — tests). The backward is the standard flash-2 scheme: the forward
-additionally saves the per-row logsumexp L = m + log(l); backward recomputes
-each probability tile from (q, k, L) in VMEM and streams
-  dq += (p * (dO·v^T - D)) · k,   dv += p^T · dO,   dk += ds^T · q
+elsewhere — tests). The backward is the flash-2 scheme in one kernel
+(``bigdl_flash_bwd``, PR 37; two kernels before it, dq and dk/dv, each of which
+made ``s``, the mask, ``p``, ``dp`` and ``ds`` for itself: seven products a
+tile). The forward additionally saves the per-row logsumexp L = m + log(l);
+the backward walks each live tile once, recomputes the probability tile from
+(q, k, L) in VMEM, in the transposed orientation (keys down the sublanes), and
+from the one ``p_t`` and ``ds_t = p_t * (v·dO^T - D)`` adds to all three sums,
+  dv += p_t · dO,   dk += ds_t · q,   dq += ds_t^T · k
+five products a tile, the last the only one with a transposed left operand,
 with D = rowsum(dO * O) precomputed in one fused elementwise pass — so
 TRAINING memory is O(T·d) too, not just inference (the O(T^2) score matrix is
 never materialised in either direction; asserted by test against the compiled
@@ -65,21 +71,49 @@ HLO). Off TPU, and at sequence lengths no legal tile covers (``_pick_block``),
 the reference jnp attention and its recompute-form VJP run instead. On TPU a
 kernel that does not build raises: nothing here catches a build error.
 
+The two sums run across each other: dk and dv sum over queries and over the
+group's query heads, dq over keys. The backward's grid is (key/value head,
+the group's query heads, spans of queries, key blocks), the key blocks
+innermost: dq's fp32 span sits in VMEM scratch across the key blocks and is
+scaled, cast and written once a (query head, span); dk and dv sum in fp32
+scratch as long as the head (2 x T x d x 4 bytes: 16 MB at T 16,384 and d 128,
+8 MB at the SDAR cell's T 8,192, 0.5 MB at GPT-2's) across the spans and the
+group's heads, and a block of them is written in the last of those passes
+(until then their output's index map names block 0, which Pallas copies out
+only when the index moves on, after that pass has filled it). Nothing partial
+crosses HBM. The VMEM the kernel asks for is computed from the shapes
+(``_bwd_vmem_limit``: 42 MiB at the SmallThinker cell's shape, 34 at SDAR's,
+the forward's 32 at GPT-2's); a sequence whose head-long sums pass
+``_VMEM_CEILING_BYTES`` (T 65,536 at d 128 still fits) raises by name.
+
 Per-row residuals (logsumexp ``L``, ``D``) cross HBM in the orientation each
 kernel broadcasts them in, so no kernel has to move a vector between
-sublanes and lanes: a column ``(bh, T, 1)`` for the forward and dq kernels
-(rows of the ``(block, chunk)`` tile), a row ``(bh, 1, T)`` for the dk/dv
-kernel (its tile is transposed; XLA gets from one to the other by a bitcast).
-Both shapes meet Mosaic's block rule — the last two block dims divide (8, 128)
-or span the array — which a ``(1, block)`` block over ``(bh, T)`` does not.
+sublanes and lanes: the forward writes ``L`` as a column ``(bh, T, 1)`` (rows
+of its ``(block, chunk)`` tile), the backward reads ``L`` and ``D`` as rows
+``(bh, 1, T)`` (its tile is transposed; XLA gets from one to the other by a
+bitcast). Both shapes meet Mosaic's block rule — the last two block dims
+divide (8, 128) or span the array — which a ``(1, block)`` block over
+``(bh, T)`` does not.
 
-Measured on one v5e chip (PERF.md, PR 27) at (8, 16, 1024, 64) bf16 causal,
+Measured on one v5e chip at (8, 16, 1024, 64) bf16 causal,
 the shape of the benchmark's GPT-2 medium cell, 512-row blocks and chunks:
-forward 0.49, dq 0.61, dk/dv 0.73 ms a call (1.05, 2.94 and 2.91 ms with the
-256x512 forward and 128x128 backward tiles of before; 0.85, 0.76 and 0.81 ms
-with 512x512 tiles on that older three-axis grid, so the tile size alone was
-most of it). The loops' bodies are then within a fifth of what the MXU needs
-for products that fill half of it (head_dim 64 against 128 rows).
+forward 0.49, dq 0.61, dk/dv 0.73 ms a call (PERF.md, PR 27; 1.05, 2.94 and
+2.91 ms with the 256x512 forward and 128x128 backward tiles of before). The
+loops' bodies are then within a fifth of what the MXU needs for products that
+fill half of it (head_dim 64 against 128 rows). The one backward kernel
+against the pair it replaced (PERF.md, PR 37; bf16, the backward of one call
+with its rowsum pass, ms, and the loop body's bundles a 512x512 tile):
+  (8, 16, 1024, 64) causal, GPT-2's            1.79 -> 1.28    3,452 -> 2,366
+  (2, 32 on 4, 8192, 128) BlockDiffusion(4096, 4), SDAR's
+                                               17.98 -> 10.69   3,422 -> 2,245
+  (1, 28 on 4, 16384, 128) causal, SmallThinker's full layer
+                                               42.84 -> 28.71   3,423 -> 2,158
+  the same under CausalWindow(4096)            24.04 -> 14.57   3,564 -> 2,348
+Tried beside it and not kept: dq summed transposed (``k^T ds_t`` with ``k^T``
+made once a grid step: 1.24, 10.68, 28.94, 14.78 ms), ``ds_t`` transposed in
+fp32 before its cast (2,440 bundles), and tiles of 512x256, 256x512,
+1,024x512 and 512x1,024 (2,988, 2,800, 2,204 and 2,423 bundles a 512x512
+tile's worth against 2,158).
 """
 
 from __future__ import annotations
@@ -164,7 +198,7 @@ def _reference_attention(q, k, v, causal=False):
 class _Tiles(NamedTuple):
     """One kernel's tiling of a sequence axis of length ``t``. A grid step
     owns ``block`` rows of the operand that streams through the MXU (queries
-    for the forward and dq kernels, keys for dk/dv) and holds ``span`` rows
+    for the forward kernel, keys for the backward) and holds ``span`` rows
     of the other side resident in VMEM (the whole axis wherever it fits);
     a rolled loop walks the span in chunks of ``chunk`` rows."""
     block: int
@@ -172,14 +206,21 @@ class _Tiles(NamedTuple):
     span: int
 
 
-# VMEM the kernels may take (v5e has 128 MiB; Mosaic's default scope is 16).
-# The largest plan holds _RESIDENT_BYTES of resident operands (two of them,
+# VMEM the forward kernel may take (v5e has 128 MiB; Mosaic's default scope is
+# 16): its plan holds _RESIDENT_BYTES of resident operands (two of them,
 # double-buffered) and about five fp32 temporaries of a block x chunk tile.
+# The backward kernel asks for what its own plan needs, from the shapes
+# (``_bwd_vmem_limit``), and no more than _VMEM_CEILING_BYTES.
 _VMEM_LIMIT_BYTES = 32 * 2 ** 20
+_VMEM_CEILING_BYTES = 100 * 2 ** 20
 _RESIDENT_BYTES = 8 * 2 ** 20
+# fp32 tiles of block x chunk the backward's loop body is given room for:
+# s_t, p_t, dp_t, ds_t, the masks' iotas and the casts' copies
+_TILE_TEMPORARIES = 8
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
 def _part(ref, axis, j, size):
@@ -328,7 +369,7 @@ def _banded(s, lead, diag, size: int):
     first chunks the tile walks (those are there for its first rows), and a
     row's running maximum has to stay finite for ``exp(m_prev - m_new)``. What
     such a row gathers before its first live key (every ``p`` reads 1) that
-    factor wipes, as exactly 0, when the key comes; in the backward kernels
+    factor wipes, as exactly 0, when the key comes; in the backward kernel
     ``exp(_DEAD - lse)`` is 0."""
     return jnp.where((lead <= diag) & (lead > diag - size), s, _DEAD)
 
@@ -395,14 +436,6 @@ def _kv_map(block_q, span, mask, group):
             s = jnp.maximum(s, jnp.maximum(i * block_q - mask.size + 1, 0) // span)
         return (b if group == 1 else b // group, s, 0)
     return index
-
-
-def _compiler_params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
 @_kernel
@@ -495,104 +528,67 @@ def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
             pltpu.VMEM((block_q, width), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="bigdl_flash_fwd",
     )(q3, k3, v3)
     return out, lse
 
 
-@_kernel
-def _pallas_flash_bwd_dq(q3, k3, v3, do3, lse_col, dd_col, mask,
-                         tiles, interpret):
-    """dq = Σ_j (p_ij * (dO_i·v_j^T - D_i)) · k_j * scale, streaming over j
-    with the probability tile recomputed from (q, k, lse) in VMEM."""
+def _rows(ref, j, size):
+    """Rows ``[j * size, (j + 1) * size)`` of a 2-D scratch, as an index."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    bh, t, d = q3.shape
-    group = bh // k3.shape[0]
-    block_q, block_k, span = tiles
-    diffusion = isinstance(mask, BlockDiffusion)
-    windowed = isinstance(mask, CausalWindow)
-    scale = 1.0 / (d ** 0.5)
-    n_span, per_span = t // span, span // block_k
+    if ref.shape[0] == size:
+        return slice(None)
+    return pl.ds(pl.multiple_of(j * size, size), size)
 
-    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc_scr):
-        i, s_idx = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(s_idx == 0)
-        def _init():
-            acc_scr[:] = jnp.zeros_like(acc_scr)
-
-        q = q_ref[0] * scale
-        do = do_ref[0]
-        lse = lse_ref[0]                                  # (bq, 1)
-        dd = dd_ref[0]                                    # (bq, 1)
-        row0 = i * block_q
-        if diffusion:
-            query_keys = _query_keys(mask, _positions(block_q, 0, row0))
-
-        def step(j):
-            k = _part(k_ref, 1, j, block_k)
-            v = _part(v_ref, 1, j, block_k)
-            s = jax.lax.dot_general(q, k, _NT,
-                                    preferred_element_type=jnp.float32)
-            col0 = (s_idx * per_span + j) * block_k
-            if mask == "causal":
-                s = jnp.where(_lead(s.shape, 1, 0) <= row0 - col0, s, -jnp.inf)
-            elif windowed:
-                s = _banded(s, _lead(s.shape, 1, 0), row0 - col0, mask.size)
-            elif diffusion:
-                s = _block_diffusion(s, query_keys, _key_value(
-                    mask, _positions(block_k, 1, col0)))
-            p = jnp.exp(s - lse)                          # (bq, bk)
-            dp = jax.lax.dot_general(do, v, _NT,
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - dd)
-            acc_scr[:] = acc_scr[:] + jax.lax.dot_general(
-                ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
-
-        _walk(_live_keys(mask, row0, block_q, block_k, n_span * per_span),
-              s_idx, per_span, n_span, step)
-
-        @pl.when(s_idx == n_span - 1)
-        def _flush():
-            # ds' own factor of the scale, once on the (bq, d) sum
-            dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
-
-    kv_map = _kv_map(block_q, span, mask, group)
-    row_map = lambda b, i, s: (b, i, 0)
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_struct((bh, t, d), q3.dtype, q3, k3, v3, do3),
-        grid=(bh, t // block_q, n_span),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), row_map),
-            pl.BlockSpec((1, span, d), kv_map),
-            pl.BlockSpec((1, span, d), kv_map),
-            pl.BlockSpec((1, block_q, d), row_map),
-            pl.BlockSpec((1, block_q, 1), row_map),
-            pl.BlockSpec((1, block_q, 1), row_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), row_map),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-        name="bigdl_flash_bwd_dq",
-    )(q3, k3, v3, do3, lse_col, dd_col)
+def _bwd_vmem_limit(t: int, d: int, itemsize: int, tiles: _Tiles) -> int:
+    """The VMEM the backward kernel asks for, from the shapes: what its plan
+    holds (the resident spans of q and dO and the rows of their residuals,
+    double-buffered, a block of k and of v, dq's fp32 span with its output
+    block, the head-long fp32 sums of dk and dv with their output blocks, and
+    ``_TILE_TEMPORARIES`` fp32 tiles of block x chunk; a row of fewer than 128
+    lanes takes 128, a ``(1, span)`` row of residuals 8 sublanes), or the
+    forward's limit where that is more. The temporaries are counted
+    generously: Mosaic builds the SmallThinker cell's shape within 36 MiB of
+    the plan's 42, the SDAR cell's within 28 of 34. A sequence whose head-long
+    sums pass the ceiling is refused here, by name, not by the compiler."""
+    block, chunk, span = tiles
+    lanes = -(-d // 128) * 128
+    operands = 2 * (2 * span + 2 * block) * lanes * itemsize
+    residuals = 2 * 2 * 8 * span * 4
+    dq = span * lanes * (4 + 2 * itemsize)
+    dkv = 2 * (t * lanes * 4 + 2 * block * lanes * itemsize)
+    need = (operands + residuals + dq + dkv
+            + _TILE_TEMPORARIES * block * chunk * 4)
+    if need > _VMEM_CEILING_BYTES:
+        raise ValueError(
+            f"flash attention's backward keeps fp32 sums of dk and dv as long "
+            f"as the sequence in VMEM: T={t} at head_dim {d} needs "
+            f"{need >> 20} MiB of {_VMEM_CEILING_BYTES >> 20}")
+    return max(need, _VMEM_LIMIT_BYTES)
 
 
 @_kernel
-def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
-                          tiles, interpret):
-    """dv = Σ_i p_ij^T · dO_i ; dk = Σ_i ds_ij^T · q_i * scale — a grid step
-    owns a block of keys and walks the queries, in the transposed orientation
-    (keys down the sublanes), with (dk, dv) accumulators in VMEM.
-    ``lse_row`` and ``dd_row`` are ``(bh, 1, t)``: queries along the lanes,
-    as the transposed tile meets them. Where ``group`` query heads share a
-    key/value head, the grid's last axis walks the group's heads (each with
-    its spans) and the accumulators take all of them."""
+def _pallas_flash_bwd(q3, k3, v3, do3, lse_row, dd_row, mask, tiles, interpret):
+    """All three gradients from one walk of the live tiles. A grid step owns a
+    block of keys, in the transposed orientation (keys down the sublanes), and
+    walks the chunks of the resident span of queries that see it; a tile makes
+    ``s_t = k q^T``, the mask, ``p_t``, ``dp_t = v dO^T`` and ``ds_t`` once, and
+    from them ``dv += p_t dO``, ``dk += ds_t q`` and ``dq += ds_t^T k``: five
+    products. ``lse_row`` and ``dd_row`` are ``(bh, 1, t)``: queries along the
+    lanes, as the transposed tile meets them.
+
+    The grid is (key/value head, the group's query heads, spans of queries,
+    key blocks): dq's fp32 span stays in scratch across the key blocks and is
+    written once a (query head, span); dk and dv sum in fp32 scratch as long as
+    the head across the spans and the group's heads, and a block of them is
+    written in the last of those passes (until then the output's index map
+    names block 0, which is not copied out before that pass has filled it)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -603,22 +599,26 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
     diffusion = isinstance(mask, BlockDiffusion)
     windowed = isinstance(mask, CausalWindow)
     scale = 1.0 / (d ** 0.5)
-    n_span, per_span = t // span, span // block_q
-
-    def split(s):
-        """(query head within the group, span) of a step of the last axis."""
-        return (0, s) if group == 1 else (s // n_span, s % n_span)
+    n_span, per_span, n_block = t // span, span // block_q, t // block_k
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-               dk_ref, dv_ref, dk_scr, dv_scr):
-        j, s_idx = pl.program_id(1), split(pl.program_id(2))[1]
+               dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+        head, s_idx, j = (pl.program_id(a) for a in (1, 2, 3))
+        first_pass = (head == 0) & (s_idx == 0)
+        last_pass = (head == group - 1) & (s_idx == n_span - 1)
+        own = _rows(dk_scr, j, block_k)       # the block's rows of dk and dv
 
-        @pl.when(pl.program_id(2) == 0)
-        def _init():
-            dk_scr[:] = jnp.zeros_like(dk_scr)
-            dv_scr[:] = jnp.zeros_like(dv_scr)
+        @pl.when(j == 0)
+        def _init_dq():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
 
-        k = k_ref[0] * scale
+        @pl.when(first_pass)
+        def _init_dkv():
+            dk_scr[own] = jnp.zeros((block_k, d), jnp.float32)
+            dv_scr[own] = jnp.zeros((block_k, d), jnp.float32)
+
+        k = k_ref[0]
+        k_scaled = k * scale
         v = v_ref[0]
         col0 = j * block_k
         if diffusion:
@@ -629,7 +629,7 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
             do = _part(do_ref, 1, i, block_q)
             lse = _part(lse_ref, 2, i, block_q)        # (1, bq)
             dd = _part(dd_ref, 2, i, block_q)          # (1, bq)
-            s_t = jax.lax.dot_general(k, q, _NT,          # (bk, bq)
+            s_t = jax.lax.dot_general(k_scaled, q, _NT,   # (bk, bq)
                                       preferred_element_type=jnp.float32)
             row0 = (s_idx * per_span + i) * block_q
             if mask == "causal":
@@ -641,15 +641,17 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
                 s_t = _block_diffusion(s_t, _query_keys(
                     mask, _positions(block_q, 1, row0)), key_value)
             p_t = jnp.exp(s_t - lse)
-            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+            dv_scr[own] += jax.lax.dot_general(
                 p_t.astype(do.dtype), do, _NN,
                 preferred_element_type=jnp.float32)
             dp_t = jax.lax.dot_general(v, do, _NT,
                                        preferred_element_type=jnp.float32)
-            ds_t = p_t * (dp_t - dd)
-            dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-                ds_t.astype(q.dtype), q, _NN,
-                preferred_element_type=jnp.float32)
+            ds_t = (p_t * (dp_t - dd)).astype(q.dtype)
+            dk_scr[own] += jax.lax.dot_general(
+                ds_t, q, _NN, preferred_element_type=jnp.float32)
+            # the one product with a transposed left operand
+            dq_scr[_rows(dq_scr, i, block_q)] += jax.lax.dot_general(
+                ds_t, k, _TN, preferred_element_type=jnp.float32)
 
         # under a mask the loop walks the query chunks that see the block's
         # keys: from the diagonal on (to the band's end under a window), or
@@ -657,46 +659,51 @@ def _pallas_flash_bwd_dkv(q3, k3, v3, do3, lse_row, dd_row, mask,
         _walk(_live_queries(mask, col0, block_k, block_q, n_span * per_span),
               s_idx, per_span, n_span, step)
 
-        @pl.when(pl.program_id(2) == group * n_span - 1)
-        def _flush():
-            dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        @pl.when(j == n_block - 1)
+        def _flush_dq():
+            # ds' own factor of the scale, once on the (span, d) sum
+            dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
-    def q_map(b, j, s):
-        head, s = split(s)
-        if mask == "causal" or windowed:
-            # a span of queries above the diagonal names the first live one
-            s = jnp.maximum(s, (j * block_k) // span)
-        if windowed:
-            # and one past the last row that reaches the block's keys the last
-            s = jnp.minimum(s, (j * block_k + block_k + mask.size - 2) // span)
-        return (b if group == 1 else b * group + head, s, 0)
+        @pl.when(last_pass)
+        def _flush_dkv():
+            dk_ref[0] = (dk_scr[own] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[own].astype(dv_ref.dtype)
 
-    def row_map(b, j, s):
-        head, span_idx, _ = q_map(b, j, s)
-        return (head, 0, span_idx)
+    # a block of keys wholly outside what the span's queries see names the
+    # nearest live one, which is already there: no copy
+    live_block = _kv_map(span, block_k, mask, 1)
+    q_map = lambda b, h, s, j: (b * group + h, s, 0)
+    row_map = lambda b, h, s, j: (b * group + h, 0, s)
+    kv_map = lambda b, h, s, j: live_block(b, s, j)
 
-    col_map = lambda b, j, s: (b, j, 0)
+    def out_map(b, h, s, j):
+        return (b, jnp.where((h == group - 1) & (s == n_span - 1), j, 0), 0)
+
     return pl.pallas_call(
         kernel,
-        out_shape=[out_struct((bkv, t, d), k3.dtype, q3, k3, v3, do3),
+        out_shape=[out_struct((bh, t, d), q3.dtype, q3, k3, v3, do3),
+                   out_struct((bkv, t, d), k3.dtype, q3, k3, v3, do3),
                    out_struct((bkv, t, d), v3.dtype, q3, k3, v3, do3)],
-        grid=(bkv, t // block_k, group * n_span),
+        grid=(bkv, group, n_span, n_block),
         in_specs=[
             pl.BlockSpec((1, span, d), q_map),
-            pl.BlockSpec((1, block_k, d), col_map),
-            pl.BlockSpec((1, block_k, d), col_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, span, d), q_map),
             pl.BlockSpec((1, 1, span), row_map),
             pl.BlockSpec((1, 1, span), row_map),
         ],
-        out_specs=[pl.BlockSpec((1, block_k, d), col_map),
-                   pl.BlockSpec((1, block_k, d), col_map)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params(),
+        out_specs=[pl.BlockSpec((1, span, d), q_map),
+                   pl.BlockSpec((1, block_k, d), out_map),
+                   pl.BlockSpec((1, block_k, d), out_map)],
+        scratch_shapes=[pltpu.VMEM((span, d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) + ("arbitrary",) * 3,
+            vmem_limit_bytes=_bwd_vmem_limit(t, d, q3.dtype.itemsize, tiles)),
         interpret=interpret,
-        name="bigdl_flash_bwd_dkv",
+        name="bigdl_flash_bwd",
     )(q3, k3, v3, do3, lse_row, dd_row)
 
 
@@ -768,14 +775,14 @@ def flash_attention(q, k, v, causal: bool = False,
     return _fa_fwd(q, k, v, causal, force_pallas, mask)[0]
 
 
-#: What the backward kernels read, by the names ``_fa_fwd`` tags them with
+#: What the backward kernel reads, by the names ``_fa_fwd`` tags them with
 #: (``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` whose policy
 #: saves these names keeps them and does not run the forward kernel again.
 RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
 
 
 def _fa_fwd(q, k, v, causal, force_pallas, mask):
-    """The forward kernel and what the backward kernels read: ``q``, ``k``,
+    """The forward kernel and what the backward kernel reads: ``q``, ``k``,
     ``v`` as they enter (after a caller's norms, RoPE and layout copies),
     ``out`` and the rows' logsumexp, each tagged with its name of
     ``RESIDUAL_NAMES``. A tag is the identity unless a ``jax.checkpoint``
@@ -822,19 +829,16 @@ def _fa_bwd(causal, force_pallas, mask, res, g):
 def _flash_bwd(q, k, v, out, lse, g, mask):
     """Streaming flash-2 backward: O(T·d) memory, probability tiles recomputed
     from (q, k, lse) in VMEM."""
-    b, h, t, d = q.shape
-    hkv = k.shape[1]
+    t, d = q.shape[2:]
     tiles = _tiles_under(mask, t, d, q.dtype.itemsize)    # not None: _fa_fwd checked
     reshape = lambda a: a.reshape(-1, t, d)
     q3, k3, v3, do3 = reshape(q), reshape(k), reshape(v), reshape(g)
-    # D_i = rowsum(dO * O): one fused elementwise pass, O(T·d) reads
+    # D_i = rowsum(dO * O): one fused elementwise pass, O(T·d) reads; with the
+    # logsumexp it meets the kernel as a row, (bh, 1, t)
     dd = jnp.sum(do3.astype(jnp.float32) * reshape(out).astype(jnp.float32),
-                 axis=-1, keepdims=True)                    # (bh, t, 1)
-    interp = not _on_tpu()
-    dq = _pallas_flash_bwd_dq(q3, k3, v3, do3, lse, dd, mask, tiles, interp)
-    as_row = lambda a: a.reshape(b * h, 1, t)
-    dk, dv = _pallas_flash_bwd_dkv(q3, k3, v3, do3, as_row(lse), as_row(dd),
-                                   mask, tiles, interp)
+                 axis=-1)[:, None]
+    dq, dk, dv = _pallas_flash_bwd(q3, k3, v3, do3, lse.reshape(dd.shape), dd,
+                                   mask, tiles, interpret=not _on_tpu())
     unshape = lambda a, like: a.reshape(like.shape).astype(like.dtype)
     return unshape(dq, q), unshape(dk, k), unshape(dv, v)
 

@@ -9,7 +9,7 @@ width of the models the repo benchmarks, with seeded random weights:
                 NHWC + space-to-depth stem) through LocalOptimizer.optimize():
                 the per-step program and fused windows
   train-lm      TransformerLM d=512 L=6 V=32000 at b16 T=512; the lowered step
-                must hold the flash fwd / bwd-dq / bwd-dkv and LayerNorm
+                must hold the flash forward and backward and the LayerNorm
                 kernels as Mosaic custom calls; the kernels are compared with
                 their jnp references on the chip
   serve         ServingEngine over the same-width LM, 8 slots, 8 requests of
@@ -177,8 +177,7 @@ def train_vision(batch: int = 256):
 
 
 # -------------------------------------------------------------- train-lm
-KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd_dq", "bigdl_flash_bwd_dkv",
-           "bigdl_layer_norm")
+KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd", "bigdl_layer_norm")
 
 
 def _kernel_numerics(shape=(16, 8, 512, 64)) -> dict:

@@ -39,3 +39,40 @@ def _fresh_engine():
     slo.reset()
     exporter.reset()
     watchdog.clear_context_providers()
+
+
+@pytest.fixture
+def fused_backward_against_reference(monkeypatch):
+    """The flash backward kernel (interpret mode) against the VJP of
+    ``_reference_attention`` at ``"highest"`` in float32: all three gradients
+    of one call. ``tiles`` hands the kernels a ``_Tiles`` of the case's own
+    (block unequal to chunk, several spans a head); ``heads`` is (query heads,
+    key/value heads). Shared by the three files that hold the masks' cases."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bigdl_tpu.kernels import flash_attention as fa
+
+    def check(mask, heads, t, d, dtype, tiles=None):
+        if tiles is not None:
+            monkeypatch.setattr(fa, "_tiles_under",
+                                lambda *a: fa._Tiles(*tiles))
+        hq, hkv = heads
+        key = jax.random.PRNGKey(hq * 1000 + d)
+        q, k, v, g = (jax.random.normal(jax.random.fold_in(key, i), (1, h, t, d),
+                                        jnp.float32).astype(dtype)
+                      for i, h in enumerate((hq, hkv, hkv, hq)))
+        got = jax.vjp(lambda *a: fa.flash_attention(*a, False, True, mask),
+                      q, k, v)[1](g)
+        with jax.default_matmul_precision("highest"):
+            want = jax.vjp(lambda *a: fa._reference_attention(*a, mask),
+                           *(a.astype(jnp.float32) for a in (q, k, v)))[1](
+                               g.astype(jnp.float32))
+        assert [a.shape for a in got] == [q.shape, k.shape, v.shape]
+        assert [a.dtype for a in got] == [q.dtype] * 3
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - w))
+                      / jnp.max(jnp.abs(w))) for a, w in zip(got, want)]
+        assert max(errs) <= (1e-2 if dtype == jnp.bfloat16 else 1e-5), errs
+
+    return check

@@ -82,6 +82,19 @@ def test_flash_kernels_under_block_diffusion(length, b):
         np.testing.assert_allclose(a, w, atol=2e-5)
 
 
+@pytest.mark.parametrize("d,dtype", [(64, jnp.bfloat16), (128, jnp.float32)])
+@pytest.mark.parametrize("group", [1, 4, 7])
+def test_fused_backward_under_block_diffusion(
+        fused_backward_against_reference, group, d, dtype):
+    """The one backward kernel under the block-diffusion mask: a clean half
+    of 256 and its noised copy, four key blocks against two spans of two
+    chunks (block and chunk alike, as ``_tiles_under`` makes them: the
+    forward's first chunk has to hold a key of every row): two ranges of
+    chunks a key block, walked in one loop and cut to each span."""
+    fused_backward_against_reference(fa.BlockDiffusion(256, 4), (group, 1), 512,
+                                     d, dtype, tiles=(128, 128, 256))
+
+
 @pytest.mark.parametrize("mask", ["causal", None])
 def test_grouped_query_heads_reach_the_kernels_at_their_own_count(mask):
     q, k, v, g = _operands(64, 4, 2, 16)

@@ -17,7 +17,7 @@ import pytest
 from bigdl_tpu.kernels import flash_attention as fa
 from bigdl_tpu.kernels import layernorm as ln
 
-FLASH_KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd_dq", "bigdl_flash_bwd_dkv")
+FLASH_KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd")
 
 
 @pytest.fixture
@@ -41,7 +41,7 @@ def _kernel_calls(text: str, name: str) -> int:
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("shape", [(16, 8, 512, 64), (8, 16, 1024, 64)])
 def test_flash_lowers_at_bench_shape(on_tpu, shape, dtype, causal):
-    """Forward and both backward kernels at the bench LM's shape and at the
+    """Forward and the backward kernel at the bench LM's shape and at the
     benchmark's GPT-2 medium cell's."""
     q = jax.ShapeDtypeStruct(shape, dtype)
     fwd = _tpu_module(lambda a, b, c: fa.flash_attention(a, b, c, causal),
@@ -130,7 +130,7 @@ def test_untileable_length_is_reference_by_rule(on_tpu, t):
     ((1, 4, 128, 128), 1, 64, jnp.float32),         # whole-axis tiles
 ])
 def test_flash_lowers_under_block_diffusion(on_tpu, shape, kv, length, dtype):
-    """Forward and both backward kernels with the block-diffusion mask and
+    """Forward and the backward kernel with the block-diffusion mask and
     key/value heads at their own count (an index map, nothing repeated)."""
     mask = fa.BlockDiffusion(length, 4)
     q = jax.ShapeDtypeStruct(shape, dtype)
@@ -150,7 +150,7 @@ def test_flash_lowers_under_block_diffusion(on_tpu, shape, kv, length, dtype):
     ((1, 4, 128, 64), 1, 16, jnp.float32),          # whole-axis tiles
 ])
 def test_flash_lowers_under_a_causal_window(on_tpu, shape, kv, size, dtype):
-    """Forward and both backward kernels with the windowed mask and key/value
+    """Forward and the backward kernel with the windowed mask and key/value
     heads at their own count, at the SmallThinker cell's shape among them:
     28 query heads on 4 key/value heads of 128 over 16,384 positions."""
     mask = fa.CausalWindow(size)
@@ -165,6 +165,60 @@ def test_flash_lowers_under_a_causal_window(on_tpu, shape, kv, size, dtype):
     for name in FLASH_KERNELS:
         assert _kernel_calls(grad, name) == 1, name
     assert "repeat" not in grad
+
+
+# ------------------------- the backward kernel built for a described v5e
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A chip that is described, not attached: the TPU's compiler builds the
+    kernel for it from here (no result, no time). Skips where this process
+    cannot describe one."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("shape,kv,mask,tiles,limit_mib", [
+    ((8, 16, 1024, 64), 16, "causal", (512, 512, 1024), 32),          # gpt2-medium.train-t1024
+    ((2, 32, 8192, 128), 4, fa.BlockDiffusion(4096, 4), (512, 512, 8192), 34),   # sdar-30b-a3b.train-bd4k
+    ((1, 28, 16384, 128), 4, "causal", (512, 512, 8192), 42),         # smallthinker-21b-a3b.train-t16k,
+    ((1, 28, 16384, 128), 4, fa.CausalWindow(4096), (512, 512, 8192), 42),   # its full and its windowed layers
+])
+def test_flash_backward_builds_within_the_vmem_it_asks_for(
+        one_v5e_chip, shape, kv, mask, tiles, limit_mib):
+    """The three cells' shapes through Mosaic itself: the plan of scratch and
+    blocks comes from the shapes (``_bwd_vmem_limit``: the forward's 32 MiB
+    where the plan needs less), stays under the ceiling, and the compiler,
+    which refuses a kernel that passes its limit, builds it."""
+    b, h, t, d = shape
+    assert fa._tiles_under(mask, t, d, 2) == fa._Tiles(*tiles)
+    limit = fa._bwd_vmem_limit(t, d, 2, fa._Tiles(*tiles))
+    assert limit >> 20 == limit_mib and limit <= fa._VMEM_CEILING_BYTES
+    struct = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_v5e_chip)
+    q, k = struct(b * h, t, d), struct(b * kv, t, d)
+    row = struct(b * h, 1, t, dtype=jnp.float32)
+    text = fa._pallas_flash_bwd.lower(
+        q, k, k, q, row, row, mask=mask, tiles=fa._Tiles(*tiles),
+        interpret=False).compile().as_text()
+    assert "bigdl_flash_bwd" in text
+
+
+def test_a_sequence_whose_sums_pass_the_ceiling_is_refused_by_name():
+    """dk and dv sum in VMEM as long as the head: at T 131,072 and head_dim
+    128 that is 128 MiB, and the plan says so before the compiler would. Half
+    of that length still fits."""
+    assert fa._bwd_vmem_limit(65536, 128, 2, fa._tiles(65536, 128, 2)) >> 20 == 90
+    with pytest.raises(ValueError, match="T=131072"):
+        fa._bwd_vmem_limit(131072, 128, 2, fa._tiles(131072, 128, 2))
 
 
 @pytest.mark.parametrize("rows", [32768, 131072])
@@ -316,12 +370,10 @@ def test_flash_forward_build_error_raises_on_tpu(on_tpu, monkeypatch):
         fa.flash_attention(*_qkv(), True)
 
 
-@pytest.mark.parametrize("kernel", ["_pallas_flash_bwd_dq",
-                                    "_pallas_flash_bwd_dkv"])
-def test_flash_backward_build_error_raises(monkeypatch, kernel):
+def test_flash_backward_build_error_raises(monkeypatch):
     # forward through the interpreter (force_pallas=True off-TPU), then the
     # backward kernel fails to build: the reference VJP must not take over
-    monkeypatch.setattr(fa, kernel, _boom)
+    monkeypatch.setattr(fa, "_pallas_flash_bwd", _boom)
     with pytest.raises(_Boom):
         jax.grad(lambda a, b, c: fa.flash_attention(a, b, c, True, True)
                  .sum(), argnums=(0, 1, 2))(*_qkv())
@@ -379,7 +431,7 @@ def _decoder_gradient(monkeypatch, remat, body=None, **kinds) -> str:
 @pytest.mark.parametrize("remat", [True, False])
 def test_scanned_decoder_runs_attention_and_routing_once(on_tpu, monkeypatch, remat):
     """Rematerialised or not, the step holds each flash kernel once (the scan
-    body's forward, the backward body's two), one top-k and one sort: what
+    body's forward, the backward body's one), one top-k and one sort: what
     ``ConfigDecoder``'s policy keeps is not run again in the backward pass."""
     text = _decoder_gradient(monkeypatch, remat)
     for name in FLASH_KERNELS:
@@ -400,11 +452,11 @@ def test_a_period_of_four_holds_each_kind_once_a_layer(on_tpu, monkeypatch, rema
         monkeypatch, remat, sliding_window_layout=[0, 1, 1, 1] * 2,
         sliding_window_size=256, rope_layout=[0, 1, 1, 1] * 2,
         router_input="layer", expert_gate="relu", qk_norm=False)
-    for jitted in ("_pallas_flash_call", "_pallas_flash_bwd_dq", "_pallas_flash_bwd_dkv"):
+    for jitted in ("_pallas_flash_call", "_pallas_flash_bwd"):
         assert len(re.findall(rf"call @{jitted}(_\d+)?\(", text)) == 4, jitted
     # two masks and no more: the kernels' bodies as lowered (a body that
     # several layers share is lowered once)
-    assert 2 <= _kernel_calls(text, "bigdl_flash_bwd_dq") <= 4
+    assert 2 <= _kernel_calls(text, "bigdl_flash_bwd") <= 4
     if remat:       # (without it the sort is one shared function, called four times)
         assert text.count("stablehlo.sort") == 4
         assert text.count("@mhlo.topk") == 4
@@ -415,6 +467,6 @@ def test_whole_layer_rematerialisation_would_show(on_tpu, monkeypatch):
     count reads two, so the test above would see the policy go."""
     text = _decoder_gradient(monkeypatch, False, body=jax.checkpoint)
     assert _kernel_calls(text, "bigdl_flash_fwd") == 2
-    assert _kernel_calls(text, "bigdl_flash_bwd_dq") == 1
+    assert _kernel_calls(text, "bigdl_flash_bwd") == 1
     assert text.count("stablehlo.sort") == 2
     assert text.count("@mhlo.topk") == 2

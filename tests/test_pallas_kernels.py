@@ -268,6 +268,31 @@ class TestFlashTilings:
         self._check((1, 2, 1024, 64), dtype, causal, seed=1)
 
 
+# head_dim with the dtype it is run in: the GPT-2 cell's and the decoders'
+WIDTHS = [(64, jnp.bfloat16), (128, jnp.float32)]
+
+
+@pytest.mark.parametrize("d,dtype", WIDTHS)
+@pytest.mark.parametrize("group", [1, 4, 7])
+@pytest.mark.parametrize("mask", [None, "causal"])
+def test_fused_backward_matches_the_reference_vjp(
+        fused_backward_against_reference, mask, group, d, dtype):
+    """One kernel, three gradients: two spans of queries a head, two key
+    blocks of two chunks' length, so dq sums across key blocks in its scratch
+    and dk/dv across the spans and the group's heads in theirs (a group of 1,
+    of 4, and of 7, which is no power of two)."""
+    fused_backward_against_reference(mask, (group, 1), 512, d, dtype,
+                                     tiles=(256, 128, 256))
+
+
+@pytest.mark.parametrize("d,dtype", WIDTHS)
+def test_fused_backward_at_the_rules_own_tiles(
+        fused_backward_against_reference, d, dtype):
+    """Two key/value heads of two query heads each at T=1024: 512-row tiles,
+    one span, every pass the last (a block of dk/dv is written as it is made)."""
+    fused_backward_against_reference("causal", (4, 2), 1024, d, dtype)
+
+
 class TestFlashBackwardMemory:
     """Training at long T must not scale
     O(T^2). Pinned by shape math — the traced grad program may not contain

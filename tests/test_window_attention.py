@@ -96,6 +96,19 @@ def test_flash_kernels_under_a_causal_window(t, size):
     _against_dense(t, size)
 
 
+@pytest.mark.parametrize("d,dtype", [(64, jnp.bfloat16), (128, jnp.float32)])
+@pytest.mark.parametrize("group", [1, 4, 7])
+@pytest.mark.parametrize("size", [100, 300])
+def test_fused_backward_under_a_causal_window(
+        fused_backward_against_reference, size, group, d, dtype):
+    """The one backward kernel under a window shorter than a tile (100 of 128)
+    and one longer than a span (300 of 256): two spans of queries a head, key
+    blocks of two chunks' length, the key blocks a span does not see clamped
+    onto live ones."""
+    fused_backward_against_reference(fa.CausalWindow(size), (group, 1), 512, d,
+                                     dtype, tiles=(256, 128, 256))
+
+
 @pytest.mark.parametrize("size", [1024, 4096])
 def test_a_window_no_shorter_than_the_axis_is_the_causal_mask(size):
     out, grads = _against_dense(1024, size)

@@ -54,6 +54,15 @@ value in every lane of a register, and l as per-lane partial sums reduced
 once at the flush: one lane reduction (the max) a chunk instead of two
 reductions and two lane broadcasts. Statistics and accumulators are fp32.
 
+The value head may be narrower than the query/key head (latent attention's
+128 beside 192, PR 38): the forward's accumulator and output, ``dO``, ``D``
+and ``dv`` are ``d_v`` wide, ``q``, ``k``, ``dq`` and ``dk`` ``d``, nothing is
+padded, and the resident span and the backward's VMEM come from both widths.
+The softmax scale is static: ``1 / sqrt(d)`` unless a caller gives its own
+(YaRN's). At one width with no scale the kernels lower to the bundles they did
+before (PERF.md, PR 38); at 192 / 128 a 512x512 tile's loop is 1,594 bundles
+forward and 3,988 backward.
+
 Semantics: forward AND backward are Pallas kernels on TPU (interpreter
 elsewhere — tests). The backward is the flash-2 scheme in one kernel
 (``bigdl_flash_bwd``, PR 37; two kernels before it, dq and dk/dv, each of which
@@ -178,17 +187,21 @@ def dense_mask(mask, t: int):
     return jnp.where(kn, qn & (kb == qb), jnp.where(qn, kb < qb, kb <= qb))
 
 
-def _reference_attention(q, k, v, causal=False):
+def _reference_attention(q, k, v, causal=False, scale=None):
     """Plain jnp attention over (..., T, d) — the numerical oracle and VJP.
     ``causal`` is a boolean or a mask description; ``k`` and ``v`` may hold
-    fewer heads than ``q`` (query head ``i`` reads head ``i // group``)."""
+    fewer heads than ``q`` (query head ``i`` reads head ``i // group``), and
+    ``v`` a head of its own width. ``scale`` defaults to ``1 / sqrt(d)``."""
     mask = _as_mask(causal)
     d = q.shape[-1]
     if q.ndim == 4 and k.shape[1] != q.shape[1]:
         group = q.shape[1] // k.shape[1]
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32)
-    s = s / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if scale is None:
+        s = s / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    else:
+        s = s * scale
     if mask is not None:
         s = jnp.where(dense_mask(mask, s.shape[-1]), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
@@ -418,7 +431,7 @@ def _block_diffusion(s, query_keys, key_value):
 # with equal shapes, and it is then traced and lowered to Mosaic once a
 # program, not once a layer (6 s of an LM step's 8 s of lowering, 24 layers).
 _kernel = functools.partial(
-    jax.jit, static_argnames=("mask", "tiles", "interpret"))
+    jax.jit, static_argnames=("mask", "tiles", "interpret", "scale"))
 
 
 def _kv_map(block_q, span, mask, group):
@@ -439,16 +452,17 @@ def _kv_map(block_q, span, mask, group):
 
 
 @_kernel
-def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
+def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret, scale=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    d_v = v3.shape[-1]
     group = bh // k3.shape[0]
     block_q, block_k, span = tiles
     diffusion = isinstance(mask, BlockDiffusion)
     windowed = isinstance(mask, CausalWindow)
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     n_span, per_span = t // span, span // block_k
     # the running max and sum keep a row's value in every lane of a register
     # (as the (block_q, block_k) tile meets them), not in one lane of 128
@@ -494,7 +508,7 @@ def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
             # l stays a sum by lane until the flush: adds, no lane reduction
             l_scr[:] = l_scr[:] * alpha + sum(
                 p[:, c:c + width] for c in range(0, block_k, width))
-            acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jax.lax.dot_general(
+            acc_scr[:] = acc_scr[:] * _lanes(alpha, d_v) + jax.lax.dot_general(
                 p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
             m_scr[:] = m_new
 
@@ -513,20 +527,20 @@ def _pallas_flash_call(q3, k3, v3, mask, tiles, interpret):
     kv_map = _kv_map(block_q, span, mask, group)
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=[out_struct((bh, t, d), q3.dtype, q3, k3, v3),
+        out_shape=[out_struct((bh, t, d_v), q3.dtype, q3, k3, v3),
                    out_struct((bh, t, 1), jnp.float32, q3, k3, v3)],
         grid=(bh, t // block_q, n_span),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
             pl.BlockSpec((1, span, d), kv_map),
-            pl.BlockSpec((1, span, d), kv_map),
+            pl.BlockSpec((1, span, d_v), kv_map),
         ],
-        out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+        out_specs=[pl.BlockSpec((1, block_q, d_v), lambda b, i, s: (b, i, 0)),
                    pl.BlockSpec((1, block_q, 1), lambda b, i, s: (b, i, 0))],
         scratch_shapes=[
             pltpu.VMEM((block_q, width), jnp.float32),
             pltpu.VMEM((block_q, width), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -546,35 +560,39 @@ def _rows(ref, j, size):
     return pl.ds(pl.multiple_of(j * size, size), size)
 
 
-def _bwd_vmem_limit(t: int, d: int, itemsize: int, tiles: _Tiles) -> int:
+def _bwd_vmem_limit(t: int, d: int, itemsize: int, tiles: _Tiles,
+                    d_v: int | None = None) -> int:
     """The VMEM the backward kernel asks for, from the shapes: what its plan
     holds (the resident spans of q and dO and the rows of their residuals,
     double-buffered, a block of k and of v, dq's fp32 span with its output
     block, the head-long fp32 sums of dk and dv with their output blocks, and
-    ``_TILE_TEMPORARIES`` fp32 tiles of block x chunk; a row of fewer than 128
-    lanes takes 128, a ``(1, span)`` row of residuals 8 sublanes), or the
-    forward's limit where that is more. The temporaries are counted
+    ``_TILE_TEMPORARIES`` fp32 tiles of block x chunk; q, k, dq and dk are
+    ``d`` wide, v, dO and dv ``d_v`` (default ``d``); a row takes whole
+    registers of 128 lanes, a ``(1, span)`` row of residuals 8 sublanes), or
+    the forward's limit where that is more. The temporaries are counted
     generously: Mosaic builds the SmallThinker cell's shape within 36 MiB of
     the plan's 42, the SDAR cell's within 28 of 34. A sequence whose head-long
     sums pass the ceiling is refused here, by name, not by the compiler."""
     block, chunk, span = tiles
     lanes = -(-d // 128) * 128
-    operands = 2 * (2 * span + 2 * block) * lanes * itemsize
+    lanes_v = lanes if d_v is None else -(-d_v // 128) * 128
+    operands = 2 * (span + block) * (lanes + lanes_v) * itemsize
     residuals = 2 * 2 * 8 * span * 4
     dq = span * lanes * (4 + 2 * itemsize)
-    dkv = 2 * (t * lanes * 4 + 2 * block * lanes * itemsize)
+    dkv = (t * (lanes + lanes_v) * 4 + 2 * block * (lanes + lanes_v) * itemsize)
     need = (operands + residuals + dq + dkv
             + _TILE_TEMPORARIES * block * chunk * 4)
     if need > _VMEM_CEILING_BYTES:
         raise ValueError(
             f"flash attention's backward keeps fp32 sums of dk and dv as long "
-            f"as the sequence in VMEM: T={t} at head_dim {d} needs "
+            f"as the sequence in VMEM: T={t} at head_dim {d}/{d_v or d} needs "
             f"{need >> 20} MiB of {_VMEM_CEILING_BYTES >> 20}")
     return max(need, _VMEM_LIMIT_BYTES)
 
 
 @_kernel
-def _pallas_flash_bwd(q3, k3, v3, do3, lse_row, dd_row, mask, tiles, interpret):
+def _pallas_flash_bwd(q3, k3, v3, do3, lse_row, dd_row, mask, tiles, interpret,
+                      scale=None):
     """All three gradients from one walk of the live tiles. A grid step owns a
     block of keys, in the transposed orientation (keys down the sublanes), and
     walks the chunks of the resident span of queries that see it; a tile makes
@@ -588,17 +606,19 @@ def _pallas_flash_bwd(q3, k3, v3, do3, lse_row, dd_row, mask, tiles, interpret):
     written once a (query head, span); dk and dv sum in fp32 scratch as long as
     the head across the spans and the group's heads, and a block of them is
     written in the last of those passes (until then the output's index map
-    names block 0, which is not copied out before that pass has filled it)."""
+    names block 0, which is not copied out before that pass has filled it).
+    q, k, dq and dk are ``d`` wide; v, dO and dv ``d_v``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
+    d_v = v3.shape[-1]
     bkv = k3.shape[0]
     group = bh // bkv
     block_k, block_q, span = tiles
     diffusion = isinstance(mask, BlockDiffusion)
     windowed = isinstance(mask, CausalWindow)
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     n_span, per_span, n_block = t // span, span // block_q, t // block_k
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
@@ -615,7 +635,7 @@ def _pallas_flash_bwd(q3, k3, v3, do3, lse_row, dd_row, mask, tiles, interpret):
         @pl.when(first_pass)
         def _init_dkv():
             dk_scr[own] = jnp.zeros((block_k, d), jnp.float32)
-            dv_scr[own] = jnp.zeros((block_k, d), jnp.float32)
+            dv_scr[own] = jnp.zeros((block_k, d_v), jnp.float32)
 
         k = k_ref[0]
         k_scaled = k * scale
@@ -683,25 +703,25 @@ def _pallas_flash_bwd(q3, k3, v3, do3, lse_row, dd_row, mask, tiles, interpret):
         kernel,
         out_shape=[out_struct((bh, t, d), q3.dtype, q3, k3, v3, do3),
                    out_struct((bkv, t, d), k3.dtype, q3, k3, v3, do3),
-                   out_struct((bkv, t, d), v3.dtype, q3, k3, v3, do3)],
+                   out_struct((bkv, t, d_v), v3.dtype, q3, k3, v3, do3)],
         grid=(bkv, group, n_span, n_block),
         in_specs=[
             pl.BlockSpec((1, span, d), q_map),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, span, d), q_map),
+            pl.BlockSpec((1, block_k, d_v), kv_map),
+            pl.BlockSpec((1, span, d_v), q_map),
             pl.BlockSpec((1, 1, span), row_map),
             pl.BlockSpec((1, 1, span), row_map),
         ],
         out_specs=[pl.BlockSpec((1, span, d), q_map),
                    pl.BlockSpec((1, block_k, d), out_map),
-                   pl.BlockSpec((1, block_k, d), out_map)],
+                   pl.BlockSpec((1, block_k, d_v), out_map)],
         scratch_shapes=[pltpu.VMEM((span, d), jnp.float32),
                         pltpu.VMEM((t, d), jnp.float32),
-                        pltpu.VMEM((t, d), jnp.float32)],
+                        pltpu.VMEM((t, d_v), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) + ("arbitrary",) * 3,
-            vmem_limit_bytes=_bwd_vmem_limit(t, d, q3.dtype.itemsize, tiles)),
+            vmem_limit_bytes=_bwd_vmem_limit(t, d, q3.dtype.itemsize, tiles, d_v)),
         interpret=interpret,
         name="bigdl_flash_bwd",
     )(q3, k3, v3, do3, lse_row, dd_row)
@@ -732,24 +752,27 @@ def _pick_block(t: int, target: int) -> int | None:
 _TILE_ROWS = 512
 
 
-def _tiles(t: int, d: int, itemsize: int) -> _Tiles | None:
+def _tiles(t: int, d: int, itemsize: int, d_v: int | None = None) -> _Tiles | None:
     """The kernels' tiles from what the call can see: ``block`` and ``chunk``
     by ``_pick_block`` towards ``_TILE_ROWS``, ``span`` the longest legal
-    tile whose two resident operands, double-buffered, stay within
-    ``_RESIDENT_BYTES``. ``None`` where ``_pick_block(t, 128)`` is: a chunk
-    is then the whole axis or a multiple of 128 that divides the span."""
+    tile whose two resident operands (one ``d`` wide, one ``d_v``, default
+    ``d``), double-buffered, stay within ``_RESIDENT_BYTES``. ``None`` where
+    ``_pick_block(t, 128)`` is: a chunk is then the whole axis or a multiple
+    of 128 that divides the span."""
     if _pick_block(t, 128) is None:
         return None
-    span = _pick_block(t, max(128, _RESIDENT_BYTES // (4 * d * itemsize)))
+    width = d + (d if d_v is None else d_v)
+    span = _pick_block(t, max(128, _RESIDENT_BYTES // (2 * width * itemsize)))
     return _Tiles(_pick_block(t, _TILE_ROWS), _pick_block(span, _TILE_ROWS),
                   span)
 
 
-def _tiles_under(mask, t: int, d: int, itemsize: int) -> _Tiles | None:
+def _tiles_under(mask, t: int, d: int, itemsize: int,
+                 d_v: int | None = None) -> _Tiles | None:
     """``_tiles``, and under a block-diffusion mask over more than one tile a
     block and a chunk that divide the clean length: no tile then straddles
     the axis' middle, and the first chunk a row meets holds a key it sees."""
-    tiles = _tiles(t, d, itemsize)
+    tiles = _tiles(t, d, itemsize, d_v)
     if tiles is None or not isinstance(mask, BlockDiffusion) or tiles.block == t:
         return tiles
     tile = _pick_block(mask.length, _TILE_ROWS)
@@ -758,13 +781,18 @@ def _tiles_under(mask, t: int, d: int, itemsize: int) -> _Tiles | None:
     return _Tiles(tile, tile, tiles.span)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False,
-                    force_pallas: bool | None = None, mask=None):
-    """Streaming-softmax attention: ``q`` (batch, heads, T, d), ``k`` and ``v``
-    (batch, kv_heads, T, d) with ``kv_heads`` dividing ``heads`` (query head
-    ``i`` reads key/value head ``i // (heads // kv_heads)``; the kernels' index
-    maps do that, nothing is repeated in memory).
+                    force_pallas: bool | None = None, mask=None,
+                    scale: float | None = None):
+    """Streaming-softmax attention: ``q`` and ``k`` (batch, heads / kv_heads,
+    T, d), ``v`` (batch, kv_heads, T, d_v) with ``kv_heads`` dividing ``heads``
+    (query head ``i`` reads key/value head ``i // (heads // kv_heads)``; the
+    kernels' index maps do that, nothing is repeated in memory); the output is
+    (batch, heads, T, d_v). ``d_v`` may differ from ``d`` (latent attention's
+    192 and 128): the forward's accumulator, ``dO``, ``D`` and ``dv`` are
+    ``d_v`` wide, ``dq`` and ``dk`` ``d``, and nothing is padded. ``scale`` is
+    the softmax's, a static number; ``None`` is ``1 / sqrt(d)``.
 
     ``mask``: ``None`` for what ``causal`` says, or a static description:
     ``"causal"``, :class:`CausalWindow` or :class:`BlockDiffusion`. ``force_pallas``: None = pallas on
@@ -772,7 +800,7 @@ def flash_attention(q, k, v, causal: bool = False,
     False = reference. Whatever the setting, a ``T`` that ``_pick_block``
     cannot tile runs the reference.
     """
-    return _fa_fwd(q, k, v, causal, force_pallas, mask)[0]
+    return _fa_fwd(q, k, v, causal, force_pallas, mask, scale)[0]
 
 
 #: What the backward kernel reads, by the names ``_fa_fwd`` tags them with
@@ -781,15 +809,15 @@ def flash_attention(q, k, v, causal: bool = False,
 RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
 
 
-def _fa_fwd(q, k, v, causal, force_pallas, mask):
+def _fa_fwd(q, k, v, causal, force_pallas, mask, scale=None):
     """The forward kernel and what the backward kernel reads: ``q``, ``k``,
     ``v`` as they enter (after a caller's norms, RoPE and layout copies),
     ``out`` and the rows' logsumexp, each tagged with its name of
     ``RESIDUAL_NAMES``. A tag is the identity unless a ``jax.checkpoint``
     policy asks for its name (``ConfigDecoder``'s does): then these stay
     across the backward pass and the forward kernel is not run again, for
-    ``2 * (2 * heads + 2 * kv_heads) * T * head_dim`` bytes a batch row in
-    bf16 (q and out, k and v) and ``4 * heads * T`` of logsumexp. The
+    ``2 * (heads + kv_heads) * (d + d_v) * T`` bytes a batch row in bf16 (q
+    and k at ``d``, v and out at ``d_v``) and ``4 * heads * T`` of logsumexp. The
     logsumexp is tagged as the kernel writes it, ``(bh, T, 1)``, which pads to
     128 lanes only where a kernel reads it: XLA lays a stack of them out with
     the unit axis outermost, so a scan holds the numbers alone (PERF.md, PR
@@ -803,42 +831,46 @@ def _fa_fwd(q, k, v, causal, force_pallas, mask):
                          f"operands have {q.shape[2]}")
     use_pallas = _on_tpu() if force_pallas is None else force_pallas
     b, h, t, d = q.shape
-    hkv = k.shape[1]
-    tiles = _tiles_under(mask, t, d, q.dtype.itemsize)
+    hkv, d_v = k.shape[1], v.shape[-1]
+    tiles = _tiles_under(mask, t, d, q.dtype.itemsize, d_v)
     if not use_pallas or tiles is None:
-        out = checkpoint_name(_reference_attention(q, k, v, mask), "flash_out")
+        out = checkpoint_name(_reference_attention(q, k, v, mask, scale), "flash_out")
         return out, (q, k, v, None, None)
     out, lse = _pallas_flash_call(
         q.reshape(b * h, t, d), k.reshape(b * hkv, t, d),
-        v.reshape(b * hkv, t, d), mask, tiles, interpret=not _on_tpu())
-    out = checkpoint_name(out.reshape(b, h, t, d), "flash_out")
+        v.reshape(b * hkv, t, d_v), mask, tiles, interpret=not _on_tpu(),
+        scale=scale)
+    out = checkpoint_name(out.reshape(b, h, t, d_v), "flash_out")
     return out, (q, k, v, out, checkpoint_name(lse, "flash_lse"))
 
 
-def _fa_bwd(causal, force_pallas, mask, res, g):
+def _fa_bwd(causal, force_pallas, mask, scale, res, g):
     mask = _as_mask(causal, mask)
     q, k, v, out, lse = res
     if lse is None:
         _, vjp = jax.vjp(
-            lambda qq, kk, vv: _reference_attention(qq, kk, vv, mask),
+            lambda qq, kk, vv: _reference_attention(qq, kk, vv, mask, scale),
             q, k, v)
         return vjp(g)
-    return _flash_bwd(q, k, v, out, lse, g, mask)
+    return _flash_bwd(q, k, v, out, lse, g, mask, scale)
 
 
-def _flash_bwd(q, k, v, out, lse, g, mask):
+def _flash_bwd(q, k, v, out, lse, g, mask, scale=None):
     """Streaming flash-2 backward: O(T·d) memory, probability tiles recomputed
     from (q, k, lse) in VMEM."""
     t, d = q.shape[2:]
-    tiles = _tiles_under(mask, t, d, q.dtype.itemsize)    # not None: _fa_fwd checked
-    reshape = lambda a: a.reshape(-1, t, d)
+    d_v = v.shape[-1]
+    # not None: _fa_fwd checked
+    tiles = _tiles_under(mask, t, d, q.dtype.itemsize, d_v)
+    reshape = lambda a: a.reshape(-1, t, a.shape[-1])
     q3, k3, v3, do3 = reshape(q), reshape(k), reshape(v), reshape(g)
     # D_i = rowsum(dO * O): one fused elementwise pass, O(T·d) reads; with the
     # logsumexp it meets the kernel as a row, (bh, 1, t)
     dd = jnp.sum(do3.astype(jnp.float32) * reshape(out).astype(jnp.float32),
                  axis=-1)[:, None]
     dq, dk, dv = _pallas_flash_bwd(q3, k3, v3, do3, lse.reshape(dd.shape), dd,
-                                   mask, tiles, interpret=not _on_tpu())
+                                   mask, tiles, interpret=not _on_tpu(),
+                                   scale=scale)
     unshape = lambda a, like: a.reshape(like.shape).astype(like.dtype)
     return unshape(dq, q), unshape(dk, k), unshape(dv, v)
 

@@ -33,19 +33,34 @@ into periods where the scan takes them. A pattern of one kind is a period of
 one: the scan body is then the one layer and takes the stacked weights as
 they are.
 
+Latent attention (DeepSeek-V2's MLA, ``nn.LatentAttention``) takes the
+attention template's place where a config names ``kv_lora_rank`` (with
+``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim`` and, for YaRN,
+``rope_scaling``; ``q_lora_rank`` must be null): one kind of layer, causal.
+``first_k_dense_replace`` leading layers are dense, a gated MLP of width
+``intermediate_size`` after attention, on their own weights (``dense`` in the
+parameter tree, stacked the same way); they run each once before the scan,
+which runs over the routed layers that follow. ``n_shared_experts`` gives the
+routed layer its shared experts (``MoE(shared=)``). A config without these
+keys builds the decoder it did.
+
 The modules are the layer's templates: the scan body calls their ``apply`` on
 a layer's slice of the stacked parameters, attention under the scope
-``bigdl_attn_window`` or ``bigdl_attn_full`` by kind
-(``obs/trace.py``). ``remat`` rematerialises the layers, each on its own: the
+``bigdl_attn_window``, ``bigdl_attn_full`` or ``bigdl_attn_latent`` by kind
+and a dense layer's MLP under ``bigdl_mlp_dense`` (``obs/trace.py``).
+``remat`` rematerialises the layers, dense and routed, each on its own: the
 scan keeps, a layer, its input and what is dear to make again (``KEPT``: the
 flash kernels' ``q``, ``k``, ``v``, ``out`` and logsumexp, the routing, the
-hidden state after attention), ``N * T * ((2 * heads + 2 * kv_heads) *
-head_dim + 2 * hidden) * 2`` bytes and the routing's ``12 * N * T * top_k``,
-0.44 GB a layer at 2 x 8,192 positions, 32 + 4 + 4 heads of 128 and hidden
-2,048 in bf16; the norms, the projections into the heads (the per-head norms'
-backward reads their results), the router's logits and its softmax run again
-in the backward pass, the flash forward kernel, the output projection, top-k
-and the sort do not. ``remat=False`` keeps every value of every layer.
+hidden state after attention), ``N * T * ((heads + kv_heads) * (d_qk + d_v)
++ 2 * hidden) * 2`` bytes, with ``d_qk`` the query/key head and ``d_v`` the
+value head (both ``head_dim`` but under latent attention, where ``kv_heads``
+is ``heads``), and the routing's ``12 * N * T * top_k``: 0.44 GB a layer at 2
+x 8,192 positions, 32 + 4 + 4 heads of 128 and hidden 2,048 in bf16, 0.47 GB
+at 16,384 positions, 16 + 16 heads of 192 / 128 and hidden 2,048; the norms,
+the projections into the heads (the per-head norms' backward reads their
+results), the router's logits and its softmax run again in the backward pass,
+the flash forward kernel, the output projection, top-k and the sort do not.
+``remat=False`` keeps every value of every layer.
 
 ``block_diffusion=(L, b)`` trains by diffusion over blocks (BD3-LMs,
 arXiv:2503.09573): the input is ``[x_t ; x_0]``, a noised copy of a sequence of
@@ -74,7 +89,7 @@ from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.criterion import AbstractCriterion
 from bigdl_tpu.nn.fused_loss import chunked_softmax_xent
 from bigdl_tpu.obs import trace
-from bigdl_tpu.parallel.moe import ROUTING_NAMES, MoE
+from bigdl_tpu.parallel.moe import ROUTING_NAMES, MoE, gated_mlp
 from bigdl_tpu.utils.random_generator import RandomGenerator
 from bigdl_tpu.utils.table import Table
 
@@ -117,17 +132,21 @@ class ConfigDecoder(TensorModule):
                    "moe_intermediate_size", "num_experts",
                    "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps",
                    "rope_theta", "initializer_range", "sliding_window_layout",
-                   "sliding_window_size", "rope_layout")
+                   "sliding_window_size", "rope_layout", "kv_lora_rank",
+                   "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                   "v_head_dim", "rope_scaling", "first_k_dense_replace",
+                   "intermediate_size", "n_shared_experts")
     #: other spellings of those keys that published configs use
     CONFIG_ALIASES = {"moe_ffn_hidden_size": "moe_intermediate_size",
                       "moe_num_primary_experts": "num_experts",
-                      "moe_num_active_primary_experts": "num_experts_per_tok"}
+                      "moe_num_active_primary_experts": "num_experts_per_tok",
+                      "n_routed_experts": "num_experts"}
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  num_hidden_layers: int, num_attention_heads: int,
-                 num_key_value_heads: int, head_dim: int,
-                 moe_intermediate_size: int, num_experts: int,
-                 num_experts_per_tok: int, norm_topk_prob: bool = True,
+                 num_key_value_heads: int, head_dim: Optional[int] = None,
+                 moe_intermediate_size: int = None, num_experts: int = None,
+                 num_experts_per_tok: int = None, norm_topk_prob: bool = True,
                  rms_norm_eps: float = 1e-6, rope_theta: float = 10000.0,
                  initializer_range: float = 0.02,
                  held: Optional[tuple] = None, qk_norm: bool = True,
@@ -135,10 +154,22 @@ class ConfigDecoder(TensorModule):
                  sliding_window_layout: Optional[Sequence[int]] = None,
                  sliding_window_size: Optional[int] = None,
                  rope_layout: Optional[Sequence[int]] = None,
-                 router_input: str = "experts", expert_gate: str = "silu"):
+                 router_input: str = "experts", expert_gate: str = "silu",
+                 kv_lora_rank: Optional[int] = None,
+                 q_lora_rank: Optional[int] = None,
+                 qk_nope_head_dim: Optional[int] = None,
+                 qk_rope_head_dim: Optional[int] = None,
+                 v_head_dim: Optional[int] = None,
+                 rope_scaling: Optional[dict] = None,
+                 first_k_dense_replace: int = 0,
+                 intermediate_size: Optional[int] = None,
+                 n_shared_experts: int = 0):
         super().__init__()
         self.vocab_size, self.hidden_size = int(vocab_size), int(hidden_size)
-        self.num_hidden_layers = n = int(num_hidden_layers)
+        #: the leading dense layers, before the scan over the routed ones
+        self.dense_layers = k = int(first_k_dense_replace or 0)
+        self.num_hidden_layers = int(num_hidden_layers)
+        n = self.num_hidden_layers - k
         self.initializer_range = float(initializer_range)
         self.remat = bool(remat)
         self.block_diffusion = block_diffusion and tuple(block_diffusion)
@@ -160,23 +191,46 @@ class ConfigDecoder(TensorModule):
             for w, r in zip(windowed, turned)])
         mask = BlockDiffusion(*self.block_diffusion) if self.block_diffusion else None
         self.norm = nn.RMSNorm(hidden_size, eps=rms_norm_eps)
-        #: one attention template a layer of the period
-        self.attentions = [nn.MultiHeadAttention(
-            hidden_size, num_attention_heads, causal=mask is None,
-            with_bias=False, num_kv_heads=num_key_value_heads, rope=kind.rope,
-            rope_base=rope_theta, window=kind.window, head_dim=head_dim,
-            qk_norm=qk_norm, qk_norm_eps=rms_norm_eps, mask=mask)
-            for kind in self.period]
+        if kv_lora_rank is not None:
+            if q_lora_rank is not None:
+                raise ValueError("latent attention with a query low-rank path "
+                                 "(q_lora_rank) is not built")
+            if mask is not None or len(self.period) > 1 or self.period[0].window:
+                raise ValueError("latent attention is causal over every key: "
+                                 "no mask, window or layout beside it")
+            #: the one attention template (latent attention: one kind of layer)
+            self.attentions = [nn.LatentAttention(
+                hidden_size, num_attention_heads, kv_lora_rank, qk_nope_head_dim,
+                qk_rope_head_dim, v_head_dim, rope_base=rope_theta,
+                rope_scaling=rope_scaling, norm_eps=rms_norm_eps)]
+        else:
+            #: one attention template a layer of the period
+            self.attentions = [nn.MultiHeadAttention(
+                hidden_size, num_attention_heads, causal=mask is None,
+                with_bias=False, num_kv_heads=num_key_value_heads, rope=kind.rope,
+                rope_base=rope_theta, window=kind.window, head_dim=head_dim,
+                qk_norm=qk_norm, qk_norm_eps=rms_norm_eps, mask=mask)
+                for kind in self.period]
         self.experts = MoE(hidden_size, moe_intermediate_size, num_experts,
                            router="topk", top_k=num_experts_per_tok,
                            norm_topk_prob=norm_topk_prob, held=held,
-                           gate=expert_gate)
+                           gate=expert_gate,
+                           shared=int(n_shared_experts or 0) * moe_intermediate_size)
+        self.expert_gate = expert_gate
         # the templates lend their shapes; their own copies are never read
-        self._layer_shapes = jax.tree_util.tree_map(lambda a: a.shape, {
-            "attn": self.attentions[0].get_params(),
-            "attn_norm": self.norm.get_params()["weight"],
-            "moe": self.experts.get_params(),
-            "moe_norm": self.norm.get_params()["weight"]})
+        norm_shape = self.norm.get_params()["weight"].shape
+        attn_shapes = jax.tree_util.tree_map(lambda a: a.shape,
+                                             self.attentions[0].get_params())
+        self._layer_shapes = {"attn": attn_shapes, "attn_norm": norm_shape,
+                              "moe": jax.tree_util.tree_map(
+                                  lambda a: a.shape, self.experts.get_params()),
+                              "moe_norm": norm_shape}
+        if k:
+            self._dense_shapes = {
+                "attn": attn_shapes, "attn_norm": norm_shape,
+                "mlp": {"w_in": (hidden_size, 2 * intermediate_size),
+                        "w_out": (intermediate_size, hidden_size)},
+                "mlp_norm": norm_shape}
         for template in (*self.attentions, self.experts, self.norm):
             template._params, template._grads = {}, {}
         self.reset()
@@ -193,15 +247,17 @@ class ConfigDecoder(TensorModule):
     def reset(self) -> None:
         """Matrices N(0, ``initializer_range``), gains 1, drawn on the device
         from the global generator's next salt."""
-        n, std = self.num_hidden_layers, self.initializer_range
+        k, std = self.dense_layers, self.initializer_range
         is_shape = lambda v: isinstance(v, tuple)
         # (shape, is a gain): a gain holds one number a channel
+        stack = lambda n, shapes: jax.tree_util.tree_map(
+            lambda shape: ((n,) + shape, len(shape) == 1), shapes, is_leaf=is_shape)
         spec = {"embed": ((self.vocab_size, self.hidden_size), False),
                 "final_norm": ((self.hidden_size,), True),
                 "head": ((self.vocab_size, self.hidden_size), False),
-                "layers": jax.tree_util.tree_map(
-                    lambda shape: ((n,) + shape, len(shape) == 1),
-                    self._layer_shapes, is_leaf=is_shape)}
+                "layers": stack(self.num_hidden_layers - k, self._layer_shapes)}
+        if k:
+            spec["dense"] = stack(k, self._dense_shapes)
         leaves, treedef = jax.tree_util.tree_flatten(spec, is_leaf=is_shape)
 
         def draw(key):
@@ -242,9 +298,12 @@ class ConfigDecoder(TensorModule):
         positions = self._positions(input.shape[1])
         norm, experts_state = self.norm, self.experts.get_state()
 
-        def layer_of(attention):
-            scope = trace.SCOPE_ATTN_FULL if attention.window is None \
-                else trace.SCOPE_ATTN_WINDOW
+        def layer_of(attention, dense=False):
+            if isinstance(attention, nn.LatentAttention):
+                scope = trace.SCOPE_ATTN_LATENT
+            else:
+                scope = trace.SCOPE_ATTN_FULL if attention.window is None \
+                    else trace.SCOPE_ATTN_WINDOW
 
             def layer(h, p):
                 a, _ = norm.apply({"weight": p["attn_norm"]}, {}, h)
@@ -252,6 +311,11 @@ class ConfigDecoder(TensorModule):
                     a, _ = attention.apply(p["attn"], {}, (a, positions),
                                            training=training)
                 after = checkpoint_name(h + a, "decoder_after_attention")
+                if dense:
+                    m, _ = norm.apply({"weight": p["mlp_norm"]}, {}, after)
+                    with jax.named_scope(trace.SCOPE_MLP_DENSE):
+                        return after + gated_mlp(m, p["mlp"]["w_in"], p["mlp"]["w_out"],
+                                                 self.expert_gate)
                 m, _ = norm.apply({"weight": p["moe_norm"]}, {}, after)
                 m, health = self.experts.apply(
                     p["moe"], experts_state,
@@ -264,6 +328,11 @@ class ConfigDecoder(TensorModule):
                                       .save_only_these_names(*KEPT))
             return layer
 
+        if self.dense_layers:
+            # the leading dense layers, each once, on their own weights
+            dense = layer_of(self.attentions[0], dense=True)
+            for i in range(self.dense_layers):
+                h = dense(h, jax.tree_util.tree_map(lambda a: a[i], params["dense"]))
         layers = [layer_of(attention) for attention in self.attentions]
         if len(layers) == 1:
             body, stacked = layers[0], params["layers"]
@@ -295,6 +364,8 @@ class ConfigDecoder(TensorModule):
 
     def __repr__(self):
         kinds = "" if len(self.period) == 1 else f" in periods of {list(self.period)}"
+        if self.dense_layers:
+            kinds += f", the first {self.dense_layers} dense"
         return (f"ConfigDecoder({self.num_hidden_layers} layers{kinds}, "
                 f"hidden={self.hidden_size}, {self.attentions[0]!r}, "
                 f"{self.experts!r})")

@@ -1,7 +1,8 @@
 """Torch-style layer library (flat namespace, mirroring the reference's ``<dl>/nn/``)."""
 
 from bigdl_tpu.nn.abstractnn import AbstractModule, Container, TensorModule
-from bigdl_tpu.nn.attention import CrossAttention, MultiHeadAttention, rope_rotate
+from bigdl_tpu.nn.attention import (CrossAttention, LatentAttention, MultiHeadAttention,
+                                   rope_rotate, yarn_inv_freq, yarn_mscale)
 from bigdl_tpu.nn.activation import (
     Abs, AddConstant, BinaryThreshold, Clamp, ELU, Exp, GELU, HardSigmoid, HardTanh,
     LeakyReLU, Log, LogSigmoid, LogSoftMax, MulConstant, Power, PReLU, ReLU, ReLU6,

@@ -10,6 +10,7 @@ the same module scales from one chip to a sequence-parallel mesh unchanged.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -20,7 +21,7 @@ from bigdl_tpu.nn.initialization import InitializationMethod, Xavier
 
 
 def rope_rotate(x: jnp.ndarray, positions: jnp.ndarray,
-                base: float = 10000.0) -> jnp.ndarray:
+                base: float = 10000.0, inv_freq=None) -> jnp.ndarray:
     """Rotary position embedding (split-half convention): ``x (..., t, d)``
     rotated by per-position angles ``positions (t,)``. Each (x[i], x[i+d/2])
     pair turns by ``pos / base^(2i/d)`` — attention scores then depend only
@@ -30,10 +31,14 @@ def rope_rotate(x: jnp.ndarray, positions: jnp.ndarray,
 
     ``positions`` may also be (b, t) — per-BATCH-ROW absolute positions, the
     continuous-batching decode case where every cache slot sits at its own
-    depth; ``x`` is then (b, h, t, d) and the angles broadcast over heads."""
+    depth; ``x`` is then (b, h, t, d) and the angles broadcast over heads.
+
+    ``inv_freq`` (``d / 2`` numbers) stands in ``base``'s frequencies where a
+    scaled RoPE gives its own (``yarn_inv_freq``)."""
     d = x.shape[-1]
     half = d // 2
-    inv_freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        inv_freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., t, half)
     if positions.ndim == 2:
         ang = ang[:, None]                 # (b, 1, t, half): broadcast heads
@@ -576,3 +581,144 @@ class CrossAttention(TensorModule):
 
     def __repr__(self):
         return f"CrossAttention(embed={self.embed_dim}, heads={self.num_heads})"
+
+
+# ------------------------------------------------------------ latent attention
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched ``factor`` times:
+    ``0.1 * mscale * ln(factor) + 1`` (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32, beta_slow: float = 1) -> jnp.ndarray:
+    """YaRN's ``dim / 2`` frequencies (Peng et al., arXiv:2309.00071, as the
+    published ``DeepseekV2YarnRotaryEmbedding`` computes them): frequency
+    ``i`` of ``base``'s own, ``f = base^(-2i/dim)``, is kept where it turns
+    more than ``beta_fast`` times over the original context, divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, and blended
+    linearly between (the correction range ``[low, high]``, 10 and 23 for 64
+    channels at base 1e4, 4,096 positions and betas 32 and 1)."""
+    def turns_at(rotations):
+        return dim * math.log(original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((i - low) / max(high - low, 0.001), 0.0, 1.0)
+    extra = 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def _halves(x):
+    """Channels read as interleaved pairs ``(2j, 2j+1)`` put into halves
+    (the evens, then the odds), as the published ``apply_rotary_pos_emb``
+    does before it turns them by halves."""
+    d = x.shape[-1]
+    return jnp.swapaxes(x.reshape(x.shape[:-1] + (d // 2, 2)), -1, -2) \
+        .reshape(x.shape)
+
+
+class LatentAttention(TensorModule):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) without a
+    query low-rank path, causal, over (batch, T, embed) or ``(x, positions)``:
+
+        q = x Wq                          heads of qk_nope + qk_rope
+        [c ; k_pe] = x Wkv_a              the latent (kv_lora_rank) and one
+                                          rope head shared by all heads
+        [k_nope ; v] = RMSNorm(c) Wkv_b   heads of qk_nope + v_head_dim
+        k_i = [k_nope_i ; RoPE(k_pe)],    q_i = [q_nope_i ; RoPE(q_pe_i)]
+        o_i = softmax(scale q_i k_i^T + causal) v_i,   out = concat(o) Wo
+
+    RoPE turns the rope channels alone, read as interleaved pairs (the
+    published code's pairing), with YaRN's frequencies and ``scale`` its
+    temperature squared over ``sqrt(qk_nope + qk_rope)`` where
+    ``rope_scaling`` (a config's ``{"type": "yarn", ...}``) is given, plain
+    RoPE at ``rope_base`` and ``1 / sqrt(qk_nope + qk_rope)`` where not.
+    Attention runs in the flash kernels with the query/key head and the
+    value head at their own widths (192 and 128 at DeepSeek-V2's), nothing
+    padded; ``k_pe`` is broadcast to every head. Parameters (out, in):
+    ``q_weight``, ``kv_a_weight``, ``kv_norm`` (the latent's gain),
+    ``kv_b_weight`` (per head ``k_nope`` above ``v``), ``out_weight``."""
+
+    def __init__(self, embed_dim: int, num_heads: int, kv_lora_rank: int,
+                 qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
+                 rope_base: float = 10000.0, rope_scaling: Optional[dict] = None,
+                 norm_eps: float = 1e-6):
+        super().__init__()
+        if qk_rope_head_dim % 2:
+            raise ValueError("rope needs an even qk_rope_head_dim")
+        if rope_scaling is not None and rope_scaling.get("type") != "yarn":
+            raise ValueError(f"rope_scaling of type {rope_scaling.get('type')!r}: "
+                             f"only 'yarn' is built")
+        self.embed_dim, self.num_heads = int(embed_dim), int(num_heads)
+        self.kv_lora_rank = int(kv_lora_rank)
+        self.qk_nope_head_dim = int(qk_nope_head_dim)
+        self.qk_rope_head_dim = int(qk_rope_head_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.rope_base = float(rope_base)
+        self.rope_scaling = None if rope_scaling is None else dict(rope_scaling)
+        self.norm_eps = float(norm_eps)
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        self.scale = qk ** -0.5
+        if self.rope_scaling:
+            y = self.rope_scaling
+            self.scale *= yarn_mscale(y["factor"], y.get("mscale_all_dim", 0)) ** 2
+        self.reset()
+
+    def reset(self) -> None:
+        e, h = self.embed_dim, self.num_heads
+        shapes = {"q_weight": (h * (self.qk_nope_head_dim + self.qk_rope_head_dim), e),
+                  "kv_a_weight": (self.kv_lora_rank + self.qk_rope_head_dim, e),
+                  "kv_b_weight": (h * (self.qk_nope_head_dim + self.v_head_dim),
+                                  self.kv_lora_rank),
+                  "out_weight": (e, h * self.v_head_dim)}
+        self._params = {k: jnp.asarray(Xavier().init(s, fan_in=s[1], fan_out=s[0]))
+                        for k, s in shapes.items()}
+        self._params["kv_norm"] = jnp.ones((self.kv_lora_rank,), jnp.float32)
+        self.zero_grad_parameters()
+
+    def _rotate(self, x, positions):
+        """RoPE over the rope channels of (..., t, qk_rope), as published."""
+        d, y = self.qk_rope_head_dim, self.rope_scaling
+        if not y:
+            return rope_rotate(_halves(x), positions, self.rope_base)
+        inv_freq = yarn_inv_freq(d, self.rope_base, y["factor"],
+                                 y["original_max_position_embeddings"],
+                                 y.get("beta_fast", 32), y.get("beta_slow", 1))
+        out = rope_rotate(_halves(x), positions, inv_freq=inv_freq)
+        m = yarn_mscale(y["factor"], y.get("mscale", 1)) / yarn_mscale(
+            y["factor"], y.get("mscale_all_dim", 0))
+        return out if m == 1.0 else out * m
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        from bigdl_tpu.kernels.flash_attention import flash_attention
+        positions = None
+        if isinstance(input, (tuple, list)):
+            input, positions = input
+        b, t, _ = input.shape
+        h, nope, rope, dv = (self.num_heads, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim, self.v_head_dim)
+        pos = jnp.arange(t) if positions is None else positions
+        heads = lambda a, n: a.reshape(b, t, h, n).transpose(0, 2, 1, 3)
+        q = heads(input @ params["q_weight"].T, nope + rope)
+        kv_a = input @ params["kv_a_weight"].T
+        c, k_pe = kv_a[..., :self.kv_lora_rank], kv_a[..., self.kv_lora_rank:]
+        ms = jnp.mean(jnp.square(c.astype(jnp.float32)), axis=-1, keepdims=True)
+        c = c * jax.lax.rsqrt(ms + self.norm_eps).astype(c.dtype) * params["kv_norm"]
+        kv = heads(c @ params["kv_b_weight"].T, nope + dv)
+        k_pe = self._rotate(k_pe[:, None], pos)                # (b, 1, t, rope)
+        q = jnp.concatenate([q[..., :nope], self._rotate(q[..., nope:], pos)], -1)
+        k = jnp.concatenate([kv[..., :nope],
+                             jnp.broadcast_to(k_pe, (b, h, t, rope))], -1)
+        o = flash_attention(q, k, kv[..., nope:], True, None, None, self.scale)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
+        return o @ params["out_weight"].T, state
+
+    def __repr__(self):
+        return (f"LatentAttention(embed={self.embed_dim}, heads={self.num_heads}, "
+                f"latent={self.kv_lora_rank}, qk={self.qk_nope_head_dim}+"
+                f"{self.qk_rope_head_dim}, v={self.v_head_dim}"
+                + (", yarn)" if self.rope_scaling else ")"))

@@ -60,6 +60,12 @@ SCOPE_MOE_COMBINE = "bigdl_moe_combine"
 #: sees a sliding window of them
 SCOPE_ATTN_FULL = "bigdl_attn_full"
 SCOPE_ATTN_WINDOW = "bigdl_attn_window"
+#: and of latent attention (``nn.LatentAttention``, whole, as above), of the
+#: routed layer's shared experts (inside ``bigdl_moe``) and of a leading
+#: dense layer's MLP: the two plain gated MLPs that every token passes through
+SCOPE_ATTN_LATENT = "bigdl_attn_latent"
+SCOPE_MOE_SHARED = "bigdl_moe_shared"
+SCOPE_MLP_DENSE = "bigdl_mlp_dense"
 
 
 class SpanRecord(NamedTuple):

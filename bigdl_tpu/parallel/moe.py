@@ -18,7 +18,10 @@ layer, by the router:
   dropless, and told which experts it holds: ``held=(first, count)``. The
   router may read another tensor than the experts do: the input ``(x, r)``
   routes on ``r`` (a decoder whose router stands before attention hands it
-  the layer's input) and feeds the experts ``x``. The (token, expert) pairs are sorted by expert, the
+  the layer's input) and feeds the experts ``x``. With ``shared`` (the
+  width of ``n_shared_experts`` experts side by side) a gated MLP that every
+  token passes through runs beside the routed experts and is added once to
+  their part. The (token, expert) pairs are sorted by expert, the
   rows gathered, one grouped product for gate and up and one for down run over
   the held groups (``kernels/grouped_matmul.py``), and the weighted rows are
   summed back per token. The layer returns the held experts' part of the sum.
@@ -134,6 +137,15 @@ def _sum_by_token(rows, token_of, tokens: int, weight=None):
 
 #: The gate's activation by the name ``MoE(gate=)`` takes.
 GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def gated_mlp(x, w_in, w_out, gate: str = "silu"):
+    """``(GATES[gate](x Wg) * (x Wu)) Wd``, a plain gated MLP that every row
+    passes through: ``w_in`` (D, 2H) holds ``Wg`` beside ``Wu``, ``w_out`` is
+    (H, D). The routed layer's shared experts and a decoder's dense layers."""
+    h = x @ w_in.astype(x.dtype)
+    hidden = w_out.shape[0]
+    return (GATES[gate](h[..., :hidden]) * h[..., hidden:]) @ w_out.astype(x.dtype)
 
 
 def _experts(xs, w_in, w_out, sizes, hidden: int, gate: str):
@@ -252,7 +264,9 @@ class MoE(TensorModule):
 
     ``router="topk"`` (module docstring) takes ``top_k``, ``norm_topk_prob``,
     ``held=(first, count)`` (default: all experts) and ``gate`` (``"silu"`` or
-    ``"relu"``: the activation of an expert's gate) and ignores
+    ``"relu"``: the activation of an expert's gate), ``shared`` (the width of
+    the shared experts side by side; 0, the default, for none: then there are
+    no ``shared_in`` (D, 2 shared) and ``shared_out`` (shared, D)) and ignores
     ``capacity_factor``: nothing is dropped. Its input may be ``(x, r)``: the
     router then reads ``r`` and the experts ``x``, both (N, D) or (N, T, D). Its parameters are the router
     ``w_gate`` (D, E) over all experts, ``w_in`` (count, D, 2H) holding each
@@ -270,7 +284,8 @@ class MoE(TensorModule):
                  z_loss_weight: float = 0.0,
                  w_init: Optional[InitializationMethod] = None,
                  top_k: Optional[int] = None, norm_topk_prob: bool = True,
-                 held: Optional[tuple] = None, gate: str = "silu"):
+                 held: Optional[tuple] = None, gate: str = "silu",
+                 shared: int = 0):
         super().__init__()
         if router not in ("top1", "top2", "expert_choice", "topk"):
             raise ValueError(f"router must be 'top1', 'top2', "
@@ -288,8 +303,8 @@ class MoE(TensorModule):
                                  f"{n_experts} experts, got {held!r}")
             if gate not in GATES:
                 raise ValueError(f"gate must be one of {sorted(GATES)}, got {gate!r}")
-        elif top_k is not None or held is not None or gate != "silu":
-            raise ValueError("top_k, held and gate belong to router='topk'")
+        elif top_k is not None or held is not None or gate != "silu" or shared:
+            raise ValueError("top_k, held, gate and shared belong to router='topk'")
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -300,6 +315,7 @@ class MoE(TensorModule):
         self.norm_topk_prob = bool(norm_topk_prob)
         self.held = held
         self.gate = gate
+        self.shared = int(shared)
         self.z_loss_weight = float(z_loss_weight)
         self.w_init = w_init or RandomNormal(0.0, 0.02)
         self.reset()
@@ -316,6 +332,10 @@ class MoE(TensorModule):
             self._params = {"w_gate": mk((d, e), d, e),
                             "w_in": mk((n, d, 2 * h), d, h),
                             "w_out": mk((n, h, d), h, d)}
+            if getattr(self, "shared", 0):
+                s = self.shared
+                self._params.update(shared_in=mk((d, 2 * s), d, s),
+                                    shared_out=mk((s, d), s, d))
         else:
             self._params = {
                 "w_gate": mk((d, e), d, e),
@@ -486,6 +506,10 @@ class MoE(TensorModule):
             routed_on = x if routed_on is None else routed_on.reshape(x.shape)
             with jax.named_scope(trace.SCOPE_MOE):
                 y, new_state = self._apply_topk(params, state, x, routed_on)
+                if getattr(self, "shared", 0):
+                    with jax.named_scope(trace.SCOPE_MOE_SHARED):
+                        y = y + gated_mlp(x, params["shared_in"],
+                                          params["shared_out"], self.gate)
             return (y.reshape(n, t, d) if flat else y), new_state
         tokens = x.shape[0]
         e = self.n_experts

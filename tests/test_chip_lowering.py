@@ -212,6 +212,32 @@ def test_flash_backward_builds_within_the_vmem_it_asks_for(
     assert "bigdl_flash_bwd" in text
 
 
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_latent_attentions_kernels_build_at_the_cells_shape(one_v5e_chip, kernel):
+    """``deepseek-v2-lite.train-t16k``'s attention through Mosaic: 16 heads,
+    T 16,384, the query/key head 192 and the value head 128 each at its own
+    width (nothing padded), YaRN's softmax scale. The backward asks for 48 MiB:
+    spans of 4,096 queries and head-long sums of dk at 192 (256 lanes) and of
+    dv at 128."""
+    t, d, d_v, scale = 16384, 192, 128, 0.114721
+    tiles = fa._tiles_under("causal", t, d, 2, d_v)
+    assert tiles == fa._Tiles(512, 512, 4096)
+    assert fa._bwd_vmem_limit(t, d, 2, tiles, d_v) >> 20 == 48
+    struct = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_v5e_chip)
+    qk, v = struct(16, t, d), struct(16, t, d_v)
+    if kernel == "fwd":
+        text = fa._pallas_flash_call.lower(
+            qk, qk, v, mask="causal", tiles=tiles, interpret=False,
+            scale=scale).compile().as_text()
+    else:
+        row = struct(16, 1, t, dtype=jnp.float32)
+        text = fa._pallas_flash_bwd.lower(
+            qk, qk, v, v, row, row, mask="causal", tiles=tiles, interpret=False,
+            scale=scale).compile().as_text()
+    assert f"bigdl_flash_{kernel}" in text
+
+
 def test_a_sequence_whose_sums_pass_the_ceiling_is_refused_by_name():
     """dk and dv sum in VMEM as long as the head: at T 131,072 and head_dim
     128 that is 128 MiB, and the plan says so before the compiler would. Half
@@ -470,3 +496,37 @@ def test_whole_layer_rematerialisation_would_show(on_tpu, monkeypatch):
     assert _kernel_calls(text, "bigdl_flash_bwd") == 1
     assert text.count("stablehlo.sort") == 2
     assert text.count("@mhlo.topk") == 2
+
+
+def test_the_latent_decoders_step_holds_the_kernels_and_its_scopes(on_tpu, monkeypatch):
+    """A decoder of DeepSeek-V2's kinds at its head widths (query/key 128 + 64,
+    value 128, YaRN): one leading dense layer, then two routed layers with
+    shared experts under the scan. Its lowered gradient runs attention in the
+    flash kernels, forward and backward, once for the dense layer and once in
+    the scan's body (each kept by the policy, not run again), and carries the
+    three scopes the per-layer metrics read."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    from bigdl_tpu.models.transformerlm import ConfigDecoder
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    yarn = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
+            "beta_fast": 32, "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707}
+    model = ConfigDecoder(
+        vocab_size=512, hidden_size=256, num_hidden_layers=3, num_attention_heads=2,
+        num_key_value_heads=2, moe_intermediate_size=128, num_experts=16,
+        num_experts_per_tok=4, held=(4, 4), qk_norm=False, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, rope_scaling=yarn,
+        first_k_dense_replace=1, intermediate_size=256, n_shared_experts=2)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), model.get_params())
+
+    def loss(p, x):
+        out, _ = model.apply(p, model.get_state(), x, training=True)
+        return out[1].astype(jnp.float32).sum()
+
+    text = _tpu_module(jax.grad(loss), params, jax.ShapeDtypeStruct((2, 1024), jnp.int32))
+    for jitted in ("_pallas_flash_call", "_pallas_flash_bwd"):
+        assert len(re.findall(rf"call @{jitted}(_\d+)?\(", text)) == 2, jitted
+    for name in FLASH_KERNELS:
+        assert _kernel_calls(text, name) >= 1, name
+    for scope in ("bigdl_attn_latent", "bigdl_moe_shared", "bigdl_mlp_dense"):
+        assert scope in text, scope

@@ -177,6 +177,40 @@ def test_the_routers_input_apart_from_the_experts_and_the_relu_gate(layer, gate,
             np.testing.assert_allclose(y3.reshape(T, D), y, atol=2e-6)
 
 
+@pytest.mark.parametrize("norm", [True, False])
+def test_shared_experts_counted_once_with_the_eight_shares_are_the_uncut_layer(layer, norm):
+    """Shared experts (a gated MLP of width ``shared`` that every token passes
+    through, under ``bigdl_moe_shared``): each of eight chips computes its two
+    held experts' part plus the shared MLP; the routed parts added up, with
+    the shared MLP counted once, are the uncut layer with every expert held,
+    and that is the dense form plus the MLP written out."""
+    from bigdl_tpu.parallel.moe import gated_mlp
+    _, params, x = layer
+    key = jax.random.PRNGKey(5)
+    shared = {"shared_in": 0.1 * jax.random.normal(key, (D, 2 * 48)),
+              "shared_out": 0.1 * jax.random.normal(jax.random.fold_in(key, 1), (48, D))}
+    plain_mlp = lambda x: (jax.nn.silu(x @ shared["shared_in"][:, :48])
+                           * (x @ shared["shared_in"][:, 48:])) @ shared["shared_out"]
+    with jax.default_matmul_precision("highest"):
+        mlp = plain_mlp(x)
+        np.testing.assert_allclose(gated_mlp(x, shared["shared_in"], shared["shared_out"]),
+                                   mlp, atol=1e-6)
+        routed = 0.0
+        for rank in range(8):
+            m = MoE(D, HID, E, router="topk", top_k=K, held=(2 * rank, 2),
+                    norm_topk_prob=norm, shared=48)
+            assert set(m.get_params()) == {"w_gate", "w_in", "w_out", "shared_in", "shared_out"}
+            y, _ = m.apply({**_share(params, 2 * rank, 2), **shared}, m.get_state(), x)
+            routed = routed + (y - mlp)
+        whole = MoE(D, HID, E, router="topk", top_k=K, norm_topk_prob=norm, shared=48)
+        y, _ = whole.apply({**params, **shared}, whole.get_state(), x)
+        np.testing.assert_allclose(routed + mlp, y, atol=5e-6)
+        np.testing.assert_allclose(y, _uncut(x, params, K, norm=norm) + mlp, atol=5e-6)
+    assert "shared_in" not in MoE(D, HID, E, router="topk", top_k=K).get_params()
+    with pytest.raises(ValueError, match="shared"):
+        MoE(D, HID, E, router="top1", shared=48)
+
+
 def test_one_pass_when_all_experts_are_held(layer):
     """A pass's rows are every pair then, whatever the routing."""
     full, params, x = layer

@@ -254,7 +254,7 @@ class TestFlashTilings:
                                                dtype, causal):
         from bigdl_tpu.kernels import flash_attention as fa
         monkeypatch.setattr(fa, "_tiles",
-                            lambda t, d, itemsize: fa._Tiles(*tiles))
+                            lambda t, d, itemsize, d_v=None: fa._Tiles(*tiles))
         self._check((1, 2, 768, 16), dtype, causal)
 
     @pytest.mark.parametrize("causal", [True, False])

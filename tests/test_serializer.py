@@ -132,6 +132,10 @@ EXAMPLES = {
         lambda: nn.QuantizedSpatialConvolution.from_float(
             nn.SpatialConvolution(2, 4, 3, 3)), _x(1, 2, 6, 6)),
     "MultiHeadAttention": (lambda: nn.MultiHeadAttention(8, 2), _x(2, 5, 8)),
+    "LatentAttention": (lambda: nn.LatentAttention(
+        16, 2, 8, 4, 4, 4, rope_scaling={"type": "yarn", "factor": 40,
+                                         "original_max_position_embeddings": 4096}),
+        _x(2, 5, 16)),
     "CrossAttention": (lambda: nn.CrossAttention(8, 2),
                        T(_x(2, 4, 8), _x(2, 6, 8))),
     "SequenceBeamSearch": (

@@ -3,6 +3,7 @@
 - ``layernorm`` — Pallas fused LayerNorm (one VMEM residency per row block);
 - ``flash_attention`` — streaming-softmax attention (imported on demand);
 - ``grouped_matmul`` — the experts of a routed layer in one call (imported on demand);
+- ``token_sum`` — a routed layer's rows summed back to their tokens (imported on demand);
 - ``conv_bn`` — fused conv→bn(→relu) with inference-time BN folding;
 - ``fused_update`` — flat-param (dtype-grouped vector) optimizer updates.
 """

@@ -24,7 +24,9 @@ layer, by the router:
   their part. The (token, expert) pairs are sorted by expert, the
   rows gathered, one grouped product for gate and up and one for down run over
   the held groups (``kernels/grouped_matmul.py``), and the weighted rows are
-  summed back per token. The layer returns the held experts' part of the sum.
+  summed back per token (``kernels/token_sum.py``: the rows sorted by token,
+  then one Pallas kernel, ``bigdl_moe_sum``). The layer returns the held
+  experts' part of the sum.
   The static bound on rows is what the layer holds, not every pair there is:
   a pass works over ``R`` sorted positions, twice the balanced expectation of
   held pairs (``2 * tokens * top_k * count / n_experts`` in whole sublanes;
@@ -38,10 +40,11 @@ layer, by the router:
   no capacity is set by anyone. A pass's products always run over all ``R``
   rows (the rows after the held pairs, weight 0, go to the last held group),
   so that a step's time follows the router's imbalance by whole passes only:
-  at the benchmark's seeded weights a layer's held pairs are 0.02 to 2.7
-  times the expectation from batch to batch, and where only the held experts
-  answer (one chip's share, run alone) training pulls the router towards
-  them within tens of steps (PERF.md, PR 29 and PR 30).
+  at the benchmark's seeded weights (an input embedding of N(0, 1), Adam at
+  1e-6) a layer's held pairs stay at 0.6 to 1.6 times the expectation, one
+  pass in every step, where weights all at N(0, 0.02) gave 0.01 to 2.4 from
+  batch to batch and a rate of 1e-4 pulled the router towards the held
+  experts (one chip's share, trained alone) within tens of steps (PERF.md).
   ``held`` is what one rank of an
   expert-parallel mesh axis holds: rank ``r`` of ``n`` holds
   ``(r * E // n, E // n)``, routes over all ``E`` and computes its own part;
@@ -61,6 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from bigdl_tpu.kernels.token_sum import sum_by_token
 from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.initialization import InitializationMethod, RandomNormal
 from bigdl_tpu.obs import trace
@@ -126,15 +130,6 @@ def _window(x, weight, order, sizes, start, rows: int):
         return pair, live, token_of, x[token_of], by_row, padded
 
 
-def _sum_by_token(rows, token_of, tokens: int, weight=None):
-    """(tokens, D) in fp32: row ``r`` of ``rows``, times ``weight[r]`` if
-    given, added to token ``token_of[r]``."""
-    part = rows.astype(jnp.float32)
-    if weight is not None:
-        part = part * weight[:, None]
-    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token_of].add(part)
-
-
 #: The gate's activation by the name ``MoE(gate=)`` takes.
 GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
@@ -191,10 +186,10 @@ def _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden, gate
     tokens, k = weight.shape
 
     def one_pass(start):
-        _, _, token_of, xs, by_row, padded = _window(x, weight, order, sizes, start, rows)
+        _, live, token_of, xs, by_row, padded = _window(x, weight, order, sizes, start, rows)
         out = _experts(xs, w_in, w_out, padded, hidden, gate)
         with jax.named_scope(trace.SCOPE_MOE_COMBINE):
-            return _sum_by_token(out, token_of, tokens, by_row)
+            return sum_by_token(out, token_of, live, tokens, by_row)
 
     y = _over_passes(one_pass, passes, rows, tokens * k)
     return y, (x, w_in, w_out, weight, order, sizes, passes)
@@ -220,7 +215,7 @@ def _routed_bwd(rows, hidden, gate, res, g):
             d_out = g_rows * by_row[:, None].astype(out.dtype)
         d_xs, d_in, d_out_w = pull(d_out)
         with jax.named_scope(trace.SCOPE_MOE_ROUTE):
-            d_x = _sum_by_token(d_xs, token_of, tokens)
+            d_x = sum_by_token(d_xs, token_of, live, tokens)
         return d_x, d_in, d_out_w, d_weight
 
     d_x, d_in, d_out_w, d_weight = _over_passes(one_pass, passes, rows, tokens * k)

@@ -7,6 +7,7 @@ that catches an illegal BlockSpec, which is how the flash-2 residual and the
 6/12-row LayerNorm once went unnoticed behind interpret-mode tests.
 """
 
+import collections
 import re
 
 import jax
@@ -16,6 +17,7 @@ import pytest
 
 from bigdl_tpu.kernels import flash_attention as fa
 from bigdl_tpu.kernels import layernorm as ln
+from bigdl_tpu.kernels import token_sum as ts
 
 FLASH_KERNELS = ("bigdl_flash_fwd", "bigdl_flash_bwd")
 
@@ -25,6 +27,7 @@ def on_tpu(monkeypatch):
     """Take the kernels' TPU branch (compiled, not interpreted)."""
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     monkeypatch.setattr(ln, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ts, "_on_tpu", lambda: True)
 
 
 def _tpu_module(fn, *avals) -> str:
@@ -34,6 +37,13 @@ def _tpu_module(fn, *avals) -> str:
 
 def _kernel_calls(text: str, name: str) -> int:
     return text.count(f'kernel_name = "{name}"')
+
+
+def _sorts_over(text: str, n: int) -> int:
+    """The sorts in ``text`` whose operands are ``n`` long: the routing's over
+    every (token, expert) pair, apart from the sums' over a pass's rows."""
+    return re.findall(r'"stablehlo\.sort".*?\}\) : \(tensor<(\d+)x', text, re.S) \
+        .count(str(n))
 
 
 # --------------------------------------------------------------- flash
@@ -291,6 +301,87 @@ def test_routed_layer_lowers_at_the_sdar_shapes(monkeypatch):
         assert "32768x2048xbf16" in text and "131072x2048" not in text
 
 
+# ----------------------------------------------------- the sum by token
+@pytest.mark.parametrize("weighted", [True, False])
+@pytest.mark.parametrize("rows,d", [(32768, 2048), (24576, 2560), (24576, 2048)])
+def test_the_sum_by_token_lowers_at_the_cells_shapes(on_tpu, rows, d, weighted):
+    """``bigdl_moe_sum`` over a pass's bf16 rows of the SDAR, SmallThinker and
+    DeepSeek cells (16,384 positions each), weighted as the output and not as
+    the input's gradient: one kernel, and no tensor of the pass's rows in fp32
+    (the scatter-add it replaced made one)."""
+    args = [jax.ShapeDtypeStruct((rows, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_)]
+    if weighted:
+        args.append(jax.ShapeDtypeStruct((rows,), jnp.float32))
+    text = _tpu_module(lambda r, t, l, *w: ts.sum_by_token(r, t, l, 16384, *w), *args)
+    assert _kernel_calls(text, ts.NAME) == 1
+    assert f"{rows}x{d}xf32" not in text
+
+
+@pytest.mark.parametrize("tokens,rows,d,itemsize,want", [
+    (16384, 24576, 2560, 2, (128, 128, 2560)),      # the SmallThinker cell
+    (16384, 32768, 2048, 2, (128, 128, 2048)),      # the SDAR cell
+    (96, 192, 64, 4, (96, 128, 64)),                # whole axes, a width under a lane tile
+    (5, 8, 64, 4, (5, 8, 64)),
+    (16384, 24576, 16384, 4, (128, 128, 8192)),     # rows too wide for the budget
+])
+def test_the_sum_tiles_rule(tokens, rows, d, itemsize, want):
+    tiles = ts._tiles(tokens, rows, d, itemsize)
+    assert tiles == ts._Tiles(*want)
+    assert d % tiles.width == 0
+    assert ts._vmem_bytes(*tiles, itemsize) <= ts._BUDGET_BYTES
+
+
+def _mosaic_lowerings(monkeypatch) -> collections.Counter:
+    """How often each Pallas kernel is lowered to Mosaic, by its name: counted
+    in the lowering rule itself."""
+    from jax._src.pallas.mosaic import pallas_call_registration as registration
+    counts = collections.Counter()
+    rule = registration.pallas_call_tpu_lowering_rule
+
+    def counted(ctx, *args, **params):
+        counts[params["name"]] += 1
+        return rule(ctx, *args, **params)
+    monkeypatch.setattr(registration, "pallas_call_tpu_lowering_rule", counted)
+    return counts
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_a_period_of_four_lowers_the_sum_once_a_weighting(on_tpu, monkeypatch, remat):
+    """The guard on set-up. Eight routed layers in two periods of four, the
+    period written out in the scan body: the step calls the sum by token from
+    16 places or more (four layers, forward and backward, each the first pass
+    and the loop's), and lowers ``bigdl_moe_sum`` to Mosaic twice, weighted
+    and not. A warm run lowers its program before the compile cache answers,
+    so a lowering a place would be paid in every run."""
+    lowered = _mosaic_lowerings(monkeypatch)
+    text = _decoder_gradient(monkeypatch, remat, **PERIOD)
+    assert len(re.findall(r"call @_sum(_\d+)?\(", text)) >= 16
+    assert lowered[ts.NAME] == 2
+
+
+def test_a_sum_lowered_at_every_place_would_show(on_tpu, monkeypatch):
+    """The control: with the sum not jitted the same count reads a lowering
+    for each place that calls it."""
+    monkeypatch.setattr(ts, "_sum", ts._sum.__wrapped__)
+    lowered = _mosaic_lowerings(monkeypatch)
+    _decoder_gradient(monkeypatch, False, **PERIOD)
+    assert lowered[ts.NAME] >= 16
+
+
+def test_the_sum_by_token_builds_at_the_smallthinker_shape(one_v5e_chip):
+    """Through Mosaic itself at the SmallThinker cell's pass: 24,576 bf16
+    rows of 2,560 into 16,384 tokens, weighted (three products a pair), with
+    the sort and the gather before it."""
+    struct = lambda *dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+    text = ts._sum.lower(
+        struct(24576, 2560, dtype=jnp.bfloat16), struct(24576, dtype=jnp.int32),
+        struct(24576, dtype=jnp.bool_), struct(24576, dtype=jnp.float32),
+        tokens=16384, interpret=False).compile().as_text()
+    assert ts.NAME in text
+
+
 # ----------------------------------------------------------- layer norm
 @pytest.mark.parametrize("h", [64, 512])
 @pytest.mark.parametrize("rows", [1, 5, 6, 12, 13, 24, 300, 8192])
@@ -433,6 +524,7 @@ def _decoder_gradient(monkeypatch, remat, body=None, **kinds) -> str:
     from bigdl_tpu.kernels import grouped_matmul as gm
     from bigdl_tpu.models.transformerlm import ConfigDecoder
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ts, "_on_tpu", lambda: True)
     model = ConfigDecoder(
         vocab_size=512, hidden_size=256, num_hidden_layers=8 if kinds else 2,
         num_attention_heads=4, num_key_value_heads=2, head_dim=128,
@@ -457,13 +549,20 @@ def _decoder_gradient(monkeypatch, remat, body=None, **kinds) -> str:
 @pytest.mark.parametrize("remat", [True, False])
 def test_scanned_decoder_runs_attention_and_routing_once(on_tpu, monkeypatch, remat):
     """Rematerialised or not, the step holds each flash kernel once (the scan
-    body's forward, the backward body's one), one top-k and one sort: what
-    ``ConfigDecoder``'s policy keeps is not run again in the backward pass."""
+    body's forward, the backward body's one), one top-k and one sort of the
+    2,048 positions' 8,192 pairs: what ``ConfigDecoder``'s policy keeps is not
+    run again in the backward pass. (The sums by token sort a pass's rows.)"""
     text = _decoder_gradient(monkeypatch, remat)
     for name in FLASH_KERNELS:
         assert _kernel_calls(text, name) == 1, name
-    assert text.count("stablehlo.sort") == 1
+    assert _sorts_over(text, 8192) == 1
     assert text.count("@mhlo.topk") == 1
+
+
+#: eight layers in two periods of (full, window, window, window)
+PERIOD = dict(sliding_window_layout=[0, 1, 1, 1] * 2, sliding_window_size=256,
+              rope_layout=[0, 1, 1, 1] * 2, router_input="layer",
+              expert_gate="relu", qk_norm=False)
 
 
 @pytest.mark.parametrize("remat", [True, False])
@@ -474,17 +573,14 @@ def test_a_period_of_four_holds_each_kind_once_a_layer(on_tpu, monkeypatch, rema
     causal mask and three times under the window (forward body and backward
     body), four top-k and four sorts (kept, not run again), one scan over two
     periods."""
-    text = _decoder_gradient(
-        monkeypatch, remat, sliding_window_layout=[0, 1, 1, 1] * 2,
-        sliding_window_size=256, rope_layout=[0, 1, 1, 1] * 2,
-        router_input="layer", expert_gate="relu", qk_norm=False)
+    text = _decoder_gradient(monkeypatch, remat, **PERIOD)
     for jitted in ("_pallas_flash_call", "_pallas_flash_bwd"):
         assert len(re.findall(rf"call @{jitted}(_\d+)?\(", text)) == 4, jitted
     # two masks and no more: the kernels' bodies as lowered (a body that
     # several layers share is lowered once)
     assert 2 <= _kernel_calls(text, "bigdl_flash_bwd") <= 4
     if remat:       # (without it the sort is one shared function, called four times)
-        assert text.count("stablehlo.sort") == 4
+        assert _sorts_over(text, 8192) == 4
         assert text.count("@mhlo.topk") == 4
 
 
@@ -494,7 +590,7 @@ def test_whole_layer_rematerialisation_would_show(on_tpu, monkeypatch):
     text = _decoder_gradient(monkeypatch, False, body=jax.checkpoint)
     assert _kernel_calls(text, "bigdl_flash_fwd") == 2
     assert _kernel_calls(text, "bigdl_flash_bwd") == 1
-    assert text.count("stablehlo.sort") == 2
+    assert _sorts_over(text, 8192) == 2
     assert text.count("@mhlo.topk") == 2
 
 

@@ -265,6 +265,64 @@ def test_grouped_matmul_kernel_matches_the_plain_form(sizes):
             np.testing.assert_allclose(a, b, atol=1e-4)
 
 
+def _sum_by_token(rows, token_of, tokens, weight=None):
+    """The plain XLA form the kernel replaced: (tokens, D) in fp32, row ``r``
+    of ``rows``, times ``weight[r]`` if given, added to token ``token_of[r]``
+    by an fp32 scatter-add."""
+    part = rows.astype(jnp.float32)
+    if weight is not None:
+        part = part * weight[:, None]
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token_of].add(part)
+
+
+# tokens, rows held by each token in turn (a pattern repeated), dead rows, D
+@pytest.mark.parametrize("tokens,held,dead,d", [
+    (96, (0, 1, K), 40, 64),        # tokens with none, one and top_k rows
+    (300, (K, 1, 0, 2), 21, 128),   # 300 tokens: not a multiple of the 128-token block
+    (5, (1,), 3, 64),               # fewer tokens and rows than a block
+    (40, (0, K, 1), 8, 2048),       # the SDAR and DeepSeek cells' width
+    (40, (1, 0, K), 8, 2560),       # the SmallThinker cell's
+])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("weighted", [True, False])
+def test_the_sum_kernel_is_the_scatter_add_by_token(tokens, held, dead, d, dtype, weighted):
+    """``bigdl_moe_sum`` through the interpreter against the fp32 scatter-add,
+    within fp32 round-off: rows in the order a pass holds them (by expert, not
+    by token), dead rows among them that name tokens and hold values the sum
+    must not see, weighted (the layer's output) and not (its input's
+    gradient), rows in bfloat16 and in fp32."""
+    from bigdl_tpu.kernels.token_sum import sum_by_token
+    rng = np.random.default_rng(tokens + d)
+    counts = np.resize(held, tokens)
+    token_of = np.concatenate([np.repeat(np.arange(tokens), counts),
+                               rng.integers(0, tokens, dead)])
+    live = np.arange(len(token_of)) < counts.sum()
+    order = rng.permutation(len(token_of))
+    token_of, live = token_of[order], live[order]
+    rows = jnp.asarray(rng.normal(size=(len(token_of), d)), dtype)
+    weight = jnp.asarray(rng.random(len(token_of)), jnp.float32) if weighted else None
+    got = sum_by_token(rows, jnp.asarray(token_of, jnp.int32), jnp.asarray(live), tokens, weight)
+    want = _sum_by_token(rows[live], jnp.asarray(token_of[live]), tokens,
+                         None if weight is None else weight[live])
+    assert got.dtype == jnp.float32 and got.shape == (tokens, d)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(want))))
+    np.testing.assert_array_equal(got[counts == 0], 0.0)
+
+
+def test_the_sum_kernel_cuts_rows_too_wide_for_its_budget(monkeypatch):
+    """Where a whole row's plan passes the VMEM budget the kernel walks the
+    features in blocks of whole lane tiles, the pairs once for each."""
+    from bigdl_tpu.kernels import token_sum as ts
+    monkeypatch.setattr(ts, "_BUDGET_BYTES", ts._vmem_bytes(128, 128, 128, 4))
+    assert ts._tiles(300, 260, 384, 4) == ts._Tiles(128, 128, 128)
+    rng = np.random.default_rng(3)
+    token_of = jnp.asarray(rng.permutation(300 * K)[:260] // K, jnp.int32)
+    rows = jnp.asarray(rng.normal(size=(260, 384)), jnp.float32)
+    weight = jnp.asarray(rng.random(260), jnp.float32)
+    got = ts.sum_by_token(rows, token_of, jnp.ones(260, bool), 300, weight)
+    np.testing.assert_allclose(got, _sum_by_token(rows, token_of, 300, weight), rtol=0, atol=1e-5)
+
+
 def test_topk_arguments_are_checked():
     with pytest.raises(ValueError):
         MoE(D, HID, E, router="topk")                       # no top_k

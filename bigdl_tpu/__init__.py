@@ -21,9 +21,13 @@ upstream path* (e.g. ``<dl>/nn/Linear.scala``) per SURVEY.md §2 and are marked 
 
 __version__ = "0.1.0"
 
+from bigdl_tpu.obs import compile as _obs_compile
 from bigdl_tpu.utils.engine import Engine, place_compile_cache
 from bigdl_tpu.utils.table import Table, T
 
 place_compile_cache()
+# before anything of the package compiles: set-up's compile phases and the
+# persistent cache's answers, recorded from the first program on
+_obs_compile.install()
 
 __all__ = ["Engine", "Table", "T", "__version__"]

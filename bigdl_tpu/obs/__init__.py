@@ -33,7 +33,11 @@ each with its own accumulator and consumer glue. This package unifies them:
   snapshot spools (``BIGDL_OBS_SPOOL_DIR``) merged into one ``/metrics``
   scrape with ``{host=}`` labels;
 - :mod:`bigdl_tpu.obs.access_log` — opt-in structured request log
-  (``BIGDL_ACCESS_LOG``) with the ``to_bdlrec`` flywheel converter.
+  (``BIGDL_ACCESS_LOG``) with the ``to_bdlrec`` flywheel converter;
+- :mod:`bigdl_tpu.obs.compile` — jax's trace, lowering and backend-compile
+  phases and the persistent cache's answers as always-on registry totals, a
+  table by program, and spans when tracing is on (installed by
+  ``import bigdl_tpu``).
 
 Dependency-free by design: nothing here imports ``optim``/``dataset``/
 ``nn``, so every layer of the framework may publish into it (``mfu``
@@ -44,8 +48,8 @@ from __future__ import annotations
 
 import os
 
-from bigdl_tpu.obs import access_log, cluster, device, exporter, mfu, \
-    registry, report, slo, trace, watchdog
+from bigdl_tpu.obs import access_log, cluster, compile, device, exporter, \
+    mfu, registry, report, slo, trace, watchdog
 from bigdl_tpu.obs.registry import registry as metric_registry
 
 
@@ -72,5 +76,5 @@ def describe_config() -> str:
 
 
 __all__ = ["trace", "registry", "watchdog", "report", "exporter", "mfu",
-           "slo", "device", "cluster", "access_log", "metric_registry",
-           "describe_config"]
+           "slo", "device", "cluster", "access_log", "compile",
+           "metric_registry", "describe_config"]

@@ -19,7 +19,8 @@ in-process telemetry rails into a scrapeable plane:
   peer degrades the fleet instead of 503ing the process: the router is
   routing around it, which is the design working, not an outage.
 - ``/statusz`` — JSON status: the latest run report (published by the
-  trainer at end of run), MFU accounting, full engine ledgers, SLO state,
+  trainer at end of run), MFU accounting, the process's compile phases and
+  the programs that took longest to compile, full engine ledgers, SLO state,
   the device-memory picture, and — under ``BIGDL_OBS_SPOOL_DIR`` — a
   per-host table merged from the cluster spools (``obs/cluster.py``).
 - ``/profilez?seconds=N`` — on-demand ``jax.profiler.trace`` capture into
@@ -57,6 +58,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from bigdl_tpu.obs import cluster, mfu
+from bigdl_tpu.obs import compile as obs_compile
 from bigdl_tpu.obs import watchdog as obs_watchdog
 from bigdl_tpu.obs.registry import registry
 
@@ -378,7 +380,8 @@ def render_healthz() -> "tuple[int, dict]":
 
 
 def render_statusz() -> dict:
-    """The /statusz payload: run report + MFU + engine ledgers + SLO."""
+    """The /statusz payload: run report + MFU + compile phases + engine
+    ledgers + SLO."""
     with _STATUS_LOCK:
         status = dict(_STATUS)
     engs = {}
@@ -411,6 +414,7 @@ def render_statusz() -> dict:
             "slo": status.get("slo"),
             "status": status,
             "mfu": mfu.stats(),
+            "compile": obs_compile.summary(),
             "device_memory": device_block,
             "hosts": hosts,
             "engines": engs,

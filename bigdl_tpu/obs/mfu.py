@@ -29,7 +29,10 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Optional
+
+from bigdl_tpu.obs import trace
 
 #: peak dense (non-sparse) FLOPs/s per chip, matched by substring against
 #: ``jax.devices()[0].device_kind.lower()``. Order matters: first match wins
@@ -113,7 +116,18 @@ def program_flops(fn, *args) -> Optional[float]:
     arguments — only their shapes/dtypes are used, via ShapeDtypeStruct
     avals, so donated buffers are never touched. Returns None when the
     backend provides no cost analysis (callers memoize either way: this
-    re-traces, ~ms per program)."""
+    re-traces, ~ms per program). Its seconds go to the histogram
+    ``obs/program_flops`` always, and to a span of that name with tracing
+    on: the compile phases it sets off (``obs/compile.py``) nest under it."""
+    from bigdl_tpu.obs.registry import registry
+    t0 = time.perf_counter()
+    with trace.span("obs/program_flops"):
+        flops = _cost_flops(fn, args)
+    registry.histogram("obs/program_flops").observe(time.perf_counter() - t0)
+    return flops
+
+
+def _cost_flops(fn, args) -> Optional[float]:
     try:
         ca = fn.lower(*avals_of(args)).cost_analysis()
         if isinstance(ca, (list, tuple)):

@@ -2,7 +2,9 @@
 
 :func:`build_report` reduces a registry snapshot delta (step-time
 percentiles, feed-stage attribution, feed stalls, phase means, robustness
-counters) plus the tracer's span totals into one plain-data dict; the
+counters) plus the tracer's span totals and the process's compile phases
+(``obs/compile.py``: seconds by phase, the persistent cache's answers, the
+programs that took longest) into one plain-data dict; the
 Optimizer stores it in ``state["run_report"]``, logs :func:`format_report`,
 and appends it to the JSONL event log, where ``bigdl-tpu diag <jsonl>``
 re-renders the IDENTICAL text — the on-call engineer reads the same report
@@ -12,6 +14,8 @@ whether the process is still alive or all that's left is the log.
 from __future__ import annotations
 
 from typing import Optional
+
+from bigdl_tpu.obs import compile as obs_compile
 
 
 def _hist_delta(snap0: dict, snap1: dict, name: str) -> Optional[dict]:
@@ -45,9 +49,11 @@ def build_report(snap0: dict, snap1: dict,
                  span_totals: Optional[dict] = None,
                  robustness: Optional[dict] = None,
                  watchdog_dumps: int = 0) -> dict:
-    """Merge a run's registry delta + span totals into the report dict.
-    Everything is JSON-plain (ints/floats/strings) so the dict survives the
-    JSONL round trip bit-for-bit and ``diag`` re-renders identical text."""
+    """Merge a run's registry delta + span totals into the report dict, with
+    the process's compile phases as of ``snap1``
+    (:func:`~bigdl_tpu.obs.compile.summary`). Everything is
+    JSON-plain (ints/floats/strings) so the dict survives the JSONL round
+    trip bit-for-bit and ``diag`` re-renders identical text."""
     rep: dict = {}
     step = _hist_delta(snap0, snap1, "train/step_wall")
     if step is not None:
@@ -96,6 +102,9 @@ def build_report(snap0: dict, snap1: dict,
         rep["spans"] = {name: dict(v) for name, v in top}
     if watchdog_dumps:
         rep["watchdog_dumps"] = int(watchdog_dumps)
+    compiles = obs_compile.summary(snap1)
+    if compiles:
+        rep["compile"] = compiles
     return rep
 
 
@@ -138,4 +147,15 @@ def format_report(rep: dict) -> str:
         lines.append(f"span totals: {parts}")
     if rep.get("watchdog_dumps"):
         lines.append(f"watchdog dumps: {rep['watchdog_dumps']}")
+    comp = rep.get("compile")
+    if comp:
+        parts = ", ".join(f"{p} {comp[p + '_s']:.3f} s (x{comp[p + '_count']})"
+                          for p in ("trace", "lower", "backend") if p + "_s" in comp)
+        lines.append(f"compile since process start: {parts}; persistent cache "
+                     f"hits {comp.get('cache_hits', 0)}, misses "
+                     f"{comp.get('cache_misses', 0)}")
+        progs = ", ".join(f"{p} {f} {s:.3f} s (x{c})"
+                          for p, f, s, c in comp.get("programs", []))
+        if progs:
+            lines.append(f"largest compiles: {progs}")
     return "\n".join(lines)

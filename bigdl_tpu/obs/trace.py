@@ -5,7 +5,9 @@ name, thread, start in Unix nanoseconds, duration, the span open beneath it
 on its thread (``parent``) and ``args``. The Unix start puts a span on the
 clock of a ``jax.profiler`` trace with no host tracer at all: the xplane's
 ``Task Environment`` plane carries ``profile_start_time`` in Unix nanoseconds
-and every event's offset is relative to it. :func:`open_spans` exposes the
+and every event's offset is relative to it. :func:`record_span` keeps a
+span timed elsewhere on the Unix clock (jax's compile phases,
+``obs/compile.py``). :func:`open_spans` exposes the
 live per-thread stacks for the hang watchdog. The newest ``_MAX_SPANS``
 records are kept (a ring; what falls out is counted), and
 :func:`spans_between` is their one reader. Two outputs:
@@ -100,7 +102,8 @@ def _sync_clock() -> None:
     global _UNIX_OFFSET_NS
     _UNIX_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
 
-#: _Span instances ever constructed — the zero-alloc-when-disabled pin
+#: spans ever made (``_Span`` instances and :func:`record_span` calls) — the
+#: zero-alloc-when-disabled pin
 _SPANS_CREATED = 0
 
 
@@ -231,34 +234,42 @@ class _Span:
         read an edge)."""
         tid = threading.get_ident()
         self._tid = tid
-        stack = _open_stacks.get(tid)
-        if stack is None:
-            # first span on this thread: register its name for the trace
-            _open_stacks[tid] = stack = []
-            _threads[tid] = threading.current_thread().name
+        stack = _stack(tid)
         self._parent = stack[-1][0] if stack else None
         self._t0 = t0_ns
         stack.append((self.name, t0_ns))
 
     def end(self, t1_ns: int) -> None:
-        global _dropped
         stack = _open_stacks.get(self._tid)
         if stack:
             stack.pop()
-        dur = t1_ns - self._t0
-        rec = SpanRecord(self.name, self._tid, _threads.get(self._tid, "?"),
-                         self._t0 + _UNIX_OFFSET_NS, dur, self._parent,
-                         self.args)
-        with _lock:
-            tot = _totals.get(self.name)
-            if tot is None:
-                _totals[self.name] = [1, dur / 1e9]
-            else:
-                tot[0] += 1
-                tot[1] += dur / 1e9
-            if len(_finished) == _finished.maxlen:
-                _dropped += 1       # the ring pushes its oldest out
-            _finished.append(rec)
+        _keep(SpanRecord(self.name, self._tid, _threads.get(self._tid, "?"),
+                         self._t0 + _UNIX_OFFSET_NS, t1_ns - self._t0,
+                         self._parent, self.args))
+
+
+def _stack(tid: int) -> list:
+    """The open-span stack of thread ``tid`` (the caller's own)."""
+    stack = _open_stacks.get(tid)
+    if stack is None:
+        # first span on this thread: register its name for the trace
+        _open_stacks[tid] = stack = []
+        _threads[tid] = threading.current_thread().name
+    return stack
+
+
+def _keep(rec: SpanRecord) -> None:
+    global _dropped
+    with _lock:
+        tot = _totals.get(rec.name)
+        if tot is None:
+            _totals[rec.name] = [1, rec.dur_ns / 1e9]
+        else:
+            tot[0] += 1
+            tot[1] += rec.dur_ns / 1e9
+        if len(_finished) == _finished.maxlen:
+            _dropped += 1       # the ring pushes its oldest out
+        _finished.append(rec)
 
 
 def span(name: str, args: Optional[dict] = None):
@@ -269,6 +280,21 @@ def span(name: str, args: Optional[dict] = None):
     if not _ENABLED:
         return _NULL
     return _Span(name, args)
+
+
+def record_span(name: str, start_unix_ns: int, dur_ns: int,
+                args: Optional[dict] = None) -> None:
+    """Keep a span that has already ended, at Unix times the caller was
+    given (jax's compile events: ``obs/compile.py``), on the current thread
+    and under the span open there. Nothing when tracing is disabled."""
+    global _SPANS_CREATED
+    if not _ENABLED:
+        return
+    _SPANS_CREATED += 1
+    tid = threading.get_ident()
+    stack = _stack(tid)
+    _keep(SpanRecord(name, tid, _threads[tid], start_unix_ns, dur_ns,
+                     stack[-1][0] if stack else None, args))
 
 
 def span_totals() -> dict:

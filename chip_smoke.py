@@ -53,9 +53,11 @@ TOL_TIE = 5e-2
 PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "benchmarks", "peaks.json")
 
-CACHE_EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "requests",
-                "/jax/compilation_cache/cache_hits": "hits",
-                "/jax/compilation_cache/cache_misses": "writes"}
+# the persistent cache's answers, from the program's own counters
+# (bigdl_tpu/obs/compile.py), under the names this script prints them by
+CACHE_COUNTERS = {"compile/cache_requests": "requests",
+                  "compile/cache_hits": "hits",
+                  "compile/cache_misses": "writes"}
 
 
 def say(phase: str, **fields) -> None:
@@ -542,21 +544,18 @@ def main() -> int:
     t0 = time.perf_counter()
     import jax
 
-    cache = dict.fromkeys(CACHE_EVENTS.values(), 0)
+    from bigdl_tpu.obs.registry import registry
 
-    def on_event(event, **_):
-        if event in CACHE_EVENTS:
-            cache[CACHE_EVENTS[event]] += 1
-
-    jax.monitoring.register_event_listener(on_event)
     devices = attach()
     train_vision()
     train_lm()
     routed_kernels()
     serve()
     all_devices()
+    counters = registry.snapshot()["counters"]
     say("done", seconds_total=round(time.perf_counter() - t0, 1),
-        compile_cache=cache)
+        compile_cache={name: counters.get(counter, 0)
+                       for counter, name in CACHE_COUNTERS.items()})
     print(json.dumps({"ok": True,
                       "device": {"platform": devices[0].platform,
                                  "kind": devices[0].device_kind,

@@ -7,9 +7,10 @@ and the rows past ``sum(group_sizes)`` come out as zeros, with zero
 gradients. ``m`` is static, the sizes are data. The routed layer
 (``parallel/moe.py``) calls it over one pass's rows, its static bound of twice
 the balanced expectation of held pairs (32,768 rows in the benchmark's SDAR
-cell, not the 131,072 pairs there are), with sizes that cover every row: the
-rows after the held pairs belong to the last held group, so the group of rows
-that no held group owns is empty there.
+cell, not the 131,072 pairs there are), with the sizes of the held groups
+inside the pass: the rows after the held pairs, about half of a pass at the
+balanced expectation, are in no group, so the products follow the pass's
+held rows and not its bound.
 
 On TPU this is the Pallas kernel jax ships (``jax.experimental.pallas.ops.tpu
 .megablox``: a grid over the row tiles that hold a group's rows, found from
@@ -40,6 +41,10 @@ SCOPE = "bigdl_gmm"
 # VMEM). A group's first and last row tiles are shared with its neighbours
 # and computed once for each, so a tile well under a group's rows wastes
 # least; the whole contraction in one tile saves the accumulator's round trips.
+# Swept again once the rows past the held pairs were skipped (PERF.md: 55%
+# of a pass's rows held, at the SDAR, SmallThinker and DeepSeek pass shapes):
+# 256 rows the best in all three, 128 1-16% slower, 512 2% slower at
+# DeepSeek's and out of VMEM in `tgmm` at the other two.
 _TILE_M, _TILE_K, _TILE_N = 256, 2048, 768
 
 
@@ -72,7 +77,9 @@ def grouped_matmul(lhs, rhs, group_sizes, force_pallas: bool | None = None):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     # the rows no held group owns are one more group, which `rhs` does not
-    # hold: the kernel leaves their tiles out and writes zeros there
+    # hold: the kernel leaves their tiles out and writes zeros there (the
+    # routed layer's sum by token multiplies those rows by a weight of 0 in
+    # a product over row blocks, which memory left as it was could poison)
     sizes = group_sizes.astype(jnp.int32)
     sizes = jnp.concatenate([sizes, (m - jnp.sum(sizes))[None]])
     with jax.named_scope(SCOPE):

@@ -100,10 +100,10 @@ from bigdl_tpu.utils.table import Table
 KEPT = RESIDUAL_NAMES + ROUTING_NAMES + ("decoder_after_attention",)
 
 # the layers' health leaves as the decoder's own state: the worst layer for
-# what warns, the sum for what counts
+# what warns, the sum for what counts, the mean for a share
 _HEALTH = {"aux_loss": jnp.sum, "router_z_loss": jnp.mean,
            "dropped_fraction": jnp.max, "expert_load_max": jnp.max,
-           "pairs_held": jnp.sum, "row_passes": jnp.max}
+           "pairs_held": jnp.sum, "row_passes": jnp.max, "pass_fill": jnp.mean}
 
 
 class LayerKind(NamedTuple):

@@ -142,7 +142,7 @@ class Optimizer:
     # any future layer exposing a same-named scalar rides for free.
     OBSERVABLE_STATE_LEAVES = ("aux_loss", "router_z_loss",
                                "dropped_fraction", "expert_load_max",
-                               "pairs_held", "row_passes")
+                               "pairs_held", "row_passes", "pass_fill")
 
     def __new__(cls, model: AbstractModule = None, dataset: AbstractDataSet = None,
                 criterion: AbstractCriterion = None, **kw):

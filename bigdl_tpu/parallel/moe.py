@@ -37,14 +37,16 @@ layer, by the router:
   trip count is the rest: once in every step unless the router sends this
   share more than twice its expectation). So no pair of a held expert can be
   dropped at any imbalance, nothing of (tokens × top_k, feature) exists, and
-  no capacity is set by anyone. A pass's products always run over all ``R``
-  rows (the rows after the held pairs, weight 0, go to the last held group),
-  so that a step's time follows the router's imbalance by whole passes only:
-  at the benchmark's seeded weights (an input embedding of N(0, 1), Adam at
-  1e-6) a layer's held pairs stay at 0.6 to 1.6 times the expectation, one
-  pass in every step, where weights all at N(0, 0.02) gave 0.01 to 2.4 from
-  batch to batch and a rate of 1e-4 pulled the router towards the held
-  experts (one chip's share, trained alone) within tens of steps (PERF.md).
+  no capacity is set by anyone. A pass's grouped products run over its held
+  rows only: the rows after the held pairs (weight 0) are in no held group,
+  and the kernel skips every row tile that holds none of a held group's rows,
+  so a step's time follows the held pairs (``pass_fill`` is the share of the
+  passes' rows they filled). At the benchmark's seeded weights (an input
+  embedding of N(0, 1), Adam at 1e-6) a layer's held pairs stay at 0.6 to
+  1.6 times the expectation, one pass in every step, where weights all at
+  N(0, 0.02) gave 0.01 to 2.4 from batch to batch and a rate of 1e-4 pulled
+  the router towards the held experts (one chip's share, trained alone)
+  within tens of steps (PERF.md).
   ``held`` is what one rank of an
   expert-parallel mesh axis holds: rank ``r`` of ``n`` holds
   ``(r * E // n, E // n)``, routes over all ``E`` and computes its own part;
@@ -115,19 +117,17 @@ def _window(x, weight, order, sizes, start, rows: int):
     pair of each row, whether it is a held pair, its token, the tokens' rows
     of ``x``, the routing weight of each row (0 past the held pairs) and the
     rows of each held group inside the window. The rows after the held pairs
-    (pairs of experts held elsewhere) go to the last held group, so that the
-    products always run over ``rows`` rows: a pass's time does not follow
-    the router's imbalance."""
+    (pairs of experts held elsewhere) are in no held group: the grouped
+    products skip them, so a pass's products follow its held rows."""
     with jax.named_scope(trace.SCOPE_MOE_ROUTE):
         pair = jax.lax.dynamic_slice(order, (start,), (rows,))
         token_of = pair // weight.shape[1]
         ends = jnp.cumsum(sizes)
         inside = (jnp.clip(ends, start, start + rows)
                   - jnp.clip(ends - sizes, start, start + rows))
-        padded = inside.at[-1].add(rows - jnp.sum(inside))
         live = start + jnp.arange(rows, dtype=jnp.int32) < ends[-1]
         by_row = jnp.where(live, weight.reshape(-1)[pair], 0.0)
-        return pair, live, token_of, x[token_of], by_row, padded
+        return pair, live, token_of, x[token_of], by_row, inside
 
 
 #: The gate's activation by the name ``MoE(gate=)`` takes.
@@ -186,8 +186,8 @@ def _routed_fwd(x, w_in, w_out, weight, order, sizes, passes, rows, hidden, gate
     tokens, k = weight.shape
 
     def one_pass(start):
-        _, live, token_of, xs, by_row, padded = _window(x, weight, order, sizes, start, rows)
-        out = _experts(xs, w_in, w_out, padded, hidden, gate)
+        _, live, token_of, xs, by_row, inside = _window(x, weight, order, sizes, start, rows)
+        out = _experts(xs, w_in, w_out, inside, hidden, gate)
         with jax.named_scope(trace.SCOPE_MOE_COMBINE):
             return sum_by_token(out, token_of, live, tokens, by_row)
 
@@ -202,10 +202,10 @@ def _routed_bwd(rows, hidden, gate, res, g):
     g = g.astype(x.dtype)
 
     def one_pass(start):
-        pair, live, token_of, xs, by_row, padded = _window(
+        pair, live, token_of, xs, by_row, inside = _window(
             x, weight, order, sizes, start, rows)
         out, pull = jax.vjp(
-            lambda a, b, c: _experts(a, b, c, padded, hidden, gate), xs, w_in, w_out)
+            lambda a, b, c: _experts(a, b, c, inside, hidden, gate), xs, w_in, w_out)
         with jax.named_scope(trace.SCOPE_MOE_COMBINE):
             g_rows = g[token_of]
             d_by_row = jnp.where(live, jnp.sum(
@@ -268,7 +268,9 @@ class MoE(TensorModule):
     held expert's gate and up matrices side by side and ``w_out`` (count, H, D).
     Its state adds ``pairs_held`` (the (token, expert) pairs that reached a held
     expert) and ``row_passes`` (the passes over its bound on rows that held
-    them: 1 unless this share got more than twice its balanced expectation);
+    them: 1 unless this share got more than twice its balanced expectation)
+    and ``pass_fill`` (the rows the grouped products ran over, the held pairs,
+    over the rows of the passes run: ``1 - pass_fill`` of them were skipped);
     ``expert_load`` is each expert's share of all pairs,
     ``dropped_fraction`` the held pairs that no pass found a row for (0), and
     ``aux_loss`` stays 0 (no balance loss is defined for it).
@@ -349,6 +351,7 @@ class MoE(TensorModule):
         if getattr(self, "router", None) == "topk":
             self._state["pairs_held"] = jnp.zeros((), jnp.float32)
             self._state["row_passes"] = jnp.zeros((), jnp.float32)
+            self._state["pass_fill"] = jnp.zeros((), jnp.float32)
         if self.z_loss_weight > 0:
             self._state["penalty"] = jnp.zeros((), jnp.float32)
         self.zero_grad_parameters()
@@ -475,6 +478,8 @@ class MoE(TensorModule):
         new_state["aux_loss"] = jnp.zeros((), jnp.float32)
         new_state["pairs_held"] = pairs
         new_state["row_passes"] = passes.astype(jnp.float32)
+        new_state["pass_fill"] = (jnp.sum(sizes).astype(jnp.float32)
+                                  / (passes * rows).astype(jnp.float32))
         # every held pair has a row in one of the passes
         new_state["dropped_fraction"] = (
             pairs - jnp.sum(sizes).astype(jnp.float32)) / jnp.maximum(pairs, 1.0)

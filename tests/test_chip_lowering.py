@@ -257,12 +257,13 @@ def test_a_sequence_whose_sums_pass_the_ceiling_is_refused_by_name():
         fa._bwd_vmem_limit(131072, 128, 2, fa._tiles(131072, 128, 2))
 
 
-@pytest.mark.parametrize("rows", [32768, 131072])
-def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch, rows):
+@pytest.mark.parametrize("rows,half_held", [(32768, False), (131072, False), (32768, True)])
+def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch, rows, half_held):
     """The grouped products of the SDAR cell's expert layer: a pass's 32,768
     rows (twice the balanced expectation of 16,384 positions x 8 over 16 of
     128 experts) and every pair there is, 16 held experts, gate and up side by
-    side, then down; forward and the gradients for rows and matrices."""
+    side, then down; forward and the gradients for rows and matrices. And a
+    pass whose sizes leave half its rows to no group, as at the expectation."""
     from bigdl_tpu.kernels import grouped_matmul as gm
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16)
@@ -271,6 +272,8 @@ def test_grouped_matmul_lowers_at_the_sdar_shapes(monkeypatch, rows):
     sizes = jax.ShapeDtypeStruct((16,), jnp.int32)
 
     def experts(x, a, b, n):
+        if half_held:       # 16 groups of 1,024 rows: the last 16,384 in none
+            n = jnp.full((16,), rows // 32, jnp.int32)
         h = gm.grouped_matmul(x, a, n)
         return gm.grouped_matmul(jax.nn.silu(h[:, :768]) * h[:, 768:], b, n) \
             .astype(jnp.float32).sum()

@@ -77,6 +77,13 @@ def test_a_period_of_four_matches_the_references_equations(bench, remat):
     # the reference's count of held pairs, all four layers', is the program's own
     assert pairs.shape == (4,) and int(pairs.sum()) == int(state["pairs_held"])
     assert float(state["dropped_fraction"]) == 0.0 and float(state["row_passes"]) >= 1.0
+    # each layer's held pairs over the rows of its passes, the mean of the four
+    from bigdl_tpu.parallel.moe import _held_rows
+    moe = model.experts
+    rows = _held_rows(x.size * moe.top_k, count, moe.n_experts)
+    pairs = np.asarray(pairs)
+    fills = pairs / (np.maximum(1, -(-pairs // rows)) * rows)
+    assert float(state["pass_fill"]) == pytest.approx(float(fills.mean()), rel=1e-6)
     grads = bench.names_from_tree(grads, names)
     assert set(grads) == set(want_grads)
     for k in want_grads:

@@ -137,6 +137,132 @@ def test_gradients_match_the_uncut_layer(layer, bias, pairs, passes):
         assert float(state["pairs_held"]) == pairs
 
 
+# held=(4, 4): a pass is 192 rows
+@pytest.mark.parametrize("bias,passes", [(None, 1), ("held", 2), (61, 2)])
+def test_pass_fill_is_the_held_pairs_over_the_rows_of_the_passes(layer, bias, passes):
+    """``pass_fill``: the rows the grouped products ran over, over the rows of
+    the passes run; 1 only where every pass is full."""
+    _, params, x = layer
+    first, count = 4, 4
+    if bias is not None:
+        params, x = _towards_held(params, x, first, count, None if bias == "held" else bias)
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
+    _, state = m.apply(_share(params, first, count), m.get_state(), x)
+    assert float(state["row_passes"]) == passes
+    assert float(state["pass_fill"]) == pytest.approx(
+        float(state["pairs_held"]) / (passes * 192), rel=1e-6)
+    assert (float(state["pass_fill"]) < 1.0) == (bias != "held")
+
+
+def _spy_on_the_sizes(monkeypatch):
+    """What the routed layer hands ``grouped_matmul`` as group sizes, each
+    call's sum, as the calls run (inside the passes' loop too)."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    seen, real = [], gm.grouped_matmul
+
+    def spy(lhs, rhs, group_sizes, force_pallas=None):
+        jax.debug.callback(lambda s: seen.append(int(s)), jnp.sum(group_sizes))
+        return real(lhs, rhs, group_sizes, force_pallas)
+
+    monkeypatch.setattr(gm, "grouped_matmul", spy)
+    return seen
+
+
+@pytest.mark.parametrize("bias,per_pass", [(None, None), (61, [192, 61 * K - 192])])
+def test_the_products_are_handed_the_held_rows_of_a_pass_only(layer, monkeypatch, bias, per_pass):
+    """Forward and VJP, the sizes of every grouped product sum to the held
+    pairs inside its pass, not to the pass's 192 rows: the rows past the held
+    pairs are in no group, and the last of two passes holds the remainder."""
+    _, params, x = layer
+    first, count = 4, 4
+    if bias is not None:
+        params, x = _towards_held(params, x, first, count, bias)
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
+    seen = _spy_on_the_sizes(monkeypatch)
+    y, state = m.apply(_share(params, first, count), m.get_state(), x)
+    jax.effects_barrier()
+    held = int(state["pairs_held"])
+    if per_pass is None:
+        assert 0 < held < 192
+        per_pass = [held]
+    assert sum(per_pass) == held
+    # gate and up, then down: two products a pass
+    assert sorted(seen) == sorted(per_pass * 2)
+    seen.clear()
+    jax.grad(lambda p: jnp.sum(m.apply(p, m.get_state(), x)[0]))(_share(params, first, count))
+    jax.effects_barrier()
+    # the forward's two a pass, and the backward's recomputed two a pass
+    assert sorted(seen) == sorted(per_pass * 4)
+
+
+@pytest.mark.parametrize("bias", [None, 61])
+def test_the_layer_through_the_kernel_skipping_dead_rows_is_the_uncut_layer(
+        layer, monkeypatch, bias):
+    """megablox's kernel through the interpreter, with the rows past the held
+    pairs left to the group it skips: the layer's output and every gradient
+    against the dense form, in one pass and in two."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    _, params, x = layer
+    first, count = 4, 4
+    if bias is not None:
+        params, x = _towards_held(params, x, first, count, bias)
+    real = gm.grouped_matmul
+    monkeypatch.setattr(gm, "grouped_matmul",
+                        lambda lhs, rhs, sizes: real(lhs, rhs, sizes, True))
+    m = MoE(D, HID, E, router="topk", top_k=K, held=(first, count))
+    probe = jnp.cos(jnp.arange(D))
+
+    def routed(p, x):
+        y, state = m.apply(_share(p, first, count), m.get_state(), x)
+        return jnp.sum(y * probe), (y, state)
+
+    def dense(p, x):
+        y = _uncut(x, p, K, first, count)
+        return jnp.sum(y * probe), y
+
+    with jax.default_matmul_precision("highest"):
+        got, (y, state) = jax.grad(routed, (0, 1), has_aux=True)(params, x)
+        want, y_want = jax.grad(dense, (0, 1), has_aux=True)(params, x)
+    np.testing.assert_allclose(y, y_want, atol=2e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=5e-6)
+    assert float(state["row_passes"]) == (1.0 if bias is None else 2.0)
+    assert float(state["pass_fill"]) < 1.0
+
+
+def test_a_pass_at_the_sdar_shape_runs_the_row_tiles_of_its_held_rows(monkeypatch):
+    """The SDAR cell's pass (16,384 positions x top-8, 16 of 128 experts
+    held: 32,768 rows) with half its rows held, at random cuts between the
+    groups: the sizes ``_window`` gives, as ``grouped_matmul`` hands them to
+    megablox, leave megablox's own count of row tiles at most 0.65 of the
+    count with the dead rows put in the last held group."""
+    from jax.experimental.pallas.ops.tpu import megablox
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    from bigdl_tpu.parallel.moe import _held_rows, _window
+    tokens, k, groups = 16384, 8, 16
+    rows = _held_rows(tokens * k, groups, 128)
+    assert rows == 32768
+    sizes = jnp.asarray(np.random.default_rng(0).multinomial(
+        rows // 2, np.full(groups, 1 / groups)), jnp.int32)
+    *_, inside = _window(jnp.zeros((tokens, 8)), jnp.ones((tokens, k)),
+                         jnp.arange(tokens * k, dtype=jnp.int32), sizes,
+                         jnp.int32(0), rows)
+    handed = []
+    monkeypatch.setattr(megablox, "gmm", lambda lhs, rhs, s, *rest: handed.append(s)
+                        or jnp.zeros((rows, rhs.shape[2])))
+    gm.grouped_matmul(jnp.zeros((rows, 8)), jnp.zeros((groups, 8, 8)), inside, True)
+
+    def row_tiles(s):
+        return int(make_group_metadata(group_sizes=s, m=rows, tm=gm._tile_m(rows),
+                                       start_group=jnp.int32(0), num_nonzero_groups=groups,
+                                       visit_empty_groups=False)[1])
+
+    padded = jnp.append(inside.at[-1].add(rows - jnp.sum(inside)), 0)
+    assert int(jnp.sum(handed[0][:groups])) == rows // 2
+    assert row_tiles(handed[0]) <= 0.65 * row_tiles(padded)
+
+
 @pytest.mark.parametrize("gate", ["relu", "silu"])
 @pytest.mark.parametrize("apart", [True, False])
 def test_the_routers_input_apart_from_the_experts_and_the_relu_gate(layer, gate, apart):
@@ -247,10 +373,12 @@ def test_no_tensor_of_every_pair_by_a_feature(layer, grad):
     assert wide and not any({D, HID, 2 * HID} & set(s) for s in wide), wide
 
 
-@pytest.mark.parametrize("sizes", [[40, 0, 100], [0, 0, 0], [128, 64, 64]])
+@pytest.mark.parametrize("sizes", [[40, 0, 100], [0, 0, 0], [128, 64, 64],
+                                   [30, 20, 10]])
 def test_grouped_matmul_kernel_matches_the_plain_form(sizes):
     """The Pallas kernel through the interpreter against ``ragged_dot``,
-    forward and both gradients; rows past the groups come out zero."""
+    forward and both gradients; rows past the groups (196 of 256 in the last
+    case, as a pass past its held pairs) come out zero."""
     key = jax.random.PRNGKey(0)
     lhs = jax.random.normal(key, (256, 64))
     rhs = jax.random.normal(jax.random.fold_in(key, 1), (3, 64, 48))

@@ -44,6 +44,31 @@ which runs over the routed layers that follow. ``n_shared_experts`` gives the
 routed layer its shared experts (``MoE(shared=)``). A config without these
 keys builds the decoder it did.
 
+One block a layer (Nemotron-H's form) where a config names
+``hybrid_override_pattern``, a letter a layer (its first
+``num_hidden_layers`` count):
+
+    h = h + Mixer(RMSNorm(h))
+
+with ``Mixer`` the layer's one kind: ``M`` Mamba-2's mixer
+(``nn.Mamba2Mixer`` from ``mamba_num_heads``, ``mamba_head_dim``,
+``n_groups``, ``ssm_state_size``, ``conv_kernel``, ``chunk_size`` and the
+``time_step_*`` keys; the convolution has its bias), ``*`` causal grouped-query
+attention without positions (the family's attention carries none), ``E`` the
+routed expert layer. Each kind's weights are stacked apart (``mamba``,
+``attention``, ``experts`` in the parameter tree, each ``{mixer, norm}``), so
+kinds whose weights differ in shape share one scan: the pattern's shortest
+period is written out inside the scan body, each block on its own slice of
+its kind's stack, and the scan runs over the periods. Its expert layer takes
+``scoring_func`` (``"sigmoid"``: chosen by the score plus a correction bias,
+``router_bias`` in the decoder's state, a buffer a layer that no gradient
+reaches; weighted by the score alone), ``routed_scaling_factor``,
+``moe_latent_size`` (the routed experts between a down and an up projection,
+router and shared expert on the full width),
+``moe_shared_expert_intermediate_size`` and ``mlp_hidden_act`` (``relu2``:
+ungated experts, read as ``expert_gate``); ``layer_norm_epsilon`` is read as
+``rms_norm_eps``. A config without the pattern builds the decoder it did.
+
 The modules are the layer's templates: the scan body calls their ``apply`` on
 a layer's slice of the stacked parameters, attention under the scope
 ``bigdl_attn_window``, ``bigdl_attn_full`` or ``bigdl_attn_latent`` by kind
@@ -56,7 +81,11 @@ hidden state after attention), ``N * T * ((heads + kv_heads) * (d_qk + d_v)
 value head (both ``head_dim`` but under latent attention, where ``kv_heads``
 is ``heads``), and the routing's ``12 * N * T * top_k``: 0.44 GB a layer at 2
 x 8,192 positions, 32 + 4 + 4 heads of 128 and hidden 2,048 in bf16, 0.47 GB
-at 16,384 positions, 16 + 16 heads of 192 / 128 and hidden 2,048; the norms,
+at 16,384 positions, 16 + 16 heads of 192 / 128 and hidden 2,048. A Mamba
+block keeps its input projection (``mamba_in``), ``N * T * (2 * I + 2 * G * S
++ H) * 2`` bytes with ``I`` heads x ``mamba_head_dim``, ``G`` groups, ``S``
+the state and ``H`` heads: 38 MB a layer at 8,192 positions, 16 heads of 64,
+one group and a state of 128 (its input 67 MB beside it); the norms,
 the projections into the heads (the per-head norms' backward reads their
 results), the router's logits and its softmax run again in the backward pass,
 the flash forward kernel, the output projection, top-k and the sort do not.
@@ -88,6 +117,7 @@ from bigdl_tpu.kernels.flash_attention import RESIDUAL_NAMES, BlockDiffusion
 from bigdl_tpu.nn.abstractnn import TensorModule
 from bigdl_tpu.nn.criterion import AbstractCriterion
 from bigdl_tpu.nn.fused_loss import chunked_softmax_xent
+from bigdl_tpu.nn.mamba import MAMBA_NAMES, Mamba2Mixer
 from bigdl_tpu.obs import trace
 from bigdl_tpu.parallel.moe import ROUTING_NAMES, MoE, gated_mlp
 from bigdl_tpu.utils.random_generator import RandomGenerator
@@ -95,9 +125,13 @@ from bigdl_tpu.utils.table import Table
 
 #: What a rematerialised layer keeps across the backward pass beside its
 #: input, by the names the values are tagged with where they are made: the
-#: flash kernels' operands and residuals, the routing, and the hidden state
-#: after attention (tagged in ``apply``).
-KEPT = RESIDUAL_NAMES + ROUTING_NAMES + ("decoder_after_attention",)
+#: flash kernels' operands and residuals, the routing, the hidden state
+#: after attention (tagged in ``apply``) and a Mamba mixer's input projection.
+KEPT = RESIDUAL_NAMES + ROUTING_NAMES + ("decoder_after_attention",) + MAMBA_NAMES
+
+#: The blocks a ``hybrid_override_pattern`` names, one a layer, by the group
+#: of the parameter tree that stacks each kind's weights.
+BLOCKS = {"M": "mamba", "*": "attention", "E": "experts"}
 
 # the layers' health leaves as the decoder's own state: the worst layer for
 # what warns, the sum for what counts, the mean for a share
@@ -135,12 +169,20 @@ class ConfigDecoder(TensorModule):
                    "sliding_window_size", "rope_layout", "kv_lora_rank",
                    "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
                    "v_head_dim", "rope_scaling", "first_k_dense_replace",
-                   "intermediate_size", "n_shared_experts")
+                   "intermediate_size", "n_shared_experts",
+                   "hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
+                   "n_groups", "ssm_state_size", "conv_kernel", "chunk_size",
+                   "time_step_min", "time_step_max",
+                   "time_step_floor", "moe_latent_size",
+                   "moe_shared_expert_intermediate_size", "scoring_func",
+                   "routed_scaling_factor")
     #: other spellings of those keys that published configs use
     CONFIG_ALIASES = {"moe_ffn_hidden_size": "moe_intermediate_size",
                       "moe_num_primary_experts": "num_experts",
                       "moe_num_active_primary_experts": "num_experts_per_tok",
-                      "n_routed_experts": "num_experts"}
+                      "n_routed_experts": "num_experts",
+                      "layer_norm_epsilon": "rms_norm_eps",
+                      "mlp_hidden_act": "expert_gate"}
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  num_hidden_layers: int, num_attention_heads: int,
@@ -163,7 +205,17 @@ class ConfigDecoder(TensorModule):
                  rope_scaling: Optional[dict] = None,
                  first_k_dense_replace: int = 0,
                  intermediate_size: Optional[int] = None,
-                 n_shared_experts: int = 0):
+                 n_shared_experts: int = 0,
+                 hybrid_override_pattern: Optional[str] = None,
+                 mamba_num_heads: Optional[int] = None,
+                 mamba_head_dim: Optional[int] = None, n_groups: int = 1,
+                 ssm_state_size: Optional[int] = None, conv_kernel: int = 4,
+                 chunk_size: int = 128, time_step_min: float = 0.001, time_step_max: float = 0.1,
+                 time_step_floor: float = 1e-4,
+                 moe_latent_size: Optional[int] = None,
+                 moe_shared_expert_intermediate_size: Optional[int] = None,
+                 scoring_func: str = "softmax",
+                 routed_scaling_factor: float = 1.0):
         super().__init__()
         self.vocab_size, self.hidden_size = int(vocab_size), int(hidden_size)
         #: the leading dense layers, before the scan over the routed ones
@@ -177,20 +229,38 @@ class ConfigDecoder(TensorModule):
             raise ValueError(f"router_input must be 'experts' or 'layer', got "
                              f"{router_input!r}")
         self.router_input = router_input
-        windowed = [0] * n if sliding_window_layout is None else \
-            list(sliding_window_layout)[:n]
-        turned = [1] * n if rope_layout is None else list(rope_layout)[:n]
-        if len(windowed) != n or len(turned) != n:
-            raise ValueError(f"a layout shorter than the {n} layers")
-        if any(windowed) and sliding_window_size is None:
-            raise ValueError("sliding_window_layout names windowed layers and "
-                             "sliding_window_size is not given")
-        #: the layer pattern's shortest period, a ``LayerKind`` a layer
-        self.period = _period([
-            LayerKind(int(sliding_window_size) if w else None, bool(r))
-            for w, r in zip(windowed, turned)])
-        mask = BlockDiffusion(*self.block_diffusion) if self.block_diffusion else None
         self.norm = nn.RMSNorm(hidden_size, eps=rms_norm_eps)
+        #: one letter of ``BLOCKS`` a layer, where the config names them
+        self.pattern = None
+        mask = BlockDiffusion(*self.block_diffusion) if self.block_diffusion else None
+        if hybrid_override_pattern is not None:
+            pattern = str(hybrid_override_pattern)[:n]
+            if len(pattern) != n or set(pattern) - set(BLOCKS):
+                raise ValueError(f"hybrid_override_pattern must name one of "
+                                 f"{sorted(BLOCKS)} for each of the {n} layers, "
+                                 f"got {hybrid_override_pattern!r}")
+            if (k or mask is not None or kv_lora_rank is not None
+                    or sliding_window_layout is not None or rope_layout is not None
+                    or router_input != "experts"):
+                raise ValueError("a hybrid pattern's layers are one block each: no "
+                                 "leading dense layer, mask, window, layout, latent "
+                                 "attention or router input beside it")
+            self.pattern = pattern
+            #: the pattern's shortest period, a letter a layer
+            self.period = _period(list(pattern))
+        else:
+            windowed = [0] * n if sliding_window_layout is None else \
+                list(sliding_window_layout)[:n]
+            turned = [1] * n if rope_layout is None else list(rope_layout)[:n]
+            if len(windowed) != n or len(turned) != n:
+                raise ValueError(f"a layout shorter than the {n} layers")
+            if any(windowed) and sliding_window_size is None:
+                raise ValueError("sliding_window_layout names windowed layers and "
+                                 "sliding_window_size is not given")
+            #: the layer pattern's shortest period, a ``LayerKind`` a layer
+            self.period = _period([
+                LayerKind(int(sliding_window_size) if w else None, bool(r))
+                for w, r in zip(windowed, turned)])
         if kv_lora_rank is not None:
             if q_lora_rank is not None:
                 raise ValueError("latent attention with a query low-rank path "
@@ -204,34 +274,52 @@ class ConfigDecoder(TensorModule):
                 qk_rope_head_dim, v_head_dim, rope_base=rope_theta,
                 rope_scaling=rope_scaling, norm_eps=rms_norm_eps)]
         else:
-            #: one attention template a layer of the period
+            #: one attention template a layer of the period (of a hybrid
+            #: pattern: one, causal, without RoPE)
+            kinds = [LayerKind(None, False)] if self.pattern else self.period
             self.attentions = [nn.MultiHeadAttention(
                 hidden_size, num_attention_heads, causal=mask is None,
                 with_bias=False, num_kv_heads=num_key_value_heads, rope=kind.rope,
                 rope_base=rope_theta, window=kind.window, head_dim=head_dim,
                 qk_norm=qk_norm, qk_norm_eps=rms_norm_eps, mask=mask)
-                for kind in self.period]
+                for kind in kinds]
+        shared = int(n_shared_experts or 0) * (
+            moe_shared_expert_intermediate_size or moe_intermediate_size)
         self.experts = MoE(hidden_size, moe_intermediate_size, num_experts,
                            router="topk", top_k=num_experts_per_tok,
                            norm_topk_prob=norm_topk_prob, held=held,
-                           gate=expert_gate,
-                           shared=int(n_shared_experts or 0) * moe_intermediate_size)
+                           gate=expert_gate, shared=shared, scoring=scoring_func,
+                           routed_scale=routed_scaling_factor, latent=moe_latent_size)
         self.expert_gate = expert_gate
+        templates = (*self.attentions, self.experts, self.norm)
         # the templates lend their shapes; their own copies are never read
         norm_shape = self.norm.get_params()["weight"].shape
-        attn_shapes = jax.tree_util.tree_map(lambda a: a.shape,
-                                             self.attentions[0].get_params())
-        self._layer_shapes = {"attn": attn_shapes, "attn_norm": norm_shape,
-                              "moe": jax.tree_util.tree_map(
-                                  lambda a: a.shape, self.experts.get_params()),
-                              "moe_norm": norm_shape}
+        shapes_of = lambda module: jax.tree_util.tree_map(lambda a: a.shape,
+                                                          module.get_params())
+        attn_shapes = shapes_of(self.attentions[0])
+        if self.pattern:
+            #: the Mamba mixer's template, where the pattern names one
+            self.mamba = Mamba2Mixer(
+                hidden_size, mamba_num_heads, mamba_head_dim, n_groups,
+                ssm_state_size, conv_kernel, chunk_size, rms_norm_eps,
+                initializer_range, time_step_min, time_step_max,
+                time_step_floor) if "M" in self.pattern else None
+            mixers = {"M": self.mamba, "*": self.attentions[0], "E": self.experts}
+            self._layer_shapes = {
+                BLOCKS[kind]: {"mixer": shapes_of(mixers[kind]), "norm": norm_shape}
+                for kind in sorted(set(self.pattern))}
+            templates += (self.mamba,) if self.mamba else ()
+        else:
+            self._layer_shapes = {"attn": attn_shapes, "attn_norm": norm_shape,
+                                  "moe": shapes_of(self.experts),
+                                  "moe_norm": norm_shape}
         if k:
             self._dense_shapes = {
                 "attn": attn_shapes, "attn_norm": norm_shape,
                 "mlp": {"w_in": (hidden_size, 2 * intermediate_size),
                         "w_out": (intermediate_size, hidden_size)},
                 "mlp_norm": norm_shape}
-        for template in (*self.attentions, self.experts, self.norm):
+        for template in templates:
             template._params, template._grads = {}, {}
         self.reset()
 
@@ -245,8 +333,9 @@ class ConfigDecoder(TensorModule):
         return cls(**{**{k: config[named] for k, named in keys.items()}, **more})
 
     def reset(self) -> None:
-        """Matrices N(0, ``initializer_range``), gains 1, drawn on the device
-        from the global generator's next salt."""
+        """Matrices N(0, ``initializer_range``), gains 1, a Mamba mixer's
+        leaves as ``Mamba2Mixer.draw`` makes them, drawn on the device from the
+        global generator's next salt."""
         k, std = self.dense_layers, self.initializer_range
         is_shape = lambda v: isinstance(v, tuple)
         # (shape, is a gain): a gain holds one number a channel
@@ -254,22 +343,45 @@ class ConfigDecoder(TensorModule):
             lambda shape: ((n,) + shape, len(shape) == 1), shapes, is_leaf=is_shape)
         spec = {"embed": ((self.vocab_size, self.hidden_size), False),
                 "final_norm": ((self.hidden_size,), True),
-                "head": ((self.vocab_size, self.hidden_size), False),
-                "layers": stack(self.num_hidden_layers - k, self._layer_shapes)}
+                "head": ((self.vocab_size, self.hidden_size), False)}
+        if self.pattern:
+            counts = self.kind_counts()
+            for kind, group in BLOCKS.items():
+                if counts[kind]:
+                    spec[group] = stack(counts[kind], self._layer_shapes[group])
+            if counts["M"]:
+                # (shape, the leaf's name): drawn as the family draws it
+                spec["mamba"]["mixer"] = {name: ((counts["M"],) + shape, name)
+                                          for name, shape in self.mamba.shapes().items()}
+        else:
+            spec["layers"] = stack(self.num_hidden_layers - k, self._layer_shapes)
         if k:
             spec["dense"] = stack(k, self._dense_shapes)
         leaves, treedef = jax.tree_util.tree_flatten(spec, is_leaf=is_shape)
 
         def draw(key):
-            return [jnp.ones(shape, jnp.float32) if gain else
+            return [self.mamba.draw(how, jax.random.fold_in(key, i), shape)
+                    if isinstance(how, str) else
+                    jnp.ones(shape, jnp.float32) if how else
                     std * jax.random.normal(jax.random.fold_in(key, i), shape,
                                             jnp.float32)
-                    for i, (shape, gain) in enumerate(leaves)]
+                    for i, (shape, how) in enumerate(leaves)]
 
         key = jax.random.PRNGKey(RandomGenerator.next_salt() & 0x7FFFFFFF)
         self._params = jax.tree_util.tree_unflatten(treedef, jax.jit(draw)(key))
         self._state = {k: jnp.zeros((), jnp.float32) for k in _HEALTH}
+        if "router_bias" in self.experts.get_state():
+            # the sigmoid router's correction bias, a buffer a routed layer:
+            # it steers the choice alone and no gradient reaches it
+            routed = (self.kind_counts()["E"] if self.pattern
+                      else self.num_hidden_layers - k)
+            self._state["router_bias"] = jnp.zeros(
+                (routed, self.experts.n_experts), jnp.float32)
         self.zero_grad_parameters()
+
+    def kind_counts(self) -> dict:
+        """Layers of each kind of block in a hybrid pattern, by its letter."""
+        return {kind: self.pattern.count(kind) for kind in BLOCKS}
 
     def zero_grad_parameters(self) -> None:
         # no buffer of zeros the size of the model: the optimizers never read
@@ -293,10 +405,69 @@ class ConfigDecoder(TensorModule):
                              f"{2 * length} positions, got {t}")
         return jnp.tile(jnp.arange(length), 2)
 
+    def _experts_state(self, p):
+        """The expert layers' template state, with the layer's own
+        correction bias where ``p`` carries one."""
+        state = self.experts.get_state()
+        if "router_bias" in p:
+            state = {**state, "router_bias": jax.lax.stop_gradient(p["router_bias"])}
+        return state
+
+    def _blocks(self, params, state, h, positions, training):
+        """The hybrid pattern's layers, ``h = h + Mixer(RMSNorm(h))`` each:
+        the period written out inside one scan body, each kind of block on
+        its own slice of its group's weights, and the scan over periods."""
+        norm, attention = self.norm, self.attentions[0]
+
+        def block_of(kind):
+            def block(h, p):
+                x, _ = norm.apply({"weight": p["norm"]}, {}, h)
+                if kind == "M":
+                    return h + self.mamba.apply(p["mixer"], {}, x)[0], None
+                if kind == "*":
+                    with jax.named_scope(trace.SCOPE_ATTN_FULL):
+                        a, _ = attention.apply(p["mixer"], {}, (x, positions),
+                                               training=training)
+                    return h + a, None
+                y, health = self.experts.apply(p["mixer"], self._experts_state(p), x,
+                                               training=training)
+                return h + y, {k: health[k] for k in _HEALTH}
+
+            if self.remat:
+                return jax.checkpoint(block, policy=jax.checkpoint_policies
+                                      .save_only_these_names(*KEPT))
+            return block
+
+        blocks = {kind: block_of(kind) for kind in set(self.period)}
+        per = {kind: self.period.count(kind) for kind in blocks}
+        groups = {BLOCKS[kind]: params[BLOCKS[kind]] for kind in blocks}
+        if "router_bias" in state and "experts" in groups:
+            groups["experts"] = {**groups["experts"], "router_bias": state["router_bias"]}
+        # each group cut into periods: (periods, its layers in a period, ...)
+        stacked = {group: jax.tree_util.tree_map(
+            lambda a, n=per[kind]: a.reshape((-1, n) + a.shape[1:]), groups[group])
+            for kind, group in BLOCKS.items() if group in groups}
+
+        def body(h, period):
+            seen, health = dict.fromkeys(per, 0), []
+            for kind in self.period:
+                p = jax.tree_util.tree_map(lambda a: a[seen[kind]], period[BLOCKS[kind]])
+                seen[kind] += 1
+                h, leaves = blocks[kind](h, p)
+                if leaves is not None:
+                    health.append(leaves)
+            return h, {k: jnp.stack([leaves[k] for leaves in health]) for k in _HEALTH} \
+                if health else {}
+
+        return jax.lax.scan(body, h, stacked)
+
     def apply(self, params, state, input, *, training=False, rng=None):
         h = params["embed"][input]                              # (N, T, D)
         positions = self._positions(input.shape[1])
-        norm, experts_state = self.norm, self.experts.get_state()
+        if self.pattern:
+            h, health = self._blocks(params, state, h, positions, training)
+            return self._head(params, state, h, health, training)
+        norm = self.norm
 
         def layer_of(attention, dense=False):
             if isinstance(attention, nn.LatentAttention):
@@ -318,7 +489,7 @@ class ConfigDecoder(TensorModule):
                                                  self.expert_gate)
                 m, _ = norm.apply({"weight": p["moe_norm"]}, {}, after)
                 m, health = self.experts.apply(
-                    p["moe"], experts_state,
+                    p["moe"], self._experts_state(p),
                     (m, h) if self.router_input == "layer" else m,
                     training=training)
                 return after + m, {k: health[k] for k in _HEALTH}
@@ -334,14 +505,16 @@ class ConfigDecoder(TensorModule):
             for i in range(self.dense_layers):
                 h = dense(h, jax.tree_util.tree_map(lambda a: a[i], params["dense"]))
         layers = [layer_of(attention) for attention in self.attentions]
+        stacked = params["layers"]
+        if "router_bias" in state:
+            stacked = {**stacked, "router_bias": state["router_bias"]}
         if len(layers) == 1:
-            body, stacked = layers[0], params["layers"]
+            body = layers[0]
         else:
             # the period written out: each of its layers on its own slice of
             # the period's weights, the health leaves a row a layer
             stacked = jax.tree_util.tree_map(
-                lambda a: a.reshape((-1, len(layers)) + a.shape[1:]),
-                params["layers"])
+                lambda a: a.reshape((-1, len(layers)) + a.shape[1:]), stacked)
 
             def body(h, period):
                 health = []
@@ -353,16 +526,30 @@ class ConfigDecoder(TensorModule):
                            for k in _HEALTH}
 
         h, health = jax.lax.scan(body, h, stacked)
+        return self._head(params, state, h, health, training)
+
+    def _head(self, params, state, h, health, training):
+        """The final norm and the head; the layers' health leaves folded
+        into the decoder's state (as they were, where no layer routes), the
+        correction biases passed on as they came."""
         if self.block_diffusion:
             h = h[:, :self.block_diffusion[0]]      # the noised half predicts
-        h, _ = norm.apply({"weight": params["final_norm"]}, {}, h)
-        new_state = {k: jax.lax.stop_gradient(fold(health[k]))
+        h, _ = self.norm.apply({"weight": params["final_norm"]}, {}, h)
+        new_state = {k: jax.lax.stop_gradient(fold(health[k])) if health else state[k]
                      for k, fold in _HEALTH.items()}
+        if "router_bias" in state:
+            new_state["router_bias"] = state["router_bias"]
         if training:
             return Table(h, params["head"]), new_state
         return h @ params["head"].T, new_state
 
     def __repr__(self):
+        if self.pattern:
+            kinds = f" in periods of {''.join(self.period)}"
+            mixers = ", ".join(repr(m) for m in (self.mamba, self.attentions[0], self.experts)
+                               if m is not None)
+            return (f"ConfigDecoder({self.num_hidden_layers} layers{kinds}, "
+                    f"hidden={self.hidden_size}, {mixers})")
         kinds = "" if len(self.period) == 1 else f" in periods of {list(self.period)}"
         if self.dense_layers:
             kinds += f", the first {self.dense_layers} dense"

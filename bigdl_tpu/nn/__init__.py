@@ -30,6 +30,7 @@ from bigdl_tpu.nn.convolution import (
     SpatialSeparableConvolution, SpatialShareConvolution, TemporalConvolution,
 )
 from bigdl_tpu.nn.embedding import HashBucketEmbedding, LookupTable
+from bigdl_tpu.nn.mamba import Mamba2Mixer
 from bigdl_tpu.nn.graph import (
     Graph, Input, ModuleNode, StaticGraph, fuse_conv_bn,
 )

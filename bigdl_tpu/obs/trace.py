@@ -68,6 +68,12 @@ SCOPE_ATTN_WINDOW = "bigdl_attn_window"
 SCOPE_ATTN_LATENT = "bigdl_attn_latent"
 SCOPE_MOE_SHARED = "bigdl_moe_shared"
 SCOPE_MLP_DENSE = "bigdl_mlp_dense"
+#: and of Mamba-2's mixer (``nn.Mamba2Mixer``, whole: projections,
+#: convolution, scan, gated norm), of its chunked scan inside it, and of the
+#: routed layer's projections into and out of its experts' latent space
+SCOPE_MAMBA = "bigdl_mamba"
+SCOPE_SSM_SCAN = "bigdl_ssm_scan"
+SCOPE_MOE_LATENT = "bigdl_moe_latent"
 
 
 class SpanRecord(NamedTuple):

@@ -15,7 +15,17 @@ layer, by the router:
 - ``topk``: the routed layer of today's open models (softmax over all
   experts, the ``top_k`` largest, their weights renormalised, gated experts
   without biases, the gate's activation ``gate="silu"`` or ``"relu"``),
-  dropless, and told which experts it holds: ``held=(first, count)``. The
+  dropless, and told which experts it holds: ``held=(first, count)``. Beside
+  the softmax stands a sigmoid (``scoring="sigmoid"``, Nemotron-H's and
+  DeepSeek-V3's): each expert scored by the sigmoid of its logit, the
+  ``top_k`` largest of score plus a correction bias chosen (``router_bias``
+  in the layer's state: a buffer that steers the choice alone, no gradient
+  reaches it), their scores renormalised with ``1e-20`` beside the sum. A
+  ``routed_scale`` multiplies the weights; ``latent`` puts the routed experts
+  in a space of that width between a down and an up projection (scope
+  ``bigdl_moe_latent``) while the router and the shared experts read the full
+  width; ``gate="relu2"`` makes the experts and the shared experts ungated,
+  ``relu(x W1)^2 W2``, one input matrix in the grouped products. The
   router may read another tensor than the experts do: the input ``(x, r)``
   routes on ``r`` (a decoder whose router stands before attention hands it
   the layer's input) and feeds the experts ``x``. With ``shared`` (the
@@ -106,6 +116,29 @@ def _top_k_bwd(k, n_experts, top_e, g):
 _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _top_k_biased(scores, bias, k: int, n_experts: int):
+    """``_top_k`` where the choice and the weights part: the ``k`` largest of
+    ``scores + bias``, the ``scores`` at them. ``bias`` (a buffer, the
+    sigmoid router's correction) gets no gradient."""
+    return _top_k_biased_fwd(scores, bias, k, n_experts)[0]
+
+
+def _top_k_biased_fwd(scores, bias, k, n_experts):
+    _, top_e = jax.lax.top_k(scores + bias, k)
+    top_e = checkpoint_name(top_e, "moe_top_e")
+    top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+    return (checkpoint_name(top_p, "moe_top_p"), top_e), (top_e, bias)
+
+
+def _top_k_biased_bwd(k, n_experts, res, g):
+    top_e, bias = res
+    return _top_k_bwd(k, n_experts, top_e, g)[0], jnp.zeros_like(bias)
+
+
+_top_k_biased.defvjp(_top_k_biased_fwd, _top_k_biased_bwd)
+
+
 def _held_rows(total: int, count: int, n_experts: int) -> int:
     """The static bound on rows of one pass: twice the balanced expectation
     of held pairs, in whole sublanes, and never more than every pair."""
@@ -130,28 +163,40 @@ def _window(x, weight, order, sizes, start, rows: int):
         return pair, live, token_of, x[token_of], by_row, inside
 
 
-#: The gate's activation by the name ``MoE(gate=)`` takes.
+#: An expert's activation by the name ``MoE(gate=)`` takes. Of these an
+#: expert is gated, ``GATES[gate](x Wg) * (x Wu)`` with ``Wg`` and ``Wu`` side
+#: by side in one matrix ...
 GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+#: ... and of these it is not: ``UNGATED[gate](x W1)``, one input matrix
+#: (``relu2``, ReLU squared: Nemotron-H's ``mlp_hidden_act``).
+UNGATED = {"relu2": lambda u: jnp.square(jax.nn.relu(u))}
+
+
+def _activate(h, hidden: int, gate: str):
+    if gate in UNGATED:
+        return UNGATED[gate](h)
+    return GATES[gate](h[..., :hidden]) * h[..., hidden:]
 
 
 def gated_mlp(x, w_in, w_out, gate: str = "silu"):
     """``(GATES[gate](x Wg) * (x Wu)) Wd``, a plain gated MLP that every row
     passes through: ``w_in`` (D, 2H) holds ``Wg`` beside ``Wu``, ``w_out`` is
-    (H, D). The routed layer's shared experts and a decoder's dense layers."""
+    (H, D); or, for a ``gate`` of ``UNGATED``, ``UNGATED[gate](x W1) W2`` with
+    ``w_in`` (D, H). The routed layer's shared experts and a decoder's dense
+    layers."""
     h = x @ w_in.astype(x.dtype)
-    hidden = w_out.shape[0]
-    return (GATES[gate](h[..., :hidden]) * h[..., hidden:]) @ w_out.astype(x.dtype)
+    return _activate(h, w_out.shape[0], gate) @ w_out.astype(x.dtype)
 
 
 def _experts(xs, w_in, w_out, sizes, hidden: int, gate: str):
     """Gate and up in one grouped product, the gate (``GATES[gate]`` of the
-    first half times the second), down in another."""
+    first half times the second), down in another; an ungated expert's one
+    input matrix and its activation in the first's place."""
     from bigdl_tpu.kernels.grouped_matmul import grouped_matmul
 
     with jax.named_scope(trace.SCOPE_MOE_EXPERTS):
         h = grouped_matmul(xs, w_in, sizes)
-        act = GATES[gate](h[:, :hidden]) * h[:, hidden:]
-        return grouped_matmul(act, w_out, sizes)
+        return grouped_matmul(_activate(h, hidden, gate), w_out, sizes)
 
 
 def _over_passes(one_pass, passes, rows: int, total: int):
@@ -259,7 +304,11 @@ class MoE(TensorModule):
 
     ``router="topk"`` (module docstring) takes ``top_k``, ``norm_topk_prob``,
     ``held=(first, count)`` (default: all experts) and ``gate`` (``"silu"`` or
-    ``"relu"``: the activation of an expert's gate), ``shared`` (the width of
+    ``"relu"``: the activation of an expert's gate; ``"relu2"``: ungated, and
+    ``w_in`` (count, D, H) one matrix), ``scoring`` (``"softmax"`` or
+    ``"sigmoid"``, whose state adds ``router_bias`` (E,)), ``routed_scale``,
+    ``latent`` (then ``latent_down`` (D, L) and ``latent_up`` (L, D), and the
+    experts' ``w_in`` and ``w_out`` at L in D's place), ``shared`` (the width of
     the shared experts side by side; 0, the default, for none: then there are
     no ``shared_in`` (D, 2 shared) and ``shared_out`` (shared, D)) and ignores
     ``capacity_factor``: nothing is dropped. Its input may be ``(x, r)``: the
@@ -282,7 +331,8 @@ class MoE(TensorModule):
                  w_init: Optional[InitializationMethod] = None,
                  top_k: Optional[int] = None, norm_topk_prob: bool = True,
                  held: Optional[tuple] = None, gate: str = "silu",
-                 shared: int = 0):
+                 shared: int = 0, scoring: str = "softmax",
+                 routed_scale: float = 1.0, latent: Optional[int] = None):
         super().__init__()
         if router not in ("top1", "top2", "expert_choice", "topk"):
             raise ValueError(f"router must be 'top1', 'top2', "
@@ -298,10 +348,17 @@ class MoE(TensorModule):
                     or held[0] + held[1] > n_experts:
                 raise ValueError(f"held must be (first, count) within the "
                                  f"{n_experts} experts, got {held!r}")
-            if gate not in GATES:
-                raise ValueError(f"gate must be one of {sorted(GATES)}, got {gate!r}")
-        elif top_k is not None or held is not None or gate != "silu" or shared:
-            raise ValueError("top_k, held, gate and shared belong to router='topk'")
+            if gate not in GATES and gate not in UNGATED:
+                raise ValueError(f"gate must be one of "
+                                 f"{sorted(GATES) + sorted(UNGATED)}, got {gate!r}")
+            if scoring not in ("softmax", "sigmoid"):
+                raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
+            if latent is not None and int(latent) < 1:
+                raise ValueError(f"latent must be >= 1, got {latent!r}")
+        elif (top_k is not None or held is not None or gate != "silu" or shared
+              or scoring != "softmax" or routed_scale != 1.0 or latent is not None):
+            raise ValueError("top_k, held, gate, shared, scoring, routed_scale and "
+                             "latent belong to router='topk'")
         self.input_size = input_size
         self.hidden_size = hidden_size
         self.n_experts = n_experts
@@ -313,6 +370,9 @@ class MoE(TensorModule):
         self.held = held
         self.gate = gate
         self.shared = int(shared)
+        self.scoring = scoring
+        self.routed_scale = float(routed_scale)
+        self.latent = None if latent is None else int(latent)
         self.z_loss_weight = float(z_loss_weight)
         self.w_init = w_init or RandomNormal(0.0, 0.02)
         self.reset()
@@ -325,13 +385,18 @@ class MoE(TensorModule):
                                                 fan_out=fan_out))
 
         if getattr(self, "router", None) == "topk":
-            n = self.held[1]
+            n, wide = self.held[1], 1 if getattr(self, "gate", "silu") in UNGATED else 2
+            # the experts' own width in and out: the latent space where one is
+            x = getattr(self, "latent", None) or d
             self._params = {"w_gate": mk((d, e), d, e),
-                            "w_in": mk((n, d, 2 * h), d, h),
-                            "w_out": mk((n, h, d), h, d)}
+                            "w_in": mk((n, x, wide * h), x, h),
+                            "w_out": mk((n, h, x), h, x)}
+            if x != d:
+                self._params.update(latent_down=mk((d, x), d, x),
+                                    latent_up=mk((x, d), x, d))
             if getattr(self, "shared", 0):
                 s = self.shared
-                self._params.update(shared_in=mk((d, 2 * s), d, s),
+                self._params.update(shared_in=mk((d, wide * s), d, s),
                                     shared_out=mk((s, d), s, d))
         else:
             self._params = {
@@ -352,6 +417,8 @@ class MoE(TensorModule):
             self._state["pairs_held"] = jnp.zeros((), jnp.float32)
             self._state["row_passes"] = jnp.zeros((), jnp.float32)
             self._state["pass_fill"] = jnp.zeros((), jnp.float32)
+            if self.scoring == "sigmoid":
+                self._state["router_bias"] = jnp.zeros((e,), jnp.float32)
         if self.z_loss_weight > 0:
             self._state["penalty"] = jnp.zeros((), jnp.float32)
         self.zero_grad_parameters()
@@ -440,13 +507,24 @@ class MoE(TensorModule):
         first, count = self.held
         total = tokens * k
         rows = _held_rows(total, count, self.n_experts)
+        scoring = getattr(self, "scoring", "softmax")
         with jax.named_scope(trace.SCOPE_MOE_ROUTE):
             logits = jnp.dot(routed_on, params["w_gate"].astype(routed_on.dtype),
                              preferred_element_type=jnp.float32)
-            probs = jax.nn.softmax(logits, axis=-1)
-            top_p, top_e = _top_k(probs, k, self.n_experts)         # (T, k)
-            if self.norm_topk_prob:
-                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if scoring == "sigmoid":
+                # chosen by score + correction bias, weighted by the score alone
+                top_p, top_e = _top_k_biased(
+                    jax.nn.sigmoid(logits), jax.lax.stop_gradient(
+                        state["router_bias"].astype(jnp.float32)), k, self.n_experts)
+                if self.norm_topk_prob:
+                    top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+                top_p, top_e = _top_k(probs, k, self.n_experts)     # (T, k)
+                if self.norm_topk_prob:
+                    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if getattr(self, "routed_scale", 1.0) != 1.0:
+                top_p = top_p * self.routed_scale
             here = (top_e >= first) & (top_e < first + count)
             # pairs by held expert, those of experts held elsewhere last
             group = jnp.where(here, top_e - first, count).reshape(-1)
@@ -461,9 +539,17 @@ class MoE(TensorModule):
             order = checkpoint_name(order, "moe_order")
             sizes = checkpoint_name(sizes, "moe_sizes")
             passes = checkpoint_name(passes, "moe_passes")
-        y = _routed(x, params["w_in"].astype(x.dtype),
+        latent = getattr(self, "latent", None)
+        xs = x
+        if latent:
+            with jax.named_scope(trace.SCOPE_MOE_LATENT):
+                xs = x @ params["latent_down"].astype(x.dtype)
+        y = _routed(xs, params["w_in"].astype(x.dtype),
                     params["w_out"].astype(x.dtype), top_p, order, sizes,
                     passes, rows, self.hidden_size, getattr(self, "gate", "silu"))
+        if latent:
+            with jax.named_scope(trace.SCOPE_MOE_LATENT):
+                y = y.astype(x.dtype) @ params["latent_up"].astype(x.dtype)
 
         new_state = dict(state)
         share = jax.lax.stop_gradient(jnp.mean(
@@ -568,9 +654,14 @@ class MoE(TensorModule):
 
     def __repr__(self):
         gate = getattr(self, "gate", "silu")
+        more = "".join(
+            f", {k}={v}" for k, v in (
+                ("gate", gate), ("scoring", getattr(self, "scoring", "softmax")),
+                ("routed_scale", getattr(self, "routed_scale", 1.0)),
+                ("latent", getattr(self, "latent", None)))
+            if v not in ("silu", "softmax", 1.0, None))
         return (f"MoE({self.input_size}, hidden={self.hidden_size}, "
-                f"experts={self.n_experts}, router={self.router}"
-                + (f", gate={gate})" if gate != "silu" else ")"))
+                f"experts={self.n_experts}, router={self.router}{more})")
 
 
 def expert_parallel_rules(moe_path_prefix: str = "", axis: str = "model",

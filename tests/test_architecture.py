@@ -44,7 +44,7 @@ MAY = {
     "utils": set(),
     "obs": {"utils"},
     "kernels": {"utils"},
-    "nn": {"utils", "kernels"},
+    "nn": {"utils", "obs", "kernels"},
     "dataset": {"native", "utils", "obs"},
     "transform": {"utils"},
     "parallel": {"utils", "obs", "kernels", "nn"},

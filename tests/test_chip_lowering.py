@@ -304,6 +304,43 @@ def test_routed_layer_lowers_at_the_sdar_shapes(monkeypatch):
         assert "32768x2048xbf16" in text and "131072x2048" not in text
 
 
+def test_the_mamba_mixer_and_the_ungated_latent_experts_lower_at_the_cells_shapes(
+        on_tpu, monkeypatch):
+    """The Nemotron cell's two new paths at its shapes, forward and gradient:
+    Mamba-2's mixer over 8,192 positions at 16 heads of 64, one group, a
+    state of 128 in chunks of 128 (plain XLA, under its two scopes); and the
+    expert layer with the sigmoid router over 512 experts (22 a token, 8
+    held), ReLU squared experts of 2,688 in the 1,024 latent (one input
+    matrix: the first grouped product is 1,024 x 2,688) and the shared expert.
+    The grouped products see a pass's 5,632 rows of the latent."""
+    from bigdl_tpu.kernels import grouped_matmul as gm
+    from bigdl_tpu.nn import Mamba2Mixer
+    from bigdl_tpu.parallel.moe import MoE
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    bf16 = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), tree)
+    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16)
+    mixer = Mamba2Mixer(4096, 16, 64, 1, 128, 4, 128, 1e-5)
+
+    def mamba(p, x):
+        return mixer.apply(p, {}, x)[0].astype(jnp.float32).sum()
+
+    text = _tpu_module(jax.grad(mamba, argnums=(0, 1)), bf16(mixer.get_params()), x)
+    assert "bigdl_mamba" in text and "bigdl_ssm_scan" in text
+    assert "1x64x128x1x16x64xbf16" in text     # a chunk's scores times dt x, by head
+    layer = MoE(4096, 2688, 512, router="topk", top_k=22, held=(216, 8), gate="relu2",
+                shared=5376, scoring="sigmoid", routed_scale=5.0, latent=1024)
+    assert layer.get_params()["w_in"].shape == (8, 1024, 2688)
+    state = layer.get_state()
+
+    def experts(p, x):
+        return layer.apply(p, state, x)[0].astype(jnp.float32).sum()
+
+    text = _tpu_module(jax.grad(experts, argnums=(0, 1)), bf16(layer.get_params()), x)
+    assert text.count("tpu_custom_call") >= 6       # gmm twice forward, four backward
+    assert "5632x1024xbf16" in text and "bigdl_moe_latent" in text
+
+
 # ----------------------------------------------------- the sum by token
 @pytest.mark.parametrize("weighted", [True, False])
 @pytest.mark.parametrize("rows,d", [(32768, 2048), (24576, 2560), (24576, 2048)])

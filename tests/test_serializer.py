@@ -136,6 +136,7 @@ EXAMPLES = {
         16, 2, 8, 4, 4, 4, rope_scaling={"type": "yarn", "factor": 40,
                                          "original_max_position_embeddings": 4096}),
         _x(2, 5, 16)),
+    "Mamba2Mixer": (lambda: nn.Mamba2Mixer(16, 4, 4, 2, 4, chunk_size=4), _x(2, 6, 16)),
     "CrossAttention": (lambda: nn.CrossAttention(8, 2),
                        T(_x(2, 4, 8), _x(2, 6, 8))),
     "SequenceBeamSearch": (
